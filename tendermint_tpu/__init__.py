@@ -10,18 +10,41 @@ pass/fail bitmap.
 """
 
 import os as _os
+import sys as _sys
+
+# where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+# does not place it: one fixed, git-ignored directory of the checkout
+COMPILATION_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+
+_CACHE_SETTINGS = (
+    ("jax_compilation_cache_dir", "JAX_COMPILATION_CACHE_DIR",
+     COMPILATION_CACHE_DIR),
+    ("jax_persistent_cache_min_compile_time_secs",
+     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 1.0),
+    ("jax_persistent_cache_min_entry_size_bytes",
+     "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", 0),
+)
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> None:
-    """Opt in to JAX's persistent compilation cache (the verify kernel costs
-    minutes of XLA compile per shape/platform).  Must run before jax is
-    imported to take effect via env vars; no-op on backends whose compile
-    path bypasses the persistent cache (e.g. remote-compile tunnels).
+def enable_compilation_cache() -> None:
+    """Turn on JAX's persistent compilation cache (a verify kernel costs
+    tens of seconds of tracing and compiling per lane bucket).  A value
+    the environment sets wins and nothing else is set beside it; the
+    rest get the defaults above.  Works whichever of jax / this package
+    is imported first: before jax's import the defaults go into the
+    environment jax reads its config from, after it into jax.config —
+    importing jax here would tax every CLI command that never verifies.
     """
-    d = cache_dir or _os.path.expanduser("~/.cache/jax_comp")
-    _os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", d)
-    _os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-    _os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+    jax = _sys.modules.get("jax")
+    for name, env, default in _CACHE_SETTINGS:
+        if _os.environ.get(env):
+            continue
+        if jax is None:
+            _os.environ[env] = str(default)
+        else:
+            jax.config.update(name, default)
 
 
 enable_compilation_cache()

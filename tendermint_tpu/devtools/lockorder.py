@@ -126,6 +126,9 @@ LOCK_ORDER = {
     "tendermint_tpu/crypto/degrade.py:CircuitBreaker._lock": 36,
     "tendermint_tpu/crypto/degrade.py:DeviceLaneRuntime._pool_lock": 38,
     "tendermint_tpu/crypto/degrade.py:DeviceLaneRuntime._backend_lock": 40,
+    # compile-time bookkeeping of the lane worker: a leaf — compiling()
+    # and collect()'s deadline loop each take it alone, holding nothing
+    "tendermint_tpu/crypto/degrade.py:_CompileClock._lock": 42,
 
     # -- device-resident caches and launch bookkeeping (ops/) --
     "tendermint_tpu/ops/ed25519.py:_table_key_lock": 44,

@@ -162,7 +162,7 @@ REGISTERED_SITES = frozenset({
     # dead-backend (raise) and wedged-backend (latency:<ms> past the
     # probe timeout) classes deterministically, so the opportunistic
     # probe-retry window and the rc=0 host-fallback line are testable
-    # without a real tunnel
+    # without a real dead backend
     "bench.probe",
     # gossip observatory (p2p/netobs.py, ADR-025): fires on every
     # flow/rtt/receipt recording.  raise = the sample sheds (counted

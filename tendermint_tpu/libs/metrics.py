@@ -66,6 +66,13 @@ class Counter(_Metric):
         with self._lock:
             return self._values.get(self._key(labels), 0.0)
 
+    def items(self) -> Dict[Tuple[str, ...], float]:
+        """Snapshot of every series: label values (in label_names
+        order) -> count.  For readers that must see all of them, like
+        a gate over every failure reason, not one they name."""
+        with self._lock:
+            return dict(self._values)
+
     def render(self) -> List[str]:
         with self._lock:
             items = sorted(self._values.items())
@@ -471,9 +478,9 @@ class CryptoMetrics:
             "device batch (pad lanes are pure overhead).")
         self.device_compile_seconds = reg.histogram(
             "crypto", "device_compile_seconds",
-            "Wall-clock of FIRST launches per (path, lane bucket) — "
-            "dominated by jit compile; steady-state launches land in "
-            "crypto_device_launch_seconds instead.",
+            "One-time trace + compile seconds of a kernel shape, paid "
+            "ahead of its first launch and outside the launch deadline "
+            "(ops/ed25519.launch_kernel).",
             labels=("site",), buckets=exp_buckets(0.01, 4, 10))
         # fixed-base comb table cache (ops/ed25519, ADR-013): is the
         # zero-doubling verify path engaging (crypto_msm_route_total

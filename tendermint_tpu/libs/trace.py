@@ -86,7 +86,8 @@ KNOWN_SPANS = frozenset({
     "consensus.finalize_commit", "consensus.preverify",
     "consensus.quorum", "consensus.step", "consensus.vote",
     # ops/ — kernel routing
-    "msm.route", "ops.ed25519.verify_batch", "table_build",
+    "comb.prewarm_failed", "msm.route", "ops.ed25519.verify_batch",
+    "table_build",
     # state/pipeline.py — the block application pipeline (ADR-017)
     "pipeline.apply", "pipeline.commit", "pipeline.stage",
     # crypto/scheduler.py — the VerifyScheduler pipeline

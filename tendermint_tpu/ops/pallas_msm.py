@@ -38,19 +38,6 @@ from .pallas_ed25519 import (_CONSTS_PACKED, _COL_D, _COL_D2, _COL_ONE,
 NLIMB = F.NLIMB
 _i32 = jnp.int32
 
-
-def _compiler_params(**kw):
-    """The Mosaic compiler-params class was renamed TPUCompilerParams ->
-    CompilerParams across jax releases; fail with the missing API named
-    instead of an opaque NoneType call."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; incompatible jax version")
-    return cls(**kw)
-
 DEFAULT_TILE = 256
 
 
@@ -198,7 +185,7 @@ def _bucket_scan_call(ypx, ymx, t2d, tile: int):
         grid=grid,
         in_specs=[spec_in] * 3,
         out_specs=[spec_out] * 4,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(ypx, ymx, t2d)
 

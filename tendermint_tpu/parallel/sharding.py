@@ -104,6 +104,17 @@ def set_mesh_chunk(lanes=None):
     _mesh_chunk_override = None if lanes is None else int(lanes)
 
 
+# The local plane does not engage by itself on a multi-chip TPU host.
+# PR 21 repaired the shard_map'd Pallas step and ran it on a four-chip
+# v5e (bitmaps right at 1,024 and 10,000 rows), but a cold bucket of it
+# costs 213-278 s there, every new bucket stalls its caller that long,
+# chip_smoke.py did not finish with it on, and mesh-comb* never ran on a
+# chip (PERF.md "Chip bring-up").  A code constant, not a knob: the PR
+# that cuts that cost flips it and passes chip_smoke.py on four chips
+# (ROADMAP Reach 6); scripts/mesh_probe.py sets it to measure.  CPU
+# meshes (the tests' forced host devices, mesh-xla) are not affected.
+MESH_ON_TPU = False
+
 _PLANE = None
 _PLANE_KEY = None      # local-topology fingerprint the plane latched on
 _GLOBAL_PLANE = None
@@ -128,7 +139,8 @@ def data_plane():
     process verifies its own batches; the global multi-controller mesh
     lives behind global_plane() and is reachable only from coordinated
     lockstep() call sites (ADR-027).  Thread-safe (reactors call
-    verify_batch concurrently).  TM_TPU_NO_MESH=1 forces single-device.
+    verify_batch concurrently).  TM_TPU_NO_MESH=1 forces single-device,
+    and so does a TPU backend while MESH_ON_TPU is off (above).
     The latch is topology-keyed: degrade's backend re-probe calls
     invalidate_on_topology_change() so a backend that comes up after
     the first probe gets its mesh instead of a forever-False plane."""
@@ -140,12 +152,14 @@ def data_plane():
                 if os.environ.get("TM_TPU_NO_MESH") == "1":
                     _PLANE = False
                 else:
-                    try:
-                        ndev = jax.local_device_count()
-                    except Exception:
-                        ndev = 1
+                    # a backend that fails to initialize raises out of
+                    # here (nothing latches): the dispatch wrapping this
+                    # call counts it as a device fault, and the next
+                    # call probes again
+                    on = jax.local_device_count() > 1 and \
+                        (MESH_ON_TPU or not edops._use_pallas())
                     _PLANE = _DataPlane(make_mesh(jax.local_devices())) \
-                        if ndev > 1 else False
+                        if on else False
     return _PLANE or None
 
 
@@ -242,12 +256,9 @@ def global_plane():
 
 def _coord_client():
     """The jax.distributed coordination-service client, or None when
-    the runtime is single-process / uninitialized / too old."""
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 - old jax without the service
-        return None
+    the runtime is single-process / uninitialized."""
+    from jax._src import distributed
+    return distributed.global_state.client
 
 
 # every process that latches the global plane off writes a key under
@@ -437,8 +448,6 @@ class _DataPlane:
             fn = self._fns.get(key)
         if fn is not None:
             return fn
-        from jax.experimental.shard_map import shard_map
-
         from tendermint_tpu.ops import curve as Cv
         from tendermint_tpu.ops import msm as msmops
 
@@ -463,10 +472,10 @@ class _DataPlane:
             ovf_any = jax.lax.psum(ovf.astype(jnp.int32), BATCH_AXIS) > 0
             return jnp.stack(list(total)), ok_all, ovf_any
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(BATCH_AXIS, None),) * 5,
-            out_specs=(P(), P(), P()), check_rep=False))
+            out_specs=(P(), P(), P()), check_vma=False))
         with self._lock:
             self._fns.setdefault(key, f)
             return self._fns[key]
@@ -620,7 +629,7 @@ class _DataPlane:
         table_ops = self._comb_repl_operands(entry, base)
         fn = self._comb_fn()
         bitmap, nb = self._run_comb_chunks(
-            lambda args: fn(*args, *table_ops)[0],
+            lambda args: edops.launch_kernel(fn, *args, *table_ops)[0],
             r_b, s_digits, k_digits, vidx, probe)
         return bitmap[:n], nb, self.nshard, "mesh-comb"
 
@@ -752,20 +761,18 @@ class _DataPlane:
             fn = self._fns.get("comb-sharded")
         if fn is not None:
             return fn
-        from jax.experimental.shard_map import shard_map
-
         from tendermint_tpu.ops import ed25519 as edops
 
         def body(r, sd, kd, vl, ty, tm, tz, td, dok, by, bm, bt):
             return edops.comb_verify_staged(r, sd, kd, vl, ty, tm, tz,
                                             td, dok, by, bm, bt)
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=((P(BATCH_AXIS),) * 4
                       + (P(None, None, None, BATCH_AXIS),) * 4
                       + (P(BATCH_AXIS), P(), P(), P())),
-            out_specs=P(BATCH_AXIS), check_rep=False))
+            out_specs=P(BATCH_AXIS), check_vma=False))
         with self._lock:
             self._fns.setdefault("comb-sharded", f)
             return self._fns["comb-sharded"]
@@ -855,7 +862,7 @@ class _DataPlane:
         try:
             args = self._put_sharded((rs, ss, ks, vl),
                                      (P(BATCH_AXIS),) * 4, walls=walls)
-            out = np.asarray(fn(*args, *table_ops))
+            out = np.asarray(edops.launch_kernel(fn, *args, *table_ops))
         finally:
             devobs.ledger_add("staging", -nb * row_bytes)
         self._merge_probe(probe, [walls], 1)
@@ -863,19 +870,21 @@ class _DataPlane:
 
     def _packed_fn(self):
         """TPU path: the fused Pallas kernel inside shard_map, packed
-        (128, B) input sharded on the lane axis."""
+        (128, B) input sharded on the lane axis.  check_vma=False: the
+        replication checker cannot see through pallas_call (its
+        out_shape carries no varying-axes annotation) and refuses the
+        trace; every output lane is computed from its own shard's input
+        lanes, so there is nothing replicated for it to check."""
         with self._lock:
             if "packed" not in self._fns:
-                from jax.experimental.shard_map import shard_map
-
                 from tendermint_tpu.ops import ed25519 as edops
                 from tendermint_tpu.ops import pallas_ed25519 as pe
 
-                f = shard_map(
+                f = jax.shard_map(
                     lambda p: pe.verify_packed_pallas(
                         p, tile=edops.PALLAS_TILE),
                     mesh=self.mesh, in_specs=(P(None, BATCH_AXIS),),
-                    out_specs=P(BATCH_AXIS))
+                    out_specs=P(BATCH_AXIS), check_vma=False)
                 self._fns["packed"] = jax.jit(f)
             return self._fns["packed"]
 
@@ -969,7 +978,7 @@ class _DataPlane:
             nxt = stage(0)
             for ci, _s in enumerate(starts):
                 cur = nxt
-                bm, av = fn(*cur)
+                bm, av = edops.launch_kernel(fn, *cur)
                 outs.append(bm)
                 flags.append(av)
                 if ci + 1 < len(starts):
@@ -1045,7 +1054,7 @@ class _DataPlane:
                 put_walls.append(time.perf_counter() - t_put)
                 for ci, s in enumerate(starts):
                     cur = nxt
-                    outs.append(fn(cur))
+                    outs.append(edops.launch_kernel(fn, cur))
                     if ci + 1 < len(starts):
                         s2 = starts[ci + 1]
                         t_put = time.perf_counter()

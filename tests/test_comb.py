@@ -337,6 +337,31 @@ def test_comb_routing_build_hit_subset_mixed(monkeypatch):
     assert edops.last_launch()["path"] == "xla"
 
 
+def test_table_build_compile_stays_off_the_launch_record(monkeypatch):
+    """launch_kernel's trace + compile seconds go to whoever takes them
+    next on the thread.  The table build takes its own (its span and
+    crypto_device_compile_seconds{site="comb-build"}) and verify_batch
+    drops leftovers at entry, so the comb launch that follows records
+    only its own kernel's — here none, the kernel is a stub."""
+    import jax
+
+    rt = degrade.configure(registry=Registry("comb_compile"))
+    _stub_kernels(monkeypatch)
+    monkeypatch.setattr(edops, "comb_build_kernel",
+                        jax.jit(edops.comb_build_kernel))
+    monkeypatch.setattr(edops, "_comb_min_override", 8)
+    pubs, msgs, sigs = _batch(24)
+    edops._compile_tls.s = 9.0     # left by a dispatch that raised
+    assert edops.verify_batch(pubs, msgs, sigs, cache_pubs=True).all()
+    ll = edops.last_launch()
+    assert ll["path"] == "comb" and ll["table_build"]
+    assert "compile_s" not in ll
+    hist = rt.metrics.device_compile_seconds
+    assert hist.count(site="comb-build") == 1
+    assert 0.0 < hist.total(site="comb-build") < 9.0
+    assert hist.count(site="comb") == 0
+
+
 def test_comb_disabled_and_budget_declined(monkeypatch):
     rt = degrade.configure(registry=Registry("comb_cfg"))
     rec = {}

@@ -190,6 +190,28 @@ def test_topology_invalidation_drops_stale_plane(monkeypatch):
     assert sharding._PLANE is False
 
 
+def test_a_tpu_host_stays_on_one_device_until_the_mesh_is_switched_on(
+        monkeypatch):
+    """PR 21: the TPU mesh step runs, but a cold bucket of it costs
+    minutes and chip_smoke.py has not passed with it on — so on a
+    multi-chip TPU host the plane is off until MESH_ON_TPU says
+    otherwise.  The forced-host-device CPU mesh is untouched."""
+    monkeypatch.delenv("TM_TPU_NO_MESH", raising=False)
+
+    def probe():
+        with sharding._PLANE_LOCK:
+            sharding._PLANE = None
+            sharding._PLANE_KEY = None
+        return sharding.data_plane()
+
+    assert sharding.MESH_ON_TPU is False
+    assert probe() is not None                      # CPU mesh: engages
+    monkeypatch.setattr(edops, "_use_pallas", lambda: True)
+    assert probe() is None and sharding._PLANE is False
+    monkeypatch.setattr(sharding, "MESH_ON_TPU", True)
+    assert probe() is not None
+
+
 # ---------------------------------------------------------------------------
 # tier-1: global-plane gating, the lockstep window, the failure latch
 # ---------------------------------------------------------------------------
@@ -277,8 +299,8 @@ def test_chaos_mesh_stage_degrades_to_single_device(monkeypatch):
     truth = _oracle(pubs, msgs, sigs)
     hit = {}
 
-    def _ladder_stub(**arrs):
-        hit["nb"] = int(next(iter(arrs.values())).shape[0])
+    def _ladder_stub(*arrs):
+        hit["nb"] = int(arrs[0].shape[0])
         return edops.jnp.asarray(
             np.pad(truth, (0, hit["nb"] - len(truth))))
 
