@@ -1,0 +1,6 @@
+"""Requests completed in the window over the window's length: all the work
+and all the time, from the first request's start to the last one's return."""
+
+
+def read(run):
+    return len(run["requests"]) / run["window_s"] if run["requests"] else None

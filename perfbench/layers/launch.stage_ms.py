@@ -1,0 +1,8 @@
+"""Host staging (native/staging.c): sum of `stage_s` of the launch records
+a request caused, median per request, in ms."""
+from perfbench import stats
+
+
+def read(run):
+    m = stats.median(stats.per_request_sum(run, "stage_s"))
+    return None if m is None else m * 1e3
