@@ -30,6 +30,7 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.crypto import scheduler as vsched
+from tendermint_tpu.libs import trace
 from tendermint_tpu.types.block import Block
 from tendermint_tpu.types.basic import BlockID
 from tendermint_tpu.types.part_set import (
@@ -156,7 +157,22 @@ def replay_window(executor, store, state, blocks: List[Block],
     assert len(certifiers) == len(blocks)
     blocks = blocks[:max_window]
     certifiers = certifiers[:len(blocks)]
+    # the root of the window's span tree: the pipeline's workers and the
+    # scheduler's launches hang under it by explicit parent
+    with trace.span("blocksync.replay_window", blocks=len(blocks)) as sp:
+        try:
+            state, applied = _replay_window(sp, executor, store, state,
+                                            blocks, certifiers, max_window)
+        except WindowSyncError as e:
+            sp.add(applied=e.applied)
+            raise
+        sp.add(applied=applied)
+        return state, applied
 
+
+def _replay_window(sp, executor, store, state, blocks, certifiers,
+                   max_window):
+    """replay_window's three paths; `sp` is told which one ran."""
     # ---- pipelined path (state/pipeline.py, ADR-017) ---------------------
     # stage/verify block N+1 and group-commit storage while N applies;
     # declines (None) when not running, the window is trivial, or the
@@ -167,6 +183,7 @@ def replay_window(executor, store, state, blocks: List[Block],
         res = pipe.replay_window(executor, store, state, blocks, certifiers,
                                  max_window=max_window)
         if res is not None:
+            sp.add(path="pipelined")
             return res
 
     k = _stable_window(state, blocks)
@@ -222,6 +239,7 @@ def replay_window(executor, store, state, blocks: List[Block],
                 all_ok, _bits = vsched.verify_items(
                     items, vsched.Priority.BLOCKSYNC)
             if all_ok:
+                sp.add(path="coalesced")
                 for i in range(collected):
                     b, cert = blocks[i], certifiers[i]
                     h = base_h + i
@@ -246,6 +264,7 @@ def replay_window(executor, store, state, blocks: List[Block],
 
     # ---- strict sequential path (reference semantics) --------------------
     n = min(len(blocks), max(k, 1))
+    sp.add(path="strict")
     return _strict_sequential(executor, store, state, blocks[:n],
                               certifiers[:n], chain_id)
 
@@ -266,7 +285,8 @@ def _apply_one(executor, store, state, block, bid, parts, cert):
                 raise ValueError(
                     f"stored block {h} does not match replayed block")
         else:
-            store.save_block(block, parts, cert)
+            with trace.span("store.save_block", height=h):
+                store.save_block(block, parts, cert)
     new_state, _resp = executor.apply_block(state, bid, block)
     # drain the observatory's deferred publication per applied height:
     # during catch-up the consensus receive loop (the usual drainer)

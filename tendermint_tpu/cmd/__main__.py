@@ -372,8 +372,8 @@ def cmd_debug_trace(args):
     ui.perfetto.dev to see the vote -> verify -> commit timeline."""
     import urllib.request
 
-    addr = _pprof_addr(args, "and TM_TPU_TRACE=1 or trace.enable() on "
-                             "the node to record spans")
+    addr = _pprof_addr(args, "the recorder is on unless the node runs "
+                             "with TM_TPU_TRACE=0")
     url = f"http://{addr}/debug/trace?since={args.since}"
     with urllib.request.urlopen(url, timeout=10) as r:
         body = r.read().decode()

@@ -20,6 +20,10 @@ measure — see the instrumentation notes in ops/ed25519.verify_batch and
 parallel/sharding.make_sharded_verifier):
 
   stage_s     host staging: pack / pad / challenge hashing
+  stage_cpu_s the staging thread's CPU time over the same bracket
+              (time.thread_time): stage_s minus it is time the thread
+              was off the processor (the GIL, mostly), not staging
+              work.  A reading beside stage_s, not a phase of the wall
   h2d_s       host->device transfer (the monolithic paths bracket the
               device_put with block_until_ready on the staged buffers;
               the pipelined paths record the summed device_put walls)

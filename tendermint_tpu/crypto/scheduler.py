@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import enum
 import queue as _queue
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -131,7 +132,7 @@ class _Submission:
                  "bits", "remaining", "enq_t", "n",
                  # lifecycle stamps (ADR-016): monotonic, 0.0 = not yet
                  "submit_t", "wclose_t", "settle_t", "deadline_missed",
-                 "path")
+                 "path", "parent_span")
 
     def __init__(self, items, prio, deadline, populate_cache):
         self.items = items          # List[_batch._Item]
@@ -148,6 +149,10 @@ class _Submission:
         self.settle_t = 0.0         # future resolved
         self.deadline_missed = False
         self.path = "sched-cache"   # what settled it (see _execute)
+        # the submitter's open span (libs/trace): what this submission
+        # records on the scheduler's threads hangs under it, so that one
+        # request stays one connected tree across the hand-off
+        self.parent_span = trace.current_id()
 
 
 class _Launch:
@@ -176,6 +181,15 @@ def _as_item(triple) -> _batch._Item:
     if not isinstance(pub, PubKey):
         pub = _ed.PubKey(bytes(pub))
     return _batch._Item(pub, bytes(msg), bytes(sig))
+
+
+def _last_launch() -> dict:
+    """ops/ed25519.last_launch() without importing ops: the recorder is
+    on by default, importing ops initializes the backend, and a window
+    of host lanes in a process that never launched must stay off it.
+    Not loaded means nothing launched yet."""
+    ops = sys.modules.get("tendermint_tpu.ops.ed25519")
+    return ops.last_launch() if ops is not None else {}
 
 
 def _mark_fallback(box: List[str], tag: str, fn):
@@ -562,7 +576,9 @@ class VerifyScheduler(BaseService):
         lane_of: Dict[bytes, int] = {}
         cache_hits = dedup = 0
         settled: List[_Submission] = []  # fully cache-resolved subs
-        with trace.span("sched.coalesce", submissions=len(subs),
+        # a window has one parent: its first (highest-class) submitter
+        with trace.span("sched.coalesce", parent=subs[0].parent_span,
+                        submissions=len(subs),
                         items=sum(s.n for s in subs)) as sp:
             for sub in subs:
                 for i, it in enumerate(sub.items):
@@ -678,8 +694,7 @@ class VerifyScheduler(BaseService):
             # make the post-collect bracket dereference an unbound seq0
             tracing = trace.is_enabled()
             if tracing:
-                from tendermint_tpu.ops import ed25519 as _edops
-                seq0 = _edops.last_launch().get("seq", 0)
+                seq0 = _last_launch().get("seq", 0)
             device_lanes = []
             host_lanes = []
             for tname, idxs in by_scheme.items():
@@ -744,7 +759,7 @@ class VerifyScheduler(BaseService):
                 # so only annotate when exactly OUR launch landed since
                 # the bracket started (seq advanced by 1) — a concurrent
                 # verifier's record must not mislabel this window
-                rec = _edops.last_launch()
+                rec = _last_launch()
                 if rec.get("seq", 0) == seq0 + 1:
                     sp.add(route=rec.get("path"))
         t_exec1 = time.monotonic()
@@ -847,7 +862,8 @@ class VerifyScheduler(BaseService):
 
     @staticmethod
     def _fire(sub: _Submission):
-        trace.instant("sched.resolve", priority=sub.prio.name.lower(),
+        trace.instant("sched.resolve", parent=sub.parent_span,
+                      priority=sub.prio.name.lower(),
                       n=sub.n, valid=int(sub.bits.sum()))
         sub.future._set(sub.bits)
 
@@ -872,7 +888,8 @@ class VerifyScheduler(BaseService):
         except Exception:  # noqa: BLE001 - observability must not break
             pass
         if missed:
-            trace.instant("sched.deadline_miss", priority=prio, n=sub.n,
+            trace.instant("sched.deadline_miss", parent=sub.parent_span,
+                          priority=prio, n=sub.n,
                           late_s=round(sub.settle_t - sub.deadline, 6))
 
     def _publish_slo(self, streams):

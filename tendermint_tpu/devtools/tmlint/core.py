@@ -186,7 +186,8 @@ RULES = [
          "that is not in libs/fail.py REGISTERED_SITES.  Unregistered "
          "sites dodge the chaos-coverage gate."),
     Rule("TM306", "unregistered-trace-span", "all linted files",
-         "trace.span/trace.instant called with a literal name that is "
+         "trace.span/trace.timed/trace.instant called with a literal "
+         "name that is "
          "not in libs/trace.py KNOWN_SPANS.  The registry is what lets "
          "trace consumers (bench report, debug-trace CLI) rely on span "
          "names."),

@@ -362,7 +362,7 @@ def _check_trace_spans(f: SourceFile, known: Set[str],
         if not isinstance(node, ast.Call):
             continue
         name = _call_name(node.func)
-        if name not in ("span", "instant"):
+        if name not in ("span", "timed", "instant"):
             continue
         recv = getattr(node.func, "value", None)
         if not (isinstance(recv, ast.Name) and recv.id == "trace"):

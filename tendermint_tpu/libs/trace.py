@@ -5,18 +5,24 @@ The node has counters (libs/metrics.py) and a profiler (libs/pprof.py),
 but neither answers "where did THIS batch spend its time, and which path
 did it take" — the question round 5's unmeasured perf thesis needed.
 This module is the third observability surface: a process-global tracer
-holding a bounded ring buffer of spans (monotonic-clock start + duration,
-parent linkage, key=value attrs), exported in the Chrome-trace /
-Perfetto JSON event format so any trace viewer renders the timeline.
+holding a bounded ring buffer of spans (start + wall duration + the
+thread's CPU time, parent linkage, key=value attrs), exported in the
+Chrome-trace / Perfetto JSON event format so any trace viewer renders
+the timeline.
 
 Design constraints, in order:
 
-  1. Disabled is a guaranteed no-op.  Tracing is OFF by default; every
-     call site goes through ``span()`` / ``instant()`` unconditionally,
-     so the disabled path must cost less than a microsecond (one enabled
-     check, one singleton return — no allocation beyond the kwargs dict,
-     no locks, no clock reads).  Consensus must never pay for
-     observability it didn't ask for.
+  1. ON by default, like its siblings (crypto/devobs, consensus/
+     observatory, p2p/netobs): a recorder that is off when the incident
+     happens has recorded nothing.  That is affordable only while a
+     span stands at a batch, launch, block or window boundary and NEVER
+     per vote or per signature: an enabled span costs ~5 us (PERF.md
+     section 6 has the measured cost per benchmark cell), so 300 of
+     them in a 43 ms height would be 3% of it.  ``TM_TPU_TRACE=0``
+     turns it off, and every call site goes through ``span()`` /
+     ``instant()`` unconditionally, so the disabled path must cost less
+     than a microsecond (one enabled check, one singleton return — no
+     allocation beyond the kwargs dict, no locks, no clock reads).
   2. Bounded memory.  A ring buffer (default 8192 finished spans)
      overwrites the oldest records; a wedged exporter or a forgotten
      enable can never OOM the node.  This is why it is a flight
@@ -28,12 +34,31 @@ Design constraints, in order:
      coalesce -> launch -> verdict chain is one connected tree even
      though it crosses the lane-worker boundary.
 
-Enable programmatically (``trace.enable()``), via ``TM_TPU_TRACE=1`` in
-the environment (capacity override: ``TM_TPU_TRACE_CAPACITY``), or not
-at all.  Read it back three ways: ``GET /debug/trace?since=<seq>`` on
-the pprof listener (libs/pprof.py), the ``debug-trace`` CLI
-(cmd/__main__.py), or the per-config artifact bench.py writes next to
-its JSON line.
+One clock: spans stamp ``time.perf_counter_ns()``, the clock of every
+other wall bracket in the program and of the benchmark's own rows, so a
+span can be laid beside them without a conversion.  Beside its wall
+duration a span keeps ``cpu_ns``, the CPU time of ITS thread between
+enter and exit (``time.thread_time_ns()``): wall minus CPU is the time
+the thread was off the processor, waiting for the GIL, a lock, a queue,
+the device or the disk.  That holds where the clock is cheap to read
+(0.3 us on a plain Linux host).  Under a sandboxed kernel it is not: on
+the benchmark's hosts one read measured 6 us alone, more in a threaded
+process, and moved in ticks of 10 ms, which made 14 spans cost 2% of a
+43 ms height for a number only a sum over hundreds of ms could use.  So
+the module times the clock once (``_cheap_cpu_clock``), and where a read
+costs over 2 us spans go without: ``cpu_ns`` is None there.  And while
+jax is loaded every span also enters
+a ``jax.profiler.TraceAnnotation`` of its own name, so inside a profiler
+session it lies in the profile, on the profile's clock, beside the
+device's operations.  This module never imports jax: it takes
+``jax.profiler`` from ``sys.modules`` once something else has loaded it.
+
+Switches: ``TM_TPU_TRACE=0`` in the environment or ``trace.disable()``
+turn it off, ``trace.enable()`` on again (capacity override:
+``TM_TPU_TRACE_CAPACITY``).  Read it back three ways:
+``GET /debug/trace?since=<seq>`` on the pprof listener (libs/pprof.py),
+the ``debug-trace`` CLI (cmd/__main__.py), or the per-config artifact
+bench.py writes next to its JSON line.
 """
 from __future__ import annotations
 
@@ -41,6 +66,7 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -50,9 +76,10 @@ _UNSET = object()  # sentinel: "inherit parent from the thread's stack"
 
 # ---------------------------------------------------------------------------
 # the span-name registry (tmlint TM306).  Every literal name passed to
-# trace.span()/trace.instant() must appear here: trace consumers (the
-# bench report's route columns, the debug-trace CLI, the scheduler
-# acceptance tests walking span trees) key on these strings, so an
+# trace.span()/trace.timed()/trace.instant() must appear here: trace
+# consumers (the benchmark's per-layer readers under perfbench/layers,
+# the debug-trace CLI, the acceptance tests walking span trees) key on
+# these strings, so an
 # unregistered name is either a typo or an undocumented contract.
 # Grouped by subsystem; keep alphabetical within a group.
 # ---------------------------------------------------------------------------
@@ -60,6 +87,10 @@ _UNSET = object()  # sentinel: "inherit parent from the thread's stack"
 KNOWN_SPANS = frozenset({
     # crypto/batch.py — the BatchVerifier coalesce window
     "batch.host_lane", "batch.verdict", "batch.verify",
+    # blocksync/replay.py — the root of one replayed window (path =
+    # pipelined / coalesced / strict) and the block store's share of
+    # each apply
+    "blocksync.replay_window", "store.save_block",
     # bench.py
     "bench.host_baseline", "bench.pass", "bench.propose",
     # crypto/degrade.py — breaker + device lane lifecycle
@@ -75,6 +106,15 @@ KNOWN_SPANS = frozenset({
     # one SHARED certificate verification (waiters = how many requests
     # it settles)
     "light.coalesce", "light.serve",
+    # light/verifier.py — the root of one stateless header verification
+    "light.verify",
+    # types/validator_set.py + light/verifier.py — the host work around
+    # a commit's one batched launch, each ONE span per call: the set's
+    # merkle hash, the commit's structural checks, the trusting path's
+    # match by address, the >2/3 tally, and sign-bytes + pubkey rows up
+    # to the call of verify_sigs_bulk
+    "commit.collect", "commit.match", "commit.prefix",
+    "commit.validate_basic", "valset.hash",
     # networks/ — the in-process multi-node harness (ADR-019)
     "harness.scenario", "harness.step", "vnet.deliver",
     # p2p/netobs.py — the gossip observatory's deferred drain (ADR-025)
@@ -89,7 +129,8 @@ KNOWN_SPANS = frozenset({
     "comb.prewarm_failed", "msm.route", "ops.ed25519.verify_batch",
     "table_build",
     # state/pipeline.py — the block application pipeline (ADR-017)
-    "pipeline.apply", "pipeline.commit", "pipeline.stage",
+    "pipeline.apply", "pipeline.commit", "pipeline.drain",
+    "pipeline.stage", "pipeline.wait_staged",
     # crypto/scheduler.py — the VerifyScheduler pipeline
     "sched.coalesce", "sched.deadline_miss", "sched.host_lane",
     "sched.launch", "sched.resolve", "sched.shed", "sched.submit",
@@ -124,13 +165,64 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _Stopwatch(_NoopSpan):
+    """timed() while the recorder is off: the clock pair, no record."""
+
+    __slots__ = ("_t0", "dur_ns")
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_ns = time.perf_counter_ns() - self._t0
+        return False
+
+
+_CPU_READ_BUDGET_NS = 2000
+
+
+def _cheap_cpu_clock():
+    """time.thread_time_ns where one read of it costs under the budget
+    (best of five, so a loaded host does not misjudge it), else None."""
+    best = None
+    for _ in range(5):
+        t0 = time.perf_counter_ns()
+        time.thread_time_ns()
+        cost = time.perf_counter_ns() - t0
+        best = cost if best is None else min(best, cost)
+    return time.thread_time_ns if best < _CPU_READ_BUDGET_NS else None
+
+
+_CPU_CLOCK = _cheap_cpu_clock()
+
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _profile_annotation(name: str):
+    """An entered jax.profiler.TraceAnnotation of `name`, or None while
+    nothing in the process has imported jax (this module never does)."""
+    global _annotation
+    if _annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        _annotation = prof.TraceAnnotation
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """A live span.  Created only while the tracer is enabled; records
     itself into the ring on __exit__ (even if the tracer was disabled
-    mid-span — the span was paid for, keep it)."""
+    mid-span — the span was paid for, keep it).  After the exit
+    `dur_ns` and `cpu_ns` hold what was recorded (`cpu_ns` None where
+    the host's thread CPU clock is too dear to read, _cheap_cpu_clock)."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
-                 "_t0", "_tid", "_tname")
+                 "_t0", "_c0", "_ann", "_tid", "_tname", "dur_ns",
+                 "cpu_ns")
 
     def __init__(self, tracer: "Tracer", name: str, parent, attrs: dict):
         self._tracer = tracer
@@ -153,11 +245,19 @@ class _Span:
         if self.parent_id is _UNSET:
             self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
-        self._t0 = time.monotonic_ns()
+        self._ann = _profile_annotation(self.name)
+        # the CPU bracket lies inside the wall bracket, so cpu_ns <=
+        # dur_ns holds whatever the two reads themselves cost
+        self._t0 = time.perf_counter_ns()
+        self._c0 = _CPU_CLOCK() if _CPU_CLOCK is not None else None
         return self
 
     def __exit__(self, etype, evalue, tb):
-        dur = time.monotonic_ns() - self._t0
+        self.cpu_ns = time.thread_time_ns() - self._c0 \
+            if self._c0 is not None else None
+        self.dur_ns = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -165,9 +265,9 @@ class _Span:
             del stack[stack.index(self):]
         if etype is not None:
             self.attrs["error"] = etype.__name__
-        self._tracer._record(self.name, "X", self._t0, dur, self._tid,
-                             self._tname, self.span_id, self.parent_id,
-                             self.attrs)
+        self._tracer._record(self.name, "X", self._t0, self.dur_ns,
+                             self.cpu_ns, self._tid, self._tname,
+                             self.span_id, self.parent_id, self.attrs)
         return False
 
 
@@ -175,7 +275,7 @@ class Tracer:
     def __init__(self, capacity: Optional[int] = None,
                  enabled: Optional[bool] = None):
         if enabled is None:
-            enabled = os.environ.get("TM_TPU_TRACE", "") == "1"
+            enabled = os.environ.get("TM_TPU_TRACE", "") != "0"
         if capacity is None:  # env tunes the DEFAULT only — an explicit
             # constructor argument (private test tracers) always wins.
             # A malformed value falls back: the module is imported by
@@ -236,15 +336,26 @@ class Tracer:
             return _NOOP
         return _Span(self, name, parent, attrs)
 
-    def instant(self, name: str, **attrs):
-        """A zero-duration marker event (Chrome-trace ph="i")."""
+    def timed(self, name: str, parent=_UNSET, **attrs):
+        """A span whose `dur_ns` the caller reads after the exit, with
+        the recorder on or off: for a site that feeds an operator
+        metric from the same bracket, so that the metric and the span
+        are one clock pair and can never disagree."""
+        if not self._enabled:
+            return _Stopwatch()
+        return _Span(self, name, parent, attrs)
+
+    def instant(self, name: str, parent=_UNSET, **attrs):
+        """A zero-duration marker event (Chrome-trace ph="i");
+        `parent` as for span()."""
         if not self._enabled:
             return
         t = threading.current_thread()
-        stack = self._stack()
-        parent = stack[-1].span_id if stack else None
-        self._record(name, "i", time.monotonic_ns(), 0, t.ident, t.name,
-                     next(self._ids), parent, attrs)
+        if parent is _UNSET:
+            stack = self._stack()
+            parent = stack[-1].span_id if stack else None
+        self._record(name, "i", time.perf_counter_ns(), 0, 0, t.ident,
+                     t.name, next(self._ids), parent, attrs)
 
     def current(self):
         """The innermost live span on this thread (no-op span when
@@ -263,8 +374,8 @@ class Tracer:
         stack = self._stack()
         return stack[-1].span_id if stack else None
 
-    def _record(self, name, ph, t0_ns, dur_ns, tid, tname, span_id,
-                parent_id, attrs):
+    def _record(self, name, ph, t0_ns, dur_ns, cpu_ns, tid, tname,
+                span_id, parent_id, attrs):
         with self._lock:
             self._seq += 1
             wrapped = len(self._buf) == self._buf.maxlen
@@ -272,7 +383,8 @@ class Tracer:
                 self._dropped += 1
             self._buf.append({
                 "seq": self._seq, "name": name, "ph": ph, "ts_ns": t0_ns,
-                "dur_ns": dur_ns, "tid": tid, "tname": tname,
+                "dur_ns": dur_ns, "cpu_ns": cpu_ns, "tid": tid,
+                "tname": tname,
                 "id": span_id, "parent": parent_id, "attrs": attrs,
             })
         if wrapped:
@@ -341,6 +453,8 @@ class Tracer:
                   "tid": r["tid"], "ts": r["ts_ns"] / 1000.0, "args": args}
             if r["ph"] == "X":
                 ev["dur"] = r["dur_ns"] / 1000.0
+                if r["cpu_ns"] is not None:
+                    args["cpu_us"] = r["cpu_ns"] / 1000.0
             else:
                 ev["s"] = "t"
             events.append(ev)
@@ -371,9 +485,13 @@ def span(name: str, parent=_UNSET, **attrs):
     return _Span(t, name, parent, attrs)
 
 
-def instant(name: str, **attrs):
+def timed(name: str, parent=_UNSET, **attrs):
+    return TRACER.timed(name, parent, **attrs)
+
+
+def instant(name: str, parent=_UNSET, **attrs):
     if TRACER._enabled:
-        TRACER.instant(name, **attrs)
+        TRACER.instant(name, parent, **attrs)
 
 
 def is_enabled() -> bool:

@@ -10,6 +10,7 @@ from __future__ import annotations
 from fractions import Fraction
 
 from tendermint_tpu.crypto import scheduler as vsched
+from tendermint_tpu.libs import trace
 from tendermint_tpu.types.basic import Timestamp
 from tendermint_tpu.types.light_block import LightValidationError, SignedHeader
 from tendermint_tpu.types.validator_set import (CommitVerifyError,
@@ -69,7 +70,10 @@ def _verify_new_header_and_vals(untrusted: SignedHeader,
                                 max_clock_drift_s: float):
     """Reference verifier.go:154-192."""
     try:
-        untrusted.validate_basic(trusted.header.chain_id)
+        with trace.span("commit.validate_basic",
+                        sigs=len(untrusted.commit.signatures)
+                        if untrusted.commit is not None else 0):
+            untrusted.validate_basic(trusted.header.chain_id)
     except LightValidationError as e:
         raise InvalidHeaderError(f"untrusted.validate_basic failed: {e}")
     if untrusted.height <= trusted.height:
@@ -162,13 +166,16 @@ def verify(trusted: SignedHeader, trusted_vals: ValidatorSet,
            max_clock_drift_s: float,
            trust_level: Fraction = DEFAULT_TRUST_LEVEL):
     """Reference verifier.go:138-152."""
-    if untrusted.height != trusted.height + 1:
-        verify_non_adjacent(trusted, trusted_vals, untrusted, untrusted_vals,
-                            trusting_period_s, now, max_clock_drift_s,
-                            trust_level)
-    else:
-        verify_adjacent(trusted, untrusted, untrusted_vals,
-                        trusting_period_s, now, max_clock_drift_s)
+    adjacent = untrusted.height == trusted.height + 1
+    with trace.span("light.verify", height=untrusted.height,
+                    adjacent=adjacent):
+        if adjacent:
+            verify_adjacent(trusted, untrusted, untrusted_vals,
+                            trusting_period_s, now, max_clock_drift_s)
+        else:
+            verify_non_adjacent(trusted, trusted_vals, untrusted,
+                                untrusted_vals, trusting_period_s, now,
+                                max_clock_drift_s, trust_level)
 
 
 def verify_backwards(untrusted: SignedHeader, trusted: SignedHeader):

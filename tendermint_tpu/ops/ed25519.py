@@ -735,8 +735,9 @@ def _overlap_phases(probe: dict) -> dict:
     crypto/devobs.py for why a tighter number would require serializing
     the pipeline being measured)."""
     out = {}
-    if probe.get("stage_s") is not None:
-        out["stage_s"] = probe["stage_s"]
+    for key in ("stage_s", "stage_cpu_s"):
+        if probe.get(key) is not None:
+            out[key] = probe[key]
     dma = probe.get("dma_s")
     if dma is not None:
         out["h2d_s"] = dma
@@ -1311,13 +1312,14 @@ def _comb_try(pubkeys, msgs, sigs, cache_pubs: bool, plane):
     from tendermint_tpu.crypto import devobs
     obs_on = devobs.is_enabled()
     vidx = remap[inverse].astype(np.int32)
-    t0 = time.perf_counter()
+    t0, c0 = time.perf_counter(), time.thread_time()
     _, r_b, s_b, kscal, host_ok = _stage_rows(
         pub_m, _to_u8_matrix(sigs, 64), msgs)
     s_digits = scalars_to_digits(s_b)
     k_digits = scalars_to_digits(kscal)
     use_mesh = plane is not None and plane.worth_sharding(n)
-    phases = {"stage_s": time.perf_counter() - t0} if obs_on else {}
+    phases = {"stage_cpu_s": time.thread_time() - c0,
+              "stage_s": time.perf_counter() - t0} if obs_on else {}
     res, path, nb, shards = None, "comb", 0, 1
     if use_mesh:
         # the data plane takes the FULL batch: it owns the chunking
@@ -1459,11 +1461,13 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     host_ok = np.zeros(nb, dtype=bool)
 
     stage_walls = []
+    stage_cpus = []
 
     def stage(j):
-        t_st = time.perf_counter()
+        t_st, c_st = time.perf_counter(), time.thread_time()
         a, b = j * chunk, min((j + 1) * chunk, n)
         if a >= n:  # pure padding chunk: zeroed inputs fail on-device
+            stage_cpus.append(time.thread_time() - c_st)
             stage_walls.append(time.perf_counter() - t_st)
             return np.zeros((96, chunk), dtype=np.int8)
         _, r_b, s_b, k, ok = _stage_rows(pub_m[a:b], sig_m[a:b],
@@ -1473,6 +1477,7 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
         rsk[0:32, : b - a] = r_b.T
         rsk[32:64, : b - a] = s_b.T
         rsk[64:96, : b - a] = k.T
+        stage_cpus.append(time.thread_time() - c_st)
         stage_walls.append(time.perf_counter() - t_st)
         return rsk.view(np.int8)
 
@@ -1507,6 +1512,7 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
         # inline inside the put expression): report the DMA share with
         # staging subtracted so stage_s + dma_s don't double-count
         probe["stage_s"] = sum(stage_walls)
+        probe["stage_cpu_s"] = sum(stage_cpus)
         probe["dma_s"] = max(0.0, sum(put_walls) - sum(stage_walls))
         probe["dma_first_s"] = max(0.0, put_walls[0] - stage_walls[0])
         probe["chunks"] = nsub
@@ -1630,7 +1636,7 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
         # pre-ADR-021 shape.
         obs_on = devobs.is_enabled()
         phases = {}
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         if _use_pallas():
             from . import pallas_ed25519 as pe
             if cache_pubs and len(pubkeys) >= PUB_CACHE_MIN:
@@ -1648,6 +1654,7 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
                 if nb != n:  # pad the trailing (lane) axis
                     packed = np.pad(packed, [(0, 0), (0, nb - n)])
                 if obs_on:
+                    phases["stage_cpu_s"] = time.thread_time() - c0
                     phases["stage_s"] = time.perf_counter() - t0
                 if nb > MAX_CHUNK:
                     # huge batches (100k-validator VerifyCommit) run as
@@ -1681,6 +1688,7 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
             n = host_ok.shape[0]
             dev = _pad_dev(dev, n, bucket_size(n))
             if obs_on:
+                phases["stage_cpu_s"] = time.thread_time() - c0
                 t_st = time.perf_counter()
                 phases["stage_s"] = t_st - t0
                 arrs = [jnp.asarray(dev[k]) for k in _XLA_ARGS]

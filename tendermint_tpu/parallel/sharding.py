@@ -941,7 +941,7 @@ class _DataPlane:
         from tendermint_tpu.crypto import devobs
         from tendermint_tpu.libs import fail
 
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         # chaos seam: a raise here degrades this batch to the
         # single-device ladder in ops/ed25519.verify_batch
         fail.inject(self.FAIL_SITE)
@@ -958,6 +958,7 @@ class _DataPlane:
         starts = list(range(0, nb, chunk_max))
         names = ("pub", "r", "s_digits", "k_digits")
         specs = (P(BATCH_AXIS),) * 5
+        stage_cpu_s = time.thread_time() - c0
         stage_s = time.perf_counter() - t0
         fn = self._step_fn(chunk_max)
         chunk_walls = []
@@ -999,6 +1000,7 @@ class _DataPlane:
             self._merge_probe(probe, chunk_walls, len(starts))
             extra.update(edops._overlap_phases({
                 "stage_s": probe["stage_s"],
+                "stage_cpu_s": stage_cpu_s,
                 "dma_s": probe.get("dma_s", 0.0),
                 "dma_first_s": probe.get("dma_first_s", 0.0),
                 "chunks": probe.get("chunks", len(starts))}))
@@ -1022,7 +1024,7 @@ class _DataPlane:
             from tendermint_tpu.crypto import devobs
 
             obs_on = devobs.is_enabled()
-            t0 = time.perf_counter()
+            t0, c0 = time.perf_counter(), time.thread_time()
             packed, host_ok = edops.prepare_batch_packed(pubkeys, sigs, msgs)
             n = host_ok.shape[0]
             unit = self.nshard * edops.PALLAS_TILE
@@ -1034,7 +1036,8 @@ class _DataPlane:
             nb = -(-max(edops.bucket_size(n), unit) // unit) * unit
             if nb != n:
                 packed = np.pad(packed, [(0, 0), (0, nb - n)])
-            extra = {"stage_s": time.perf_counter() - t0} if obs_on \
+            extra = {"stage_cpu_s": time.thread_time() - c0,
+                     "stage_s": time.perf_counter() - t0} if obs_on \
                 else None
             fn = self._packed_fn()
             shard_in = NamedSharding(self.mesh, P(None, BATCH_AXIS))
@@ -1169,7 +1172,7 @@ def make_sharded_verifier(mesh: Mesh, axis: str = BATCH_AXIS):
 
         from tendermint_tpu.crypto import devobs
 
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         n = dev_arrays["pub"].shape[0]
         nshard = int(mesh.devices.size)
         base = edops.bucket_size(n) if bucket else n
@@ -1177,6 +1180,7 @@ def make_sharded_verifier(mesh: Mesh, axis: str = BATCH_AXIS):
         padded = edops._pad_dev(dict(dev_arrays), n, nb)
         extra = None
         if devobs.is_enabled():
+            stage_cpu_s = time.thread_time() - c0
             t_st = time.perf_counter()
             operands = (padded["pub"], padded["r"],
                         padded["s_digits"], padded["k_digits"])
@@ -1193,7 +1197,8 @@ def make_sharded_verifier(mesh: Mesh, axis: str = BATCH_AXIS):
                 t_col = time.perf_counter()
             finally:
                 devobs.ledger_add("staging", -nbytes)
-            extra = {"stage_s": t_st - t0, "h2d_s": t_h2d - t_st,
+            extra = {"stage_s": t_st - t0, "stage_cpu_s": stage_cpu_s,
+                     "h2d_s": t_h2d - t_st,
                      "compute_s": t_cmp - t_h2d,
                      "collect_s": t_col - t_cmp,
                      **devobs.shard_fields(n, nb, nshard)}

@@ -70,9 +70,10 @@ class PipelineFault(Exception):
 
 class _StageTask:
     __slots__ = ("gen", "index", "height", "block", "cert", "state0",
-                 "first")
+                 "first", "parent")
 
-    def __init__(self, gen, index, height, block, cert, state0, first):
+    def __init__(self, gen, index, height, block, cert, state0, first,
+                 parent=None):
         self.gen = gen
         self.index = index
         self.height = height
@@ -80,6 +81,7 @@ class _StageTask:
         self.cert = cert
         self.state0 = state0
         self.first = first
+        self.parent = parent    # the window's span id (libs/trace)
 
 
 class _Staged:
@@ -101,15 +103,16 @@ class _Staged:
 
 
 class _WriteJob:
-    __slots__ = ("gen", "height", "groups", "base")
+    __slots__ = ("gen", "height", "groups", "base", "parent")
 
-    def __init__(self, gen, height, groups, base=None):
+    def __init__(self, gen, height, groups, base=None, parent=None):
         self.gen = gen
         self.height = height          # last height covered by the job
         self.groups = groups          # ordered [(GroupCommitDB, group)]
         # first height covered (durable-stamp attribution; defaults to
         # the last height for callers that don't track a window base)
         self.base = height if base is None else base
+        self.parent = parent          # the window's span id (libs/trace)
 
 
 class BlockPipeline(BaseService):
@@ -240,38 +243,41 @@ class BlockPipeline(BaseService):
         base_h = state.last_block_height + 1
         gdbs = self._group_dbs(executor, store)
         gen = self._begin_window()
+        # the window's span (blocksync.replay_window): the stage worker
+        # and the writer open theirs under it by explicit parent
+        root = trace.current_id()
         wall0 = time.perf_counter()
-        stage_s = apply_s = 0.0
+        wall = stage_s = apply_s = 0.0
         applied = 0
+        since_commit = 0
         faulted = False  # the first unapplied index is always `applied`
+        walked = False   # every block applied: the tail group is owed
         try:
             for gdb in gdbs:
                 gdb.begin_group_mode()
             for i in range(k):
                 self._stage_q.put(_StageTask(
                     gen, i, base_h + i, blocks[i], certifiers[i], state,
-                    first=(i == 0)))
-            since_commit = 0
+                    first=(i == 0), parent=root))
             try:
                 for i in range(k):
-                    staged = self._next_staged(gen)
+                    # the apply loop blocked on staging and the verdict
+                    with trace.span("pipeline.wait_staged", index=i):
+                        staged = self._next_staged(gen)
+                        ok = staged.error is None and \
+                            self._resolve_verify(staged)
                     self._metrics.pipeline_depth.set(
                         self._staged_q.qsize())
-                    if staged.error is not None:
-                        faulted = True
-                        break
-                    ok = self._resolve_verify(staged)
-                    stage_s += staged.stage_s
                     if not ok:
                         faulted = True
                         break
+                    stage_s += staged.stage_s
                     b = blocks[i]
                     h = base_h + i
                     if b.last_commit is not None:
                         # the full LastCommit set rode this block's batch
                         executor.mark_commit_verified(h - 1, b.last_commit)
-                    t0 = time.perf_counter()
-                    with trace.span("pipeline.apply", height=h):
+                    with trace.timed("pipeline.apply", height=h) as sp:
                         try:
                             state = _replay._apply_one(
                                 executor, store, state, b, staged.bid,
@@ -279,36 +285,47 @@ class BlockPipeline(BaseService):
                         except Exception as e:
                             raise _replay.WindowSyncError(
                                 h, str(e), state, applied) from e
-                    apply_s += time.perf_counter() - t0
+                    apply_s += sp.dur_ns * 1e-9
                     applied += 1
                     since_commit += 1
                     if gdbs and since_commit >= self.group_commit_heights:
                         self._enqueue_group(gen, gdbs, h,
-                                            base=h - since_commit + 1)
+                                            base=h - since_commit + 1,
+                                            parent=root)
                         since_commit = 0
-                if not faulted:
-                    last_h = base_h + applied - 1
-                    self._finish_window(gen, gdbs, last_h,
-                                        base=last_h - since_commit + 1)
+                walked = not faulted
             except PipelineFault:
                 faulted = True
-            if not faulted:
-                self._metrics.blocks_applied.inc(applied, path="pipelined")
-                wall = time.perf_counter() - wall0
-                with self._cond:
-                    commit_s = self._commit_s
-                    self.windows_pipelined += 1
-                lane_sum = stage_s + apply_s + commit_s
-                if lane_sum > 0:
-                    self._metrics.apply_overlap_ratio.set(
-                        max(0.0, 1.0 - wall / lane_sum))
-                return state, applied
         except _replay.WindowSyncError:
             # apply failed: authoritative attribution, no strict retry
             self._metrics.blocks_applied.inc(applied, path="pipelined")
             raise
         finally:
-            self._drain(gen, gdbs)
+            # leaving the window, on every exit: the tail group's barrier
+            # (a clean walk only), then the drain.  One span: the
+            # window's return waiting for the writer's last group commit
+            with trace.span("pipeline.drain", applied=applied):
+                try:
+                    if walked:
+                        last_h = base_h + applied - 1
+                        self._finish_window(gen, gdbs, last_h,
+                                            base=last_h - since_commit + 1,
+                                            parent=root)
+                        wall = time.perf_counter() - wall0
+                except PipelineFault:
+                    faulted = True
+                finally:
+                    self._drain(gen, gdbs)
+        if not faulted:
+            self._metrics.blocks_applied.inc(applied, path="pipelined")
+            with self._cond:
+                commit_s = self._commit_s
+                self.windows_pipelined += 1
+            lane_sum = stage_s + apply_s + commit_s
+            if lane_sum > 0:
+                self._metrics.apply_overlap_ratio.set(
+                    max(0.0, 1.0 - wall / lane_sum))
+            return state, applied
         # ---- fallback ladder L1/L2: strict sequential tail ----------------
         # blocks[:applied] stay applied and durable (the drain flushed
         # them); the rest of the stable prefix re-runs the reference
@@ -386,7 +403,7 @@ class BlockPipeline(BaseService):
         return staged.ok
 
     def _enqueue_group(self, gen: int, gdbs, height: int,
-                       base: Optional[int] = None):
+                       base: Optional[int] = None, parent=None):
         """Hand the current buffered generation of every store to the
         async writer as one ordered job.  Writer fault or backpressure
         timeout degrades the window (caller drains synchronously)."""
@@ -401,7 +418,7 @@ class BlockPipeline(BaseService):
                 groups.append((gdb, g))
         if not groups:
             return
-        job = _WriteJob(gen, height, groups, base=base)
+        job = _WriteJob(gen, height, groups, base=base, parent=parent)
         try:
             self._write_q.put(job, timeout=_WRITE_ENQ_TIMEOUT_S)
         except queue.Full:
@@ -410,13 +427,14 @@ class BlockPipeline(BaseService):
             self._jobs_enqueued += 1
 
     def _finish_window(self, gen: int, gdbs, last_height: int,
-                       base: Optional[int] = None):
+                       base: Optional[int] = None, parent=None):
         """End-of-window barrier: enqueue the tail group, wait for the
         writer to drain, surface any writer fault as a PipelineFault
         (the finally-drain then recovers synchronously)."""
         if not gdbs:
             return
-        self._enqueue_group(gen, gdbs, last_height, base=base)
+        self._enqueue_group(gen, gdbs, last_height, base=base,
+                            parent=parent)
         deadline = time.monotonic() + _WRITE_ENQ_TIMEOUT_S
         with self._cond:
             while (self._jobs_done < self._jobs_enqueued
@@ -473,10 +491,10 @@ class BlockPipeline(BaseService):
             if not live:
                 continue
             staged = _Staged(task.gen, task.index, task.height)
-            t0 = time.perf_counter()
+            sp = trace.timed("pipeline.stage", parent=task.parent,
+                             height=task.height, index=task.index)
             try:
-                with trace.span("pipeline.stage", height=task.height,
-                                index=task.index):
+                with sp:
                     fail.inject("pipeline.stage")
                     bid, parts, prefix_items, lc_items = \
                         _replay._collect_block_items(
@@ -508,7 +526,7 @@ class BlockPipeline(BaseService):
                         staged.bits = bits
             except Exception as e:  # noqa: BLE001 - surfaced to apply loop
                 staged.error = e
-            staged.stage_s = time.perf_counter() - t0
+            staged.stage_s = sp.dur_ns * 1e-9
             while not self.quitting.is_set():
                 with self._cond:
                     if task.gen != self._gen:
@@ -532,16 +550,16 @@ class BlockPipeline(BaseService):
             err = None
             dt = 0.0
             if not faulted:
-                t0 = time.perf_counter()
+                sp = trace.timed("pipeline.commit", parent=job.parent,
+                                 height=job.height, groups=len(job.groups))
                 try:
-                    with trace.span("pipeline.commit", height=job.height,
-                                    groups=len(job.groups)):
+                    with sp:
                         fail.inject("pipeline.commit")
                         for gdb, group in job.groups:
                             gdb.commit_group(group)
                 except Exception as e:  # noqa: BLE001 - degrade, not die
                     err = e
-                dt = time.perf_counter() - t0
+                dt = sp.dur_ns * 1e-9
             with self._cond:
                 self._jobs_done += 1
                 if err is not None and self._write_fault is None:

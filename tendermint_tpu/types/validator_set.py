@@ -31,6 +31,7 @@ import numpy as np
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.batch import verify_sigs_bulk
+from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.safemath import (
     INT64_MAX, INT64_MIN, safe_add_clip, safe_mul, safe_sub_clip, trunc_div)
 
@@ -123,8 +124,9 @@ class ValidatorSet:
         return new
 
     def hash(self) -> bytes:
-        return merkle.hash_from_byte_slices(
-            [v.bytes() for v in self.validators])
+        with trace.span("valset.hash", n=len(self.validators)):
+            return merkle.hash_from_byte_slices(
+                [v.bytes() for v in self.validators])
 
     def validate_basic(self):
         if self.is_nil_or_empty():
@@ -363,15 +365,17 @@ class ValidatorSet:
         needed = self.total_voting_power() * 2 // 3
         prefix = []
         tallied = 0
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            prefix.append(idx)
-            tallied += self.validators[idx].voting_power
-            if tallied > needed:
-                break
-        else:
-            raise NotEnoughVotingPowerError(tallied, needed)
+        with trace.span("commit.prefix") as sp:
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                prefix.append(idx)
+                tallied += self.validators[idx].voting_power
+                if tallied > needed:
+                    break
+            else:
+                raise NotEnoughVotingPowerError(tallied, needed)
+            sp.add(prefix=len(prefix))
         return prefix
 
     def verify_commit_light_trusting(self, chain_id: str, commit: Commit,
@@ -390,24 +394,30 @@ class ValidatorSet:
         prefix = []
         vals = []
         tallied = 0
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen_vals:
-                raise CommitVerifyError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen_vals[val_idx]} and {idx})")
-            seen_vals[val_idx] = idx
-            prefix.append(idx)
-            vals.append(val)
-            tallied += val.voting_power
-            if tallied > needed:
-                break
-        else:
-            raise NotEnoughVotingPowerError(tallied, needed)
+        # ONE span around the loop, never one per signature: what the
+        # loop did goes on it as counts
+        with trace.span("commit.match") as sp:
+            lookups = 0
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                lookups += 1
+                val_idx, val = self.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise CommitVerifyError(
+                        f"double vote from validator {val_idx} "
+                        f"({seen_vals[val_idx]} and {idx})")
+                seen_vals[val_idx] = idx
+                prefix.append(idx)
+                vals.append(val)
+                tallied += val.voting_power
+                if tallied > needed:
+                    break
+            else:
+                raise NotEnoughVotingPowerError(tallied, needed)
+            sp.add(scanned=idx + 1, matched=len(prefix), lookups=lookups)
         self._verify_prefix_batch(chain_id, commit, prefix, vals)
 
     def check_commit_no_sigs(self, chain_id: str, block_id: BlockID,
@@ -479,30 +489,35 @@ class ValidatorSet:
 
         from tendermint_tpu.crypto.batch import _use_device
 
-        msgs = commit_sign_bytes_batch(chain_id, commit, idxs)
-        # the raw-pubkey matrix only helps the device route; the host
-        # fallback verifies through the validators' existing PubKey
-        # objects (rebuilding 100k of them would regress that path)
-        mat, all_ed = (self._pub_matrix()
-                       if len(idxs) >= 32 and _use_device()
-                       else (None, False))
-        # the matrix rows are index-aligned with self.validators; that
-        # matches idxs only on the check-all/light paths.  The trusting
-        # path matches validators BY ADDRESS across different sets, so
-        # vals[j] need not be validators[idxs[j]] — verify alignment by
-        # identity (pointer compares, ~10 ms at 100k) before using rows
-        nvals = len(self.validators)
-        aligned = mat is not None and all(
-            idxs[j] < nvals and self.validators[idxs[j]] is vals[j]
-            for j in range(len(vals)))
-        if aligned:
-            pubs = mat if len(idxs) == mat.shape[0] else \
-                mat[np.asarray(idxs, dtype=np.int64)]
-        else:
-            pubs = [v.pub_key for v in vals]
-        bits = verify_sigs_bulk(pubs, msgs,
-                                [commit.signatures[i].signature
-                                 for i in idxs])
+        with trace.span("commit.collect", n=len(idxs)) as sp:
+            msgs = commit_sign_bytes_batch(chain_id, commit, idxs)
+            # the raw-pubkey matrix only helps the device route; the host
+            # fallback verifies through the validators' existing PubKey
+            # objects (rebuilding 100k of them would regress that path)
+            cached = getattr(self, "_pubmat_cache", None)
+            mat, all_ed = (self._pub_matrix()
+                           if len(idxs) >= 32 and _use_device()
+                           else (None, False))
+            # the matrix rows are index-aligned with self.validators;
+            # that matches idxs only on the check-all/light paths.  The
+            # trusting path matches validators BY ADDRESS across
+            # different sets, so vals[j] need not be
+            # validators[idxs[j]] — verify alignment by identity
+            # (pointer compares, ~10 ms at 100k) before using rows
+            nvals = len(self.validators)
+            aligned = mat is not None and all(
+                idxs[j] < nvals and self.validators[idxs[j]] is vals[j]
+                for j in range(len(vals)))
+            if aligned:
+                pubs = mat if len(idxs) == mat.shape[0] else \
+                    mat[np.asarray(idxs, dtype=np.int64)]
+            else:
+                pubs = [v.pub_key for v in vals]
+            sigs = [commit.signatures[i].signature for i in idxs]
+            sp.add(aligned=aligned,
+                   pubmat_cached=cached is not None
+                   and cached[0] is self.validators)
+        bits = verify_sigs_bulk(pubs, msgs, sigs)
         if not bits.all():
             bad = idxs[int(np.argmin(bits))]
             raise CommitVerifyError(

@@ -13,7 +13,11 @@ records zero spans and costs sub-microsecond per call site.
 from __future__ import annotations
 
 import json
+import os
+import subprocess
+import sys
 import threading
+import time
 import timeit
 import urllib.request
 
@@ -26,6 +30,32 @@ from tendermint_tpu.libs.trace import Tracer
 # ---------------------------------------------------------------------------
 # tracer unit behavior
 # ---------------------------------------------------------------------------
+
+def test_on_by_default_and_zero_switches_it_off(monkeypatch):
+    """The recorder is on unless TM_TPU_TRACE is "0" (as devobs reads
+    TM_TPU_DEVOBS): off at the incident, it would have recorded
+    nothing.  An explicit constructor argument wins over the variable,
+    and disable() is the switch at run time."""
+    monkeypatch.delenv("TM_TPU_TRACE", raising=False)
+    assert Tracer().is_enabled()
+    for value, on in (("0", False), ("1", True), ("", True)):
+        monkeypatch.setenv("TM_TPU_TRACE", value)
+        assert Tracer().is_enabled() is on, value
+    monkeypatch.setenv("TM_TPU_TRACE", "0")
+    assert Tracer(enabled=True).is_enabled()
+    tr = Tracer(capacity=8)
+    with tr.span("off"):
+        pass
+    assert tr.snapshot() == []
+    monkeypatch.delenv("TM_TPU_TRACE")
+    tr = Tracer(capacity=8)
+    with tr.span("on"):
+        pass
+    tr.disable()
+    with tr.span("off again"):
+        pass
+    assert [r["name"] for r in tr.snapshot()] == ["on"]
+
 
 def test_disabled_is_noop_and_records_nothing():
     tr = Tracer(capacity=64, enabled=False)
@@ -66,6 +96,135 @@ def test_disabled_call_site_overhead_sub_microsecond():
     assert per_call < 1e-6, f"disabled instant cost {per_call * 1e9:.0f} ns"
 
 
+def test_span_keeps_its_threads_cpu_time_beside_the_wall():
+    """cpu_ns is the thread's CPU time inside the wall bracket: never
+    more than the wall, near it for a span that computes, far below it
+    for one that waits."""
+    tr = Tracer(capacity=8, enabled=True)
+    with tr.span("sleeps") as sp:
+        time.sleep(0.05)
+    assert sp.dur_ns >= 50e6 and sp.cpu_ns < sp.dur_ns / 5
+    with tr.span("spins"):
+        t_end = time.perf_counter() + 0.02
+        while time.perf_counter() < t_end:
+            pass
+    tr.instant("mark")
+    sleeps, spins, mark = tr.snapshot()
+    assert (sleeps["dur_ns"], sleeps["cpu_ns"]) == (sp.dur_ns, sp.cpu_ns)
+    for r in (sleeps, spins):
+        assert 0 <= r["cpu_ns"] <= r["dur_ns"]
+    # a loaded CI host can take the thread off the processor mid-spin
+    assert spins["cpu_ns"] > spins["dur_ns"] / 4
+    assert mark["dur_ns"] == mark["cpu_ns"] == 0
+    ev = {e["name"]: e for e in tr.chrome_trace()["traceEvents"]}
+    assert ev["sleeps"]["args"]["cpu_us"] == sleeps["cpu_ns"] / 1000.0
+    assert "cpu_us" not in ev["mark"]["args"]
+
+
+def test_spans_go_without_cpu_time_where_the_clock_is_dear(monkeypatch):
+    """Under a sandboxed kernel one read of the thread CPU clock costs
+    microseconds (6 us on the benchmark's hosts): the module times it
+    once and such a host's spans record no cpu_ns."""
+    assert trace._CPU_CLOCK is time.thread_time_ns    # cheap here
+
+    def dear():
+        t_end = time.perf_counter_ns() + 5000
+        while time.perf_counter_ns() < t_end:
+            pass
+        return 0
+
+    monkeypatch.setattr(time, "thread_time_ns", dear)
+    assert trace._cheap_cpu_clock() is None
+    monkeypatch.undo()
+    monkeypatch.setattr(trace, "_CPU_CLOCK", None)
+    tr = Tracer(capacity=8, enabled=True)
+    with tr.span("s") as sp:
+        pass
+    assert sp.cpu_ns is None and tr.snapshot()[0]["cpu_ns"] is None
+    assert "cpu_us" not in tr.chrome_trace()["traceEvents"][0]["args"]
+
+
+def test_timed_hands_back_its_duration_on_and_off():
+    """trace.timed is the one clock pair of a site that also feeds an
+    operator metric: the same dur_ns goes into the ring and back to the
+    caller, and with the recorder off the caller still gets it."""
+    tr = Tracer(capacity=8, enabled=True)
+    with tr.timed("t", k=1) as sp:
+        time.sleep(0.002)
+    assert tr.snapshot()[0]["dur_ns"] == sp.dur_ns >= 2e6
+    tr.disable()
+    with tr.timed("t") as off:
+        time.sleep(0.002)
+    assert off.dur_ns >= 2e6 and off.span_id is None
+    assert off.add(x=1) is off and len(tr.snapshot()) == 1
+
+
+def test_spans_stamp_perf_counter():
+    """One clock, stated: a span's start lies between two
+    perf_counter_ns reads taken around its entry (the benchmark lays
+    its own perf_counter rows beside the program's spans)."""
+    tr = Tracer(capacity=8, enabled=True)
+    a = time.perf_counter_ns()
+    with tr.span("s"):
+        b = time.perf_counter_ns()
+    tr.instant("i")
+    c = time.perf_counter_ns()
+    s, i = tr.snapshot()
+    assert a <= s["ts_ns"] <= b <= i["ts_ns"] <= c
+
+
+_NO_JAX = """
+import sys
+from tendermint_tpu.libs import trace
+assert trace.is_enabled()
+with trace.span("batch.verify"):
+    trace.instant("batch.verdict")
+assert len(trace.snapshot()) == 2
+bad = sorted(m for m in sys.modules if m == "jax" or m.startswith("jax."))
+assert not bad, bad
+"""
+
+
+def test_trace_never_imports_jax():
+    """libs/trace is imported by every hot-path module and by processes
+    that must stay off the chip (the ABCI app, the signer): recording
+    spans must not pull jax in."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("TM_TPU_TRACE", None)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", _NO_JAX], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_spans_lie_in_a_profiler_session(tmp_path):
+    """With jax loaded, a span enters a TraceAnnotation of its own name:
+    inside a profiler session it is in the profile's host plane, on the
+    profile's clock."""
+    import glob
+
+    import jax
+
+    tr = Tracer(capacity=8, enabled=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("light.verify", height=7):
+            with tr.span("valset.hash"):
+                time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    names = {e.name
+             for pl in jax.profiler.ProfileData.from_file(path).planes
+             if not pl.name.startswith("/device:")
+             for ln in pl.lines for e in ln.events}
+    assert {"light.verify", "valset.hash"} <= names
+
+
 def test_ring_buffer_wraparound_keeps_newest():
     tr = Tracer(capacity=16, enabled=True)
     for i in range(40):
@@ -93,6 +252,7 @@ def test_parent_linkage_nesting_and_cross_thread():
         def worker():
             with tr.span("lane", parent=parent):
                 pass
+            tr.instant("settled", parent=parent)
         t = threading.Thread(target=worker)
         t.start()
         t.join()
@@ -100,6 +260,7 @@ def test_parent_linkage_nesting_and_cross_thread():
     assert by_name["child"]["parent"] == by_name["root"]["id"]
     assert by_name["mark"]["parent"] == by_name["child"]["id"]
     assert by_name["lane"]["parent"] == by_name["root"]["id"]
+    assert by_name["settled"]["parent"] == by_name["root"]["id"]
     assert by_name["root"]["parent"] is None
     assert by_name["lane"]["tid"] != by_name["root"]["tid"]
 
