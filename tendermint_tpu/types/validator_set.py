@@ -25,7 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from fractions import Fraction
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -61,6 +61,10 @@ def _sort_by_voting_power(vals: List[Validator]):
 
 
 class ValidatorSet:
+    # (the validators list it was built for, {address: lowest index}):
+    # see _address_index
+    _addr_index = None
+
     def __init__(self, validators: Optional[List[Validator]] = None):
         """NewValidatorSet semantics (reference :71-86): copies, validates,
         sorts, and advances proposer priority once."""
@@ -75,10 +79,12 @@ class ValidatorSet:
     # -- basic accessors ---------------------------------------------------
 
     def __getstate__(self):
-        # the pub-matrix cache is derived state (and holds numpy arrays
-        # the safe codec rightly refuses); never persist it
+        # the pub-matrix cache and the address index are derived state
+        # (the first holds numpy arrays the safe codec rightly refuses);
+        # never persist them
         d = dict(self.__dict__)
         d.pop("_pubmat_cache", None)
+        d.pop("_addr_index", None)
         return d
 
     def size(self) -> int:
@@ -87,14 +93,39 @@ class ValidatorSet:
     def is_nil_or_empty(self) -> bool:
         return len(self.validators) == 0
 
+    def _address_index(self) -> Dict[bytes, int]:
+        """{address: position in self.validators}, built on first use and
+        memoised on the validators list object by a retained reference,
+        as _pub_matrix keys its cache: every set mutation assigns a fresh
+        list, so the memo falls with it (_update_with_change_set, which
+        sorts its fresh list in place, drops it by hand).  A repeated
+        address (from_proto rejects none) resolves to its FIRST position,
+        as a front-to-back scan does: the trusting check's double-vote
+        detection is keyed by what this returns.  Never mutated once
+        built, so copy() may share it."""
+        index = self._built_address_index()
+        if index is None:
+            index = {}
+            for i, v in enumerate(self.validators):
+                index.setdefault(v.address, i)
+            self._addr_index = (self.validators, index)
+        return index
+
+    def _built_address_index(self) -> Optional[Dict[bytes, int]]:
+        """The memoised index if it is of the current validators list."""
+        memo = self._addr_index
+        if memo is not None and memo[0] is self.validators:
+            return memo[1]
+        return None
+
     def has_address(self, address: bytes) -> bool:
-        return any(v.address == address for v in self.validators)
+        return address in self._address_index()
 
     def get_by_address(self, address: bytes) -> Tuple[int, Optional[Validator]]:
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v.copy()
-        return -1, None
+        i = self._address_index().get(address)
+        if i is None:
+            return -1, None
+        return i, self.validators[i].copy()
 
     def get_by_index(self, index: int):
         if index < 0 or index >= len(self.validators):
@@ -121,6 +152,10 @@ class ValidatorSet:
         new.validators = [v.copy() for v in self.validators]
         new.proposer = self.proposer
         new._total_voting_power = self._total_voting_power
+        index = self._built_address_index()
+        if index is not None:
+            # same addresses in the same order: the copy need not rebuild
+            new._addr_index = (new.validators, index)
         return new
 
     def hash(self) -> bytes:
@@ -242,6 +277,9 @@ class ValidatorSet:
             PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power())
         self._shift_by_avg_proposer_priority()
         _sort_by_voting_power(self.validators)
+        # the one in-place reordering of a validators list: an index
+        # built on it since _apply_updates would now name wrong rows
+        self._addr_index = None
 
     def _verify_removals(self, deletes: List[Validator]) -> int:
         removed = 0
@@ -382,7 +420,11 @@ class ValidatorSet:
                                      trust_level: Fraction):
         """Reference :770-821 — votes are matched by address (the commit may
         belong to a *different* validator set); verify the minimal prefix
-        crossing trust_level of OUR total power."""
+        crossing trust_level of OUR total power.  The matched validators
+        are the set's own objects, not copies (nothing below writes to
+        them), so a commit signed by this very set in this order is
+        recognised as row-aligned by _verify_sigs_batch and verified from
+        the cached pubkey matrix, as verify_commit_light's is."""
         if trust_level.denominator == 0:
             raise ValueError("trustLevel has zero Denominator")
         total_mul, overflow = safe_mul(self.total_voting_power(),
@@ -397,19 +439,24 @@ class ValidatorSet:
         # ONE span around the loop, never one per signature: what the
         # loop did goes on it as counts
         with trace.span("commit.match") as sp:
+            # whether THIS call pays for the index
+            index_built = self._built_address_index() is None
+            index = self._address_index()
+            validators = self.validators
             lookups = 0
             for idx, cs in enumerate(commit.signatures):
                 if not cs.for_block():
                     continue
                 lookups += 1
-                val_idx, val = self.get_by_address(cs.validator_address)
-                if val is None:
+                val_idx = index.get(cs.validator_address)
+                if val_idx is None:
                     continue
                 if val_idx in seen_vals:
                     raise CommitVerifyError(
                         f"double vote from validator {val_idx} "
                         f"({seen_vals[val_idx]} and {idx})")
                 seen_vals[val_idx] = idx
+                val = validators[val_idx]
                 prefix.append(idx)
                 vals.append(val)
                 tallied += val.voting_power
@@ -417,7 +464,8 @@ class ValidatorSet:
                     break
             else:
                 raise NotEnoughVotingPowerError(tallied, needed)
-            sp.add(scanned=idx + 1, matched=len(prefix), lookups=lookups)
+            sp.add(scanned=idx + 1, matched=len(prefix), lookups=lookups,
+                   index_built=index_built)
         self._verify_prefix_batch(chain_id, commit, prefix, vals)
 
     def check_commit_no_sigs(self, chain_id: str, block_id: BlockID,
@@ -499,10 +547,11 @@ class ValidatorSet:
                            if len(idxs) >= 32 and _use_device()
                            else (None, False))
             # the matrix rows are index-aligned with self.validators;
-            # that matches idxs only on the check-all/light paths.  The
-            # trusting path matches validators BY ADDRESS across
-            # different sets, so vals[j] need not be
-            # validators[idxs[j]] — verify alignment by identity
+            # that matches idxs always on the check-all/light paths.
+            # The trusting path matches validators BY ADDRESS, possibly
+            # across different sets: vals[j] is validators[idxs[j]]
+            # when the commit was signed by this set in this order, and
+            # need not be otherwise — verify alignment by identity
             # (pointer compares, ~10 ms at 100k) before using rows
             nvals = len(self.validators)
             aligned = mat is not None and all(

@@ -116,7 +116,57 @@ def test_trusting_check_at_1000_validators_records_a_dozen_spans(recorder):
     spans = recorder.snapshot()
     assert len(spans) <= 12, sorted(r["name"] for r in spans)
     (m,) = _names(spans, "commit.match")
-    assert m["attrs"] == {"scanned": 334, "matched": 334, "lookups": 334}
+    counts = {"scanned": 334, "matched": 334, "lookups": 334}
+    assert m["attrs"] == {**counts, "index_built": True}
+    # the index is the set's: a second check on the same set finds it
+    vals.verify_commit_light_trusting(gdoc.chain_id, commits[0],
+                                      Fraction(1, 3))
+    assert _names(recorder.snapshot(), "commit.match")[-1]["attrs"] == \
+        {**counts, "index_built": False}
+
+
+class _CountedAddress(bytes):
+    """An address that counts how often it is compared."""
+    compares = 0
+    __hash__ = bytes.__hash__
+
+    def __eq__(self, other):
+        _CountedAddress.compares += 1
+        return bytes.__eq__(self, other)
+
+
+@pytest.mark.parametrize("index", ["cold", "built"])
+def test_trusting_check_at_1000_validators_is_linear_in_its_lookups(
+        recorder, monkeypatch, index):
+    """Counts, not times: the match loop compares about one address a
+    lookup (a scan from the front compares lookups x validators / 2 of
+    them: 55,945 here) and copies no validator, whether or not it has to
+    build the index first."""
+    from tendermint_tpu.types.commit import Commit, CommitSig
+    from tendermint_tpu.types.validator import Validator
+
+    gdoc, privs = make_genesis(1000, power=1)
+    _, commits, states = build_chain(gdoc, privs, 1)
+    vals, c = states[0].validators, commits[0]
+    counted = Commit(c.height, c.round, c.block_id, [
+        CommitSig(s.block_id_flag, _CountedAddress(s.validator_address),
+                  s.timestamp, s.signature) for s in c.signatures])
+    if index == "built":
+        assert vals.has_address(vals.validators[0].address)
+    copies = []
+    copy = Validator.copy
+    monkeypatch.setattr(Validator, "copy",
+                        lambda v: copies.append(v) or copy(v))
+    recorder.reset()
+    _CountedAddress.compares = 0
+    vals.verify_commit_light_trusting(gdoc.chain_id, counted,
+                                      Fraction(1, 3))
+    compares = _CountedAddress.compares
+    (m,) = _names(recorder.snapshot(), "commit.match")
+    assert m["attrs"] == {"scanned": 334, "matched": 334, "lookups": 334,
+                          "index_built": index == "cold"}
+    assert copies == []
+    assert 334 <= compares <= 2 * 334
 
 
 # ---------------------------------------------------------------------------

@@ -180,6 +180,143 @@ def test_valset_hash_changes_with_membership():
     assert vs.hash() != h1
 
 
+# --- the address index behind get_by_address / has_address -----------------
+
+ABSENT = bytes(20)
+
+
+def _scan(vs, address):
+    """The plain front-to-back scan the index replaced: the reference."""
+    for i, v in enumerate(vs.validators):
+        if v.address == address:
+            return i
+    return -1
+
+
+def _assert_lookups_are_the_scan(vs):
+    for v in list(vs.validators):
+        i, got = vs.get_by_address(v.address)
+        assert i == _scan(vs, v.address) == vs.validators.index(v)
+        assert got == v and got is not v
+        assert vs.has_address(v.address)
+    assert vs.get_by_address(ABSENT) == (-1, None)
+    assert not vs.has_address(ABSENT)
+
+
+def _indexed_set(n=9):
+    """A set whose index has been built and used, and its key pairs."""
+    pairs = _mkvals(n, power=lambda i: 10 + i % 3)
+    vs = ValidatorSet([v for _, v in pairs])
+    _assert_lookups_are_the_scan(vs)
+    return vs, pairs
+
+
+def _lookup_mid_update(vs, changes):
+    """What the explicit drop after the in-place sort is for: a lookup
+    between _apply_updates and the sort builds the index on the fresh
+    list in its unsorted order."""
+    shift = vs._shift_by_avg_proposer_priority
+
+    def shift_after_a_lookup():
+        assert vs.has_address(changes[0].address)
+        shift()
+
+    vs._shift_by_avg_proposer_priority = shift_after_a_lookup
+    vs.update_with_change_set(changes)
+
+
+# each gives the set to look at, or changes `vs` in place and gives None
+INDEX_CASES = {
+    "constructor": lambda vs, pairs: vs,
+    "from_proto": lambda vs, pairs: ValidatorSet.from_proto(vs.proto()),
+    "copy": lambda vs, pairs: vs.copy(),
+    "copy_of_a_fresh_set":
+        lambda vs, pairs: ValidatorSet([v for _, v in pairs]).copy(),
+    "copy_increment_proposer_priority":
+        lambda vs, pairs: vs.copy_increment_proposer_priority(3),
+    "addition": lambda vs, pairs: vs.update_with_change_set(
+        [Validator.new(edkeys.PrivKey(bytes([200 + k] * 32)).pub_key(),
+                       5 + 20 * k) for k in range(2)]),
+    # moves the last validator to the front: every position shifts
+    "power_change": lambda vs, pairs: vs.update_with_change_set(
+        [Validator.new(vs.validators[-1].pub_key, 1000)]),
+    "removal": lambda vs, pairs: vs.update_with_change_set(
+        [Validator.new(vs.validators[0].pub_key, 0),
+         Validator.new(vs.validators[4].pub_key, 0)]),
+    "all_three_at_once": lambda vs, pairs: vs.update_with_change_set(
+        [Validator.new(edkeys.PrivKey(bytes([201] * 32)).pub_key(), 11),
+         Validator.new(vs.validators[-1].pub_key, 1000),
+         Validator.new(vs.validators[2].pub_key, 0)]),
+    "lookup_between_merge_and_sort": lambda vs, pairs: _lookup_mid_update(
+        vs, [Validator.new(vs.validators[-1].pub_key, 1000),
+             Validator.new(edkeys.PrivKey(bytes([202] * 32)).pub_key(), 1)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_address_index_agrees_with_a_scan(case):
+    vs, pairs = _indexed_set()
+    before = list(vs.validators)
+    got = INDEX_CASES[case](vs, pairs) or vs
+    _assert_lookups_are_the_scan(got)
+    if got is not vs:            # the source set answers as it did
+        assert vs.validators == before
+        _assert_lookups_are_the_scan(vs)
+
+
+def test_copy_shares_a_built_index_and_a_change_to_either_drops_it_there():
+    vs, pairs = _indexed_set()
+    c = vs.copy()
+    assert c._addr_index[1] is vs._addr_index[1]
+    assert c._addr_index[0] is c.validators
+    gone = c.validators[0].address
+    c.update_with_change_set([Validator.new(c.validators[0].pub_key, 0)])
+    assert not c.has_address(gone) and vs.has_address(gone)
+    _assert_lookups_are_the_scan(c)
+    _assert_lookups_are_the_scan(vs)
+
+
+def test_from_proto_repeated_address_resolves_to_the_first():
+    """from_proto rejects no repeated address; the scan answered with the
+    first, and the trusting check's double-vote detection is keyed by the
+    index it gets."""
+    from tendermint_tpu.libs import protoenc as pe
+    pairs = _mkvals(5)
+    vals = [v for _, v in pairs]
+    twin = Validator(address=vals[1].address, pub_key=vals[3].pub_key,
+                     voting_power=77)
+    body = b"".join(pe.message_field_always(1, v.proto())
+                    for v in vals + [twin, vals[0]])
+    vs = ValidatorSet.from_proto(body)
+    assert [v.address for v in vs.validators].count(vals[1].address) == 2
+    assert vs.get_by_address(vals[1].address) == (1, vals[1])
+    assert vs.get_by_address(vals[0].address) == (0, vals[0])
+    for v in vs.validators:
+        assert vs.get_by_address(v.address)[0] == _scan(vs, v.address)
+
+
+def test_get_by_address_returns_a_copy():
+    vs, pairs = _indexed_set(4)
+    addr = vs.validators[2].address
+    i, v = vs.get_by_address(addr)
+    v.voting_power += 1
+    v.proposer_priority = 12345
+    v.address = ABSENT
+    assert vs.validators[i].voting_power == v.voting_power - 1
+    assert vs.validators[i].proposer_priority != 12345
+    assert vs.get_by_address(addr)[0] == i and not vs.has_address(ABSENT)
+
+
+def test_pickling_carries_no_address_index():
+    import pickle
+    vs, pairs = _indexed_set(4)
+    assert vs._addr_index is not None
+    assert "_addr_index" not in vs.__getstate__()
+    back = pickle.loads(pickle.dumps(vs))
+    assert "_addr_index" not in back.__dict__ and back._addr_index is None
+    _assert_lookups_are_the_scan(back)
+
+
 # --- commit verification over the batch data plane ------------------------
 
 CHAIN = "test-chain"
@@ -268,6 +405,143 @@ def test_light_trusting_different_valset():
     # trusted set = subset of 4 validators (by the same keys)
     sub = ValidatorSet([v for _, v in pairs[:4]])
     sub.verify_commit_light_trusting(CHAIN, commit, Fraction(1, 3))
+
+
+def _trusting_reference(vs, commit, level):
+    """Reference :770-821 as its serial loop reads, every lookup a scan:
+    what the check must match, raise for, or fall short by."""
+    needed = vs.total_voting_power() * level.numerator // level.denominator
+    seen, prefix, tallied = {}, [], 0
+    for idx, cs in enumerate(commit.signatures):
+        if not cs.for_block():
+            continue
+        vi = _scan(vs, cs.validator_address)
+        if vi < 0:
+            continue
+        if vi in seen:
+            return "double", (vi, seen[vi], idx)
+        seen[vi] = idx
+        prefix.append((idx, vi))
+        tallied += vs.validators[vi].voting_power
+        if tallied > needed:
+            return "prefix", prefix
+    return "short", (tallied, needed)
+
+
+@pytest.fixture
+def trusting(monkeypatch):
+    """A 120-validator commit, its own set, and an overlapping set of 100
+    of its signers (and 5 strangers) in another order; the device route's
+    choice of input (rows of the cached pubkey matrix when aligned, key
+    objects when not) taken as on a chip, every signature then verified
+    on the host; the recorder on."""
+    import numpy as np
+    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.libs import trace
+    from tendermint_tpu.types import validator_set as vsmod
+
+    calls = []
+
+    def bulk(pubs, msgs, sigs):
+        rows = isinstance(pubs, np.ndarray)
+        keys = [edkeys.PubKey(bytes(p)) for p in pubs] if rows \
+            else list(pubs)
+        calls.append({"rows": rows, "keys": [k.bytes() for k in keys],
+                      "sigs": list(sigs)})
+        return np.array([k.verify_signature(msgs[j], sigs[j])
+                         for j, k in enumerate(keys)], dtype=bool)
+
+    monkeypatch.setattr(batch, "_use_device", lambda: True)
+    monkeypatch.setattr(vsmod, "verify_sigs_bulk", bulk)
+    pairs = _mkvals(120)
+    own, _, commit = _make_commit(pairs, absent={5}, nil={9})
+    other = ValidatorSet(
+        [Validator.new(priv.pub_key(), 10 + i % 7)
+         for i, (priv, _) in enumerate(pairs[10:110])]
+        + [Validator.new(edkeys.PrivKey(bytes([240 + k] * 32)).pub_key(),
+                         15) for k in range(5)])
+    trace.enable()
+    trace.reset()
+    yield {"own": own, "other": other, "commit": commit, "calls": calls,
+           "spans": lambda name: [r for r in trace.snapshot()
+                                  if r["name"] == name]}
+    trace.disable()
+    trace.reset()
+
+
+def _tamper(commit, idx):
+    sig = commit.signatures[idx].signature
+    commit.signatures[idx].signature = bytes([sig[0] ^ 1]) + sig[1:]
+
+
+@pytest.mark.parametrize("which", ["own", "other"])
+def test_light_trusting_matches_the_scanning_reference(trusting, which):
+    """The same commit against (a) its own set and (b) an overlapping set
+    in another order: the prefix the reference's scan gives, each
+    signature against its own key, from matrix rows in (a) alone."""
+    vs, commit = trusting[which], trusting["commit"]
+    level = Fraction(1, 3)
+    kind, prefix = _trusting_reference(vs, commit, level)
+    assert kind == "prefix" and len(prefix) >= 32
+    vs.verify_commit_light_trusting(CHAIN, commit, level)
+    (call,) = trusting["calls"]
+    assert call["sigs"] == [commit.signatures[i].signature
+                            for i, _ in prefix]
+    assert call["keys"] == [vs.validators[vi].pub_key.bytes()
+                            for _, vi in prefix]
+    (match,) = trusting["spans"]("commit.match")
+    assert match["attrs"] == {
+        "scanned": prefix[-1][0] + 1, "matched": len(prefix),
+        "lookups": sum(cs.for_block() for cs in
+                       commit.signatures[:prefix[-1][0] + 1]),
+        "index_built": True}
+    (collect,) = trusting["spans"]("commit.collect")
+    aligned = all(i == vi for i, vi in prefix)
+    assert aligned is (which == "own")
+    assert collect["attrs"]["aligned"] is aligned is call["rows"]
+
+
+@pytest.mark.parametrize("which", ["own", "other"])
+def test_light_trusting_names_the_lowest_bad_index(trusting, which):
+    vs, commit = trusting[which], trusting["commit"]
+    level = Fraction(1, 3)
+    _, prefix = _trusting_reference(vs, commit, level)
+    lanes = [prefix[k][0] for k in (len(prefix) // 2, 3, len(prefix) - 1)]
+    # of a signer the trusted set does not know, or past the prefix: unseen
+    matched, end = {i for i, _ in prefix}, prefix[-1][0]
+    unseen = [i for i in range(end) if i not in matched | {5, 9}][:2] \
+        + [end + 1]
+    for i in lanes + unseen:
+        _tamper(commit, i)
+    with pytest.raises(CommitVerifyError,
+                       match=rf"wrong signature \(#{min(lanes)}\)"):
+        vs.verify_commit_light_trusting(CHAIN, commit, level)
+    for i in lanes:
+        _tamper(commit, i)
+    vs.verify_commit_light_trusting(CHAIN, commit, level)
+
+
+@pytest.mark.parametrize("which", ["own", "other"])
+def test_light_trusting_double_vote_and_short_power(trusting, which):
+    vs, commit = trusting[which], trusting["commit"]
+    _, prefix = _trusting_reference(vs, commit, Fraction(1, 3))
+    (first, vi), (second, _) = prefix[2], prefix[7]
+    honest = commit.signatures[second]
+    commit.signatures[second] = commit.signatures[first]
+    assert _trusting_reference(vs, commit, Fraction(1, 3)) == \
+        ("double", (vi, first, second))
+    with pytest.raises(CommitVerifyError) as ei:
+        vs.verify_commit_light_trusting(CHAIN, commit, Fraction(1, 3))
+    assert str(ei.value) == \
+        f"double vote from validator {vi} ({first} and {second})"
+    commit.signatures[second] = honest
+    # all of the trusted power cannot be exceeded: short, by the same sums
+    kind, (got, needed) = _trusting_reference(vs, commit, Fraction(1, 1))
+    assert kind == "short"
+    with pytest.raises(NotEnoughVotingPowerError) as ei:
+        vs.verify_commit_light_trusting(CHAIN, commit, Fraction(1, 1))
+    assert (ei.value.got, ei.value.needed) == (got, needed)
+    assert not trusting["calls"]
 
 
 def test_commit_hash_covers_signatures():
