@@ -56,6 +56,12 @@ class KVDB:
         """Sorted ascending iteration over keys with the given prefix."""
         raise NotImplementedError
 
+    def iterate_keys(self, prefix: bytes) -> Iterator[bytes]:
+        """The keys of iterate_prefix, in its order, and no value read:
+        for a caller that keeps what it wants to know in the key (the
+        light store's heights, under values of megabytes)."""
+        raise NotImplementedError
+
     def flush(self):
         """Make every accepted write durable (no-op for backends that
         commit per call)."""
@@ -95,6 +101,11 @@ class MemDB(KVDB):
             v = self.get(k)
             if v is not None:
                 yield k, v
+
+    def iterate_keys(self, prefix: bytes):
+        with self._lock:
+            return iter(sorted(k for k in self._data
+                               if k.startswith(prefix)))
 
 
 def prefix_upper_bound(prefix: bytes) -> Optional[bytes]:
@@ -203,21 +214,30 @@ class SQLiteDB(KVDB):
                     "DELETE FROM kv WHERE k = ?", [(bytes(k),) for k in deletes])
             self._commit_locked()
 
-    def iterate_prefix(self, prefix: bytes):
+    def _scan(self, columns: str, prefix: bytes) -> list:
         hi = prefix_upper_bound(prefix)
         with self._lock:
             if hi is None:
-                rows = self._conn.execute(
-                    "SELECT k, v FROM kv WHERE k >= ? ORDER BY k",
+                return self._conn.execute(
+                    f"SELECT {columns} FROM kv WHERE k >= ? ORDER BY k",
                     (prefix,)).fetchall()
-            else:
-                rows = self._conn.execute(
-                    "SELECT k, v FROM kv WHERE k >= ? AND k < ? ORDER BY k",
-                    (prefix, hi)).fetchall()
-        for k, v in rows:
+            return self._conn.execute(
+                f"SELECT {columns} FROM kv WHERE k >= ? AND k < ? "
+                f"ORDER BY k", (prefix, hi)).fetchall()
+
+    def iterate_prefix(self, prefix: bytes):
+        for k, v in self._scan("k, v", prefix):
             k = bytes(k)
             if k.startswith(prefix):
                 yield k, bytes(v)
+
+    def iterate_keys(self, prefix: bytes):
+        # answered from the primary key's index alone: the table's rows,
+        # where the values lie, are not visited
+        for (k,) in self._scan("k", prefix):
+            k = bytes(k)
+            if k.startswith(prefix):
+                yield k
 
     def flush(self):
         with self._lock:
@@ -360,16 +380,22 @@ class GroupCommitDB(KVDB):
                 return
         self._inner.write_batch(sets, deletes)
 
-    def iterate_prefix(self, prefix: bytes):
-        with self._lock:
-            over: Dict[bytes, object] = {}
-            for g in self._inflight:
-                for k, v in g.items():
-                    if k.startswith(prefix):
-                        over[k] = v
-            for k, v in self._pending.items():
+    def _buffered_under(self, prefix: bytes) -> Dict[bytes, object]:
+        """Buffered writes (values and tombstones) under `prefix`, the
+        newest of each key; call with the lock held."""
+        over: Dict[bytes, object] = {}
+        for g in self._inflight:
+            for k, v in g.items():
                 if k.startswith(prefix):
                     over[k] = v
+        for k, v in self._pending.items():
+            if k.startswith(prefix):
+                over[k] = v
+        return over
+
+    def iterate_prefix(self, prefix: bytes):
+        with self._lock:
+            over = self._buffered_under(prefix)
         if not over:
             yield from self._inner.iterate_prefix(prefix)
             return
@@ -379,6 +405,17 @@ class GroupCommitDB(KVDB):
             v = merged[k]
             if v is not _TOMBSTONE:
                 yield k, v
+
+    def iterate_keys(self, prefix: bytes):
+        with self._lock:
+            over = self._buffered_under(prefix)
+        keys = set(self._inner.iterate_keys(prefix))
+        for k, v in over.items():
+            if v is _TOMBSTONE:
+                keys.discard(k)
+            else:
+                keys.add(k)
+        return iter(sorted(keys))
 
     def compact(self):
         self.flush()

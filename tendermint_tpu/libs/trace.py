@@ -107,7 +107,15 @@ KNOWN_SPANS = frozenset({
     # it settles)
     "light.coalesce", "light.serve",
     # light/verifier.py — the root of one stateless header verification
+    # (attr `outcome`: ok / cant_trust / error)
     "light.verify",
+    # light/client.py + light/store.py — the light client proper:
+    # light.client.verify is the root of one request (attrs target,
+    # anchor, hops, refused_skips, fetched, saved), light.fetch one
+    # block from the primary, light.detect the witness cross-check, and
+    # the store's load / save (attr bytes) / prune (attr deleted)
+    "light.client.verify", "light.detect", "light.fetch",
+    "light.store.load", "light.store.prune", "light.store.save",
     # types/validator_set.py + light/verifier.py — the host work around
     # a commit's one batched launch, each ONE span per call: the set's
     # merkle hash, the commit's structural checks, the trusting path's

@@ -12,6 +12,7 @@ import threading
 from fractions import Fraction
 from typing import List, Optional
 
+from tendermint_tpu.libs import trace
 from tendermint_tpu.types.basic import Timestamp
 from tendermint_tpu.types.light_block import LightBlock
 
@@ -27,6 +28,7 @@ _SKIP_NUM, _SKIP_DEN = 1, 2
 
 DEFAULT_TRUSTING_PERIOD_S = 14 * 24 * 3600.0  # reference light/client.go
 DEFAULT_MAX_CLOCK_DRIFT_S = 10.0
+DEFAULT_PRUNING_SIZE = 1000  # reference light/client.go defaultPruningSize
 MAX_WITNESS_STRIKES = 3  # consecutive failures before a witness is dropped
 
 
@@ -47,8 +49,12 @@ class Client:
                  store: LightStore,
                  trust_level: Fraction = verifier.DEFAULT_TRUST_LEVEL,
                  max_clock_drift_s: float = DEFAULT_MAX_CLOCK_DRIFT_S,
-                 sequential: bool = False):
+                 sequential: bool = False,
+                 pruning_size: int = DEFAULT_PRUNING_SIZE):
         verifier.validate_trust_level(trust_level)
+        if pruning_size < 1:
+            raise ValueError(
+                f"pruning_size must be at least 1, given {pruning_size}")
         self.chain_id = chain_id
         self.trusting_period_s = trust_options.period_s
         self.trust_level = trust_level
@@ -57,6 +63,13 @@ class Client:
         self.witnesses = list(witnesses)
         self.store = store
         self.sequential = sequential
+        # the store holds the newest `pruning_size` light blocks: it is
+        # pruned to that, oldest first, after every saved trace
+        # (reference client.go updateTrustedLightBlock -> Prune)
+        self.pruning_size = pruning_size
+        # what the request in hand has done so far, for its root span;
+        # written under _lock only
+        self._tally = {"fetched": 0, "hops": 0, "refused_skips": 0}
         self._witness_strikes: dict = {}  # id(provider) -> count
         # fail-safe flag: a client CONFIGURED with witnesses must never
         # silently continue without any (reference errNoWitnesses) — a
@@ -116,38 +129,48 @@ class Client:
     def verify_light_block_at_height(self, height: int,
                                      now: Timestamp) -> LightBlock:
         """Reference client.go:474."""
-        with self._lock:
+        with self._lock, self._request_span(height) as sp:
             got = self.store.get(height)
             if got is not None:
                 return got
             lb = self._from_primary(height)
-            self.verify_light_block(lb, now)
+            self._verify_light_block_locked(lb, now, sp)
             return lb
 
     def verify_light_block(self, lb: LightBlock, now: Timestamp):
         """Reference client.go:558-611: pick sequential vs skipping from the
         nearest trusted anchor; on success cross-check witnesses."""
-        with self._lock:
-            self._verify_light_block_locked(lb, now)
+        with self._lock, self._request_span(lb.height) as sp:
+            self._verify_light_block_locked(lb, now, sp)
 
-    def _verify_light_block_locked(self, lb: LightBlock, now: Timestamp):
+    def _request_span(self, target: int):
+        """The root span of one request, `light.client.verify`; the
+        request's counts (_tally) start at zero with it.  Call with
+        _lock held."""
+        for k in self._tally:
+            self._tally[k] = 0
+        return trace.span("light.client.verify", target=target)
+
+    def _verify_light_block_locked(self, lb: LightBlock, now: Timestamp,
+                                   sp):
         lb.validate_basic(self.chain_id)
         if self.store.get(lb.height) is not None:
             return
         anchor = self.store.latest_before(lb.height)
         if anchor is not None and anchor.height == lb.height:
             return
+        sp.add(anchor=anchor.height if anchor is not None else None)
         if anchor is None:
             # target below the earliest trusted header: walk hash links back
             first = self.store.first()
             if first is None:
                 raise LightClientError("store is empty")
             self._backwards(first, lb)
-            trace = [lb]
+            blocks = [lb]
         elif self.sequential:
-            trace = self._verify_sequential(anchor, lb, now)
+            blocks = self._verify_sequential(anchor, lb, now)
         else:
-            trace = self._verify_skipping(anchor, lb, now)
+            blocks = self._verify_skipping(anchor, lb, now)
         # detect BEFORE persisting: on a divergence nothing from the
         # disputed trace may enter the trusted store (a primary-side
         # attack would otherwise be served as trusted forever after the
@@ -156,25 +179,28 @@ class Client:
         # and detection re-runs over the remaining pool — one garbage
         # witness must not abort an otherwise-valid verify.
         matched: set = set()   # witnesses already polled + agreeing
-        while True:
-            if self._had_witnesses and not self.witnesses:
-                raise LightClientError(
-                    "no witnesses left to cross-check the primary "
-                    "(reference errNoWitnesses): refusing to trust "
-                    "unchallenged headers")
-            div = detect_divergence(self, trace, now, matched)
-            if div is None:
-                break
-            self._handle_divergence(anchor, trace, div, now)
-        for b in trace:
+        with trace.span("light.detect", witnesses=len(self.witnesses)):
+            while True:
+                if self._had_witnesses and not self.witnesses:
+                    raise LightClientError(
+                        "no witnesses left to cross-check the primary "
+                        "(reference errNoWitnesses): refusing to trust "
+                        "unchallenged headers")
+                div = detect_divergence(self, blocks, now, matched)
+                if div is None:
+                    break
+                self._handle_divergence(anchor, blocks, div, now)
+        for b in blocks:
             self.store.save(b)
+        self.store.prune(self.pruning_size)
+        sp.add(saved=len(blocks), **self._tally)
 
     # -- verification strategies ------------------------------------------
 
     def _verify_sequential(self, trusted: LightBlock, target: LightBlock,
                            now: Timestamp) -> List[LightBlock]:
         """Reference client.go:613-704: verify every height in order."""
-        trace = []
+        hops = []
         cur = trusted
         for h in range(trusted.height + 1, target.height + 1):
             lb = target if h == target.height else self._from_primary(h)
@@ -182,8 +208,8 @@ class Client:
                 cur.signed_header, lb.signed_header, lb.validators,
                 self.trusting_period_s, now, self.max_clock_drift_s)
             cur = lb
-            trace.append(lb)
-        return trace
+            hops.append(lb)
+        return hops
 
     def _bisect(self, trusted: LightBlock, target: LightBlock,
                 now: Timestamp, fetch_pivot) -> List[LightBlock]:
@@ -196,7 +222,7 @@ class Client:
         cache = [target]
         depth = 0
         verified = trusted
-        trace: List[LightBlock] = []
+        hops: List[LightBlock] = []
         while True:
             try:
                 verifier.verify(
@@ -206,6 +232,7 @@ class Client:
                     self.trust_level)
             except verifier.NewValSetCantBeTrustedError:
                 # can't skip that far: bisect
+                self._tally["refused_skips"] += 1
                 if depth == len(cache) - 1:
                     pivot = (verified.height
                              + (cache[depth].height - verified.height)
@@ -217,13 +244,14 @@ class Client:
                     f"verification failed {verified.height}->"
                     f"{cache[depth].height}: {e}")
             else:
+                self._tally["hops"] += 1
                 if depth == 0:
-                    trace.append(target)
-                    return trace
+                    hops.append(target)
+                    return hops
                 verified = cache[depth]
                 cache = cache[:depth]
                 depth = 0
-                trace.append(verified)
+                hops.append(verified)
 
     def _verify_skipping(self, trusted: LightBlock, target: LightBlock,
                          now: Timestamp) -> List[LightBlock]:
@@ -247,7 +275,7 @@ class Client:
     # -- divergence handling (reference detector.go:90-180) ----------------
 
     def _handle_divergence(self, anchor: Optional[LightBlock],
-                           trace: List[LightBlock], div: Divergence,
+                           blocks: List[LightBlock], div: Divergence,
                            now: Timestamp):
         """Verify the witness's conflicting chain from the common block
         (reference detector.go examineConflictingHeaderAgainstTrace);
@@ -260,7 +288,7 @@ class Client:
         — the client cannot know which side is honest, so each side's
         evidence goes to the other plus every remaining provider
         (reference detector.go sendEvidence to primary and witnesses)."""
-        chain = ([anchor] if anchor is not None else []) + list(trace)
+        chain = ([anchor] if anchor is not None else []) + list(blocks)
         witness = div.witness
         try:
             common, ev_w, ev_p = examine_divergence(self, chain, div)
@@ -366,7 +394,8 @@ class Client:
         lightBlockFromPrimary + findNewPrimary)."""
         while True:
             try:
-                lb = self.primary.light_block(height)
+                with trace.span("light.fetch", height=height):
+                    lb = self.primary.light_block(height)
             except (LightBlockNotFound, HeightTooHigh):
                 # benign: the primary simply doesn't have it (yet);
                 # switching primaries would not conjure the block
@@ -376,4 +405,5 @@ class Client:
                 continue
             if lb is None:
                 raise LightBlockNotFound(f"no light block at {height}")
+            self._tally["fetched"] += 1
             return lb

@@ -168,14 +168,24 @@ def verify(trusted: SignedHeader, trusted_vals: ValidatorSet,
     """Reference verifier.go:138-152."""
     adjacent = untrusted.height == trusted.height + 1
     with trace.span("light.verify", height=untrusted.height,
-                    adjacent=adjacent):
-        if adjacent:
-            verify_adjacent(trusted, untrusted, untrusted_vals,
-                            trusting_period_s, now, max_clock_drift_s)
-        else:
-            verify_non_adjacent(trusted, trusted_vals, untrusted,
-                                untrusted_vals, trusting_period_s, now,
-                                max_clock_drift_s, trust_level)
+                    adjacent=adjacent) as sp:
+        # `cant_trust` is the bisection's ordinary "skip refused, halve
+        # the distance"; every other refusal is an `error`
+        outcome = "error"
+        try:
+            if adjacent:
+                verify_adjacent(trusted, untrusted, untrusted_vals,
+                                trusting_period_s, now, max_clock_drift_s)
+            else:
+                verify_non_adjacent(trusted, trusted_vals, untrusted,
+                                    untrusted_vals, trusting_period_s, now,
+                                    max_clock_drift_s, trust_level)
+            outcome = "ok"
+        except NewValSetCantBeTrustedError:
+            outcome = "cant_trust"
+            raise
+        finally:
+            sp.add(outcome=outcome)
 
 
 def verify_backwards(untrusted: SignedHeader, trusted: SignedHeader):

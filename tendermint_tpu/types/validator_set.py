@@ -463,6 +463,11 @@ class ValidatorSet:
                 if tallied > needed:
                     break
             else:
+                # the bisection's refused skip: it launches nothing, and
+                # this span is all that says what it cost
+                sp.add(scanned=len(commit.signatures), matched=len(prefix),
+                       lookups=lookups, index_built=index_built,
+                       tallied=tallied, needed=needed)
                 raise NotEnoughVotingPowerError(tallied, needed)
             sp.add(scanned=idx + 1, matched=len(prefix), lookups=lookups,
                    index_built=index_built)

@@ -80,7 +80,8 @@ def test_light_verify_is_one_tree_with_the_lumps_split(recorder, adjacent):
     spans = recorder.snapshot()
     roots, _ = _tree(spans)
     assert [r["name"] for r in roots] == ["light.verify"]
-    assert roots[0]["attrs"] == {"height": target, "adjacent": adjacent}
+    assert roots[0]["attrs"] == {"height": target, "adjacent": adjacent,
+                                 "outcome": "ok"}
     got = {r["name"] for r in spans}
     assert {"valset.hash", "commit.validate_basic", "commit.prefix",
             "commit.collect"} <= got
