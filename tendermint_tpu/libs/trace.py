@@ -118,9 +118,10 @@ KNOWN_SPANS = frozenset({
     "light.store.load", "light.store.prune", "light.store.save",
     # types/validator_set.py + light/verifier.py — the host work around
     # a commit's one batched launch, each ONE span per call: the set's
-    # merkle hash, the commit's structural checks, the trusting path's
-    # match by address, the >2/3 tally, and sign-bytes + pubkey rows up
-    # to the call of verify_sigs_bulk
+    # merkle hash (attr `memo`: answered by the memo on the validators
+    # list, nothing computed), the commit's structural checks, the
+    # trusting path's match by address, the >2/3 tally, and sign-bytes +
+    # pubkey rows up to the call of verify_sigs_bulk
     "commit.collect", "commit.match", "commit.prefix",
     "commit.validate_basic", "valset.hash",
     # networks/ — the in-process multi-node harness (ADR-019)

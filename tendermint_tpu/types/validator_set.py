@@ -64,6 +64,8 @@ class ValidatorSet:
     # (the validators list it was built for, {address: lowest index}):
     # see _address_index
     _addr_index = None
+    # (the validators list it was computed from, its merkle root): see hash
+    _hash_memo = None
 
     def __init__(self, validators: Optional[List[Validator]] = None):
         """NewValidatorSet semantics (reference :71-86): copies, validates,
@@ -79,12 +81,14 @@ class ValidatorSet:
     # -- basic accessors ---------------------------------------------------
 
     def __getstate__(self):
-        # the pub-matrix cache and the address index are derived state
-        # (the first holds numpy arrays the safe codec rightly refuses);
-        # never persist them
+        # the pub-matrix cache, the address index and the root are
+        # derived state (the first holds numpy arrays the safe codec
+        # rightly refuses); never persist them: a set that arrives as
+        # bytes hashes its own bytes, no memo vouches for them
         d = dict(self.__dict__)
         d.pop("_pubmat_cache", None)
         d.pop("_addr_index", None)
+        d.pop("_hash_memo", None)
         return d
 
     def size(self) -> int:
@@ -156,12 +160,39 @@ class ValidatorSet:
         if index is not None:
             # same addresses in the same order: the copy need not rebuild
             new._addr_index = (new.validators, index)
+        root = self._memoised_root()
+        if root is not None:
+            # same keys and powers in the same order: the same root
+            new._hash_memo = (new.validators, root)
         return new
 
     def hash(self) -> bytes:
-        with trace.span("valset.hash", n=len(self.validators)):
-            return merkle.hash_from_byte_slices(
-                [v.bytes() for v in self.validators])
+        """Merkle root over Validator.bytes() (pubkey + voting power; the
+        proposer priority is not in a leaf), computed once per validators
+        list and memoised on the list object by a retained reference, as
+        _pub_matrix and _address_index key theirs: every membership
+        change assigns a fresh list (_update_with_change_set, which sorts
+        its fresh list in place, drops the memo by hand), copy() carries
+        the root to its own list, and __getstate__ leaves it behind.
+        The invariant it rests on, with theirs: a Validator inside a
+        set's list is never assigned a new pub_key or voting_power;
+        changes go through update_with_change_set.  The span is opened
+        on every call and says whether the memo answered."""
+        root = self._memoised_root()
+        with trace.span("valset.hash", n=len(self.validators),
+                        memo=root is not None):
+            if root is None:
+                root = merkle.hash_from_byte_slices(
+                    [v.bytes() for v in self.validators])
+                self._hash_memo = (self.validators, root)
+            return root
+
+    def _memoised_root(self) -> Optional[bytes]:
+        """The memoised root if it is of the current validators list."""
+        memo = self._hash_memo
+        if memo is not None and memo[0] is self.validators:
+            return memo[1]
+        return None
 
     def validate_basic(self):
         if self.is_nil_or_empty():
@@ -278,8 +309,10 @@ class ValidatorSet:
         self._shift_by_avg_proposer_priority()
         _sort_by_voting_power(self.validators)
         # the one in-place reordering of a validators list: an index
-        # built on it since _apply_updates would now name wrong rows
+        # built on it since _apply_updates would now name wrong rows,
+        # and a root computed on it another leaf order
         self._addr_index = None
+        self._hash_memo = None
 
     def _verify_removals(self, deletes: List[Validator]) -> int:
         removed = 0

@@ -141,6 +141,38 @@ def test_a_request_of_256_heights_is_four_refused_skips_and_four_hops():
         assert m["scanned"] == m["lookups"] == 100
 
 
+def test_a_request_of_256_heights_hashes_nine_times_and_computes_four():
+    """The request above on blocks as a provider delivers them (decoded,
+    so no set has been hashed in this process): the client's own
+    validate_basic of the target and the first verify TO each of the
+    pivots compute a root, the five later hashes of those four sets are
+    answered by the memo."""
+    from tendermint_tpu.libs import safe_codec
+    wanted = targets(256, 1)
+    t = wanted[0]
+    blocks = {h: safe_codec.loads(safe_codec.dumps(lb))
+              for h, lb in blocks_for(5, wanted).items()}
+    client, _ = make_pair(blocks, 1000)
+    seq = trace.last_seq()
+    got, asked, calls, _ = ask(client, t)
+    assert got is blocks[t] and asked == [t, t - 128, t - 192, t - 64]
+    assert len(calls) == 8
+    spans = trace.snapshot(since=seq)
+    hashed = [r for r in spans if r["name"] == "valset.hash"]
+    assert [r["attrs"]["n"] for r in hashed] == [100] * 9
+    # a verify hashes the set it verifies TO, once
+    by_id = {r["id"]: r for r in spans}
+    under = [by_id[r["parent"]]["attrs"].get("height")
+             if by_id[r["parent"]]["name"] == "light.verify" else None
+             for r in hashed]
+    assert under == [None] + [h for h, _ in calls]
+    assert [r["attrs"]["memo"] for r in hashed] == [
+        False,                  # lb.validate_basic(t)
+        True, False, False,     # a -> t, a -> t-128, a -> t-192
+        True, True, True,       # t-192 -> t, t-192 -> t-128, t-128 -> t
+        False, True]            # t-128 -> t-64, t-64 -> t
+
+
 @pytest.mark.parametrize("where", ["pivot_trusting_prefix",
                                    "target_light_prefix"])
 def test_a_tampered_lane_is_refused_and_the_store_untouched(where):

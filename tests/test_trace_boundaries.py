@@ -30,6 +30,7 @@ from tendermint_tpu.store.block_store import BlockStore
 from tendermint_tpu.types.basic import (BlockID, PartSetHeader,
                                         SignedMsgType, Timestamp)
 from tendermint_tpu.types.light_block import SignedHeader
+from tendermint_tpu.types.validator_set import ValidatorSet
 from tendermint_tpu.types.vote import Vote
 from tendermint_tpu.types.vote_set import VoteSet
 
@@ -104,6 +105,25 @@ def test_light_verify_is_one_tree_with_the_lumps_split(recorder, adjacent):
         assert m["attrs"]["lookups"] >= m["attrs"]["matched"]
         assert m["attrs"]["scanned"] >= m["attrs"]["lookups"]
         assert len(collected) == 2
+
+
+def test_one_hash_span_a_verify_says_whether_the_memo_answered(recorder):
+    """A set's first hash computes (`memo` false), every later one is
+    answered by the memo on its validators list, and either way a verify
+    records exactly one `valset.hash` span (light.hash_ms sums them,
+    light.hash_memo_share reads the attribute)."""
+    gdoc, privs = make_genesis(40)
+    blocks, commits, states = build_chain(gdoc, privs, 5)
+    sh = {h: SignedHeader(blocks[h - 1].header, commits[h - 1])
+          for h in (3, 4)}
+    # as a provider delivers it: decoded, so never hashed in this process
+    delivered = ValidatorSet.from_proto(states[3].validators.proto())
+    for memo in (False, True, True):
+        recorder.reset()
+        verifier.verify(sh[3], states[2].validators, sh[4], delivered,
+                        PERIOD, NOW, 10.0, Fraction(1, 3))
+        (hashed,) = _names(recorder.snapshot(), "valset.hash")
+        assert hashed["attrs"] == {"n": 40, "memo": memo}
 
 
 def test_trusting_check_at_1000_validators_records_a_dozen_spans(recorder):
@@ -232,6 +252,33 @@ def test_unpipelined_window_names_its_path(recorder):
                              commits[:1])
     root = _names(recorder.snapshot(), "blocksync.replay_window")[-1]
     assert root["attrs"]["path"] == "strict"
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_a_replayed_window_computes_each_sets_root_once(recorder, pipelined):
+    """validate_block hashes state.validators and state.next_validators in
+    every block and the window's stability check both once more; a set
+    that no block changes is computed once (the state's two set objects
+    at genesis), because update_state's per-block copies carry the root."""
+    gdoc, privs = make_genesis(4)
+    blocks, commits, _ = build_chain(gdoc, privs, 6)
+    ex = BlockExecutor(StateStore(MemDB()), KVStoreApplication())
+    pipeline.set_config(enable=pipelined, depth=3, group_commit_heights=2)
+    try:
+        recorder.reset()
+        state, n = replay_window(ex, BlockStore(MemDB()),
+                                 state_from_genesis(gdoc), blocks, commits,
+                                 max_window=6)
+    finally:
+        pipeline.set_config(enable=False)
+    assert n == 6
+    memos = [r["attrs"]["memo"]
+             for r in _names(recorder.snapshot(), "valset.hash")]
+    assert len(memos) >= 2 * 6 + 2
+    assert memos.count(False) == 2
+    # and the state handed back keeps it: the next window computes nothing
+    assert state.validators._memoised_root() == state.validators.hash()
+    assert state.next_validators._memoised_root() is not None
 
 
 # ---------------------------------------------------------------------------
