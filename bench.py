@@ -57,7 +57,6 @@ def _make_batch(n):
     return pubs, msgs, sigs
 
 
-RLC_BATCH = 1 << 14  # sharded-RLC config batch (BENCH_RLC_BATCH overrides)
 COMB_BATCH = 1 << 13  # comb config batch (BENCH_COMB_BATCH overrides)
 
 
@@ -193,90 +192,17 @@ def _trace_artifact(tag: str):
 
 def _make_batch_selfhosted(n):
     """Batch built with the in-repo signer (OpenSSL when available,
-    pure-Python otherwise) — the RLC config must degrade cleanly even on
+    pure-Python otherwise) — these configs must degrade cleanly even on
     hosts without the `cryptography` package."""
     from tendermint_tpu.crypto import ed25519 as edkeys
 
     npool = 64
     privs = [edkeys.PrivKey((i + 1).to_bytes(32, "little"))
              for i in range(npool)]
-    msgs = [b"rlc bench vote sign bytes %16d" % i for i in range(n)]
+    msgs = [b"self bench vote sign bytes %16d" % i for i in range(n)]
     sigs = [privs[i % npool].sign(m) for i, m in enumerate(msgs)]
     pubs = [privs[i % npool].pub_key().bytes() for i in range(n)]
     return pubs, msgs, sigs
-
-
-def _rlc_main():
-    """Sharded-RLC config (BENCH_RLC=1): end-to-end throughput of the
-    mesh-routed RLC/MSM fast path through ops/ed25519.verify_batch —
-    per-shard partial Pippenger sums psum-reduced on the local mesh.
-    Emits ONE JSON line like the headline; exits non-zero without an
-    accelerator."""
-    _require_accelerator()
-    t_start = time.time()
-    from tendermint_tpu.crypto import ed25519 as edkeys
-    from tendermint_tpu.libs import trace
-
-    # host baseline: per-signature verify through the same PubKey wrapper
-    # the node uses (OpenSSL when present)
-    nbase = 400
-    bpubs, bmsgs, bsigs = _make_batch_selfhosted(nbase)
-    keys = [edkeys.PubKey(p) for p in bpubs]
-    with trace.span("bench.host_baseline", n=nbase) as sp:
-        t0 = time.perf_counter()
-        for i in range(nbase):
-            assert keys[i].verify_signature(bmsgs[i], bsigs[i])
-        cpu_rate = nbase / (time.perf_counter() - t0)
-        sp.add(sigs_per_s=round(cpu_rate))
-    _rlc_device_bench(cpu_rate, t_start)
-
-
-def _rlc_device_bench(cpu_rate, t_start):
-    import jax
-
-    from tendermint_tpu.ops import ed25519 as edops
-    from tendermint_tpu.ops import msm
-
-    n = int(os.environ.get("BENCH_RLC_BATCH", RLC_BATCH))
-    pubs, msgs, sigs = _make_batch_selfhosted(n)
-    prev_rlc = msm._enabled_override
-    msm.set_enabled(True)
-    try:
-        # warmup/compile, and the all-valid fast path must actually vouch
-        out = edops.verify_batch(pubs, msgs, sigs)
-        assert out.all(), "rlc path rejected valid signatures"
-        route = msm.last_route()
-        # outcome "vouched" means the fast path really accepted the
-        # batch; anything else means we'd be timing the per-sig
-        # fallback and labeling it RLC
-        assert str(route["path"]).startswith("rlc") and \
-            route.get("outcome") == "vouched", route
-        rates = []
-        for _ in range(ROUNDS):
-            t0 = time.perf_counter()
-            out = edops.verify_batch(pubs, msgs, sigs)
-            rates.append(n / (time.perf_counter() - t0))
-            assert out.all()
-        _emit({
-            "metric": "ed25519_rlc_sharded_verify_e2e",
-            "value": round(max(rates), 1),
-            # whole-MESH throughput, not per chip: the sharded MSM runs
-            # across every local device (shard count in the note)
-            "unit": "sigs/s",
-            "vs_baseline": round(max(rates) / cpu_rate, 2),
-            "median_value": round(float(np.median(rates)), 1),
-            "median_vs_baseline": round(float(np.median(rates)) / cpu_rate,
-                                        2),
-            # route is authoritative: it records what actually ran, not
-            # what the policy would model
-            "note": f"rlc path={route['path']} shards={route['shards']}",
-            "trace": _trace_artifact("rlc"),
-        })
-        print(f"# cpu_baseline={cpu_rate:.0f}/s platform="
-              f"{jax.devices()[0].platform} route={route} "
-              f"total_bench_s={time.time()-t_start:.0f}", file=sys.stderr)
-    finally:
-        msm.set_enabled(prev_rlc)  # restore, don't clobber
 
 
 def _sched_main():
@@ -1674,41 +1600,26 @@ def _light_main():
 
 def _mesh_leg_worker():
     """One mesh-scaling leg (BENCH_MESH_WORKER=<ndev>), run in its own
-    process so the XLA_FLAGS host-device forcing and — for the global
-    leg (BENCH_MESH_NPROC=2) — jax.distributed initialization see a
-    fresh runtime.  Drives the PRODUCTION ops/ed25519.verify_batch seam
-    (the local overlapped mesh plane, or the ADR-027 global plane under
-    lockstep when distributed), and writes one JSON record to
-    $BENCH_MESH_OUT for the parent to aggregate.  On a backend without
-    multi-process computations the global leg degrades through the
-    plane's latch-off and reports global_latched_off=true — the capture
-    stays honest instead of dying rc=1."""
+    process so the XLA_FLAGS host-device forcing sees a fresh runtime.
+    Drives the PRODUCTION ops/ed25519.verify_batch seam (the local
+    overlapped mesh plane), and writes one JSON record to
+    $BENCH_MESH_OUT for the parent to aggregate."""
     import jax
 
     # forced host devices are CPU devices: pin the platform in config
     # as well as in the parent's env, so a chip on this host is never
     # claimed by a leg that does not measure it
     jax.config.update("jax_platforms", "cpu")
-    nproc = int(os.environ.get("BENCH_MESH_NPROC", "1"))
-    pid = int(os.environ.get("BENCH_MESH_PID", "0"))
-    if nproc > 1:
-        jax.distributed.initialize(
-            coordinator_address=os.environ["BENCH_MESH_COORD"],
-            num_processes=nproc, process_id=pid)
     n = int(os.environ.get("BENCH_MESH_BATCH", "4096"))
     rounds = int(os.environ.get("BENCH_MESH_ROUNDS", str(ROUNDS)))
     pubs, msgs, sigs = _make_batch_selfhosted(n)
 
     from tendermint_tpu.crypto import devobs
     from tendermint_tpu.ops import ed25519 as edops
-    from tendermint_tpu.parallel import sharding as shd
 
     devobs.enable()  # the leg's record wants the chunk_overlap ratio
 
     def once():
-        if nproc > 1:
-            with shd.lockstep():
-                return edops.verify_batch(pubs, msgs, sigs)
         return edops.verify_batch(pubs, msgs, sigs)
 
     # warmup compiles the leg's bucket(s); correctness stays LOUD
@@ -1722,29 +1633,26 @@ def _mesh_leg_worker():
     ll = edops.last_launch()
     with open(os.environ["BENCH_MESH_OUT"], "w") as f:
         json.dump({
-            "ndev": len(jax.devices()), "nproc": nproc, "pid": pid,
+            "ndev": len(jax.devices()),
             "sigs_per_s": round(max(rates), 1),
             "median_sigs_per_s": round(float(np.median(rates)), 1),
             "path": ll.get("path"), "shards": ll.get("shards"),
             "chunk_overlap": ll.get("chunk_overlap"),
-            "global_latched_off": shd._GLOBAL_PLANE is False,
         }, f)
 
 
 def run_mesh_scaling(counts=(1, 2, 4, 8), batch=None, rounds=None,
-                     include_global=True, timeout_s=900.0) -> dict:
+                     timeout_s=900.0) -> dict:
     """Mesh-scaling core (shared by BENCH_MESH=1 and bench_report
     config17; ADR-027): one subprocess per device count, each forcing
     <ndev> host CPU devices and pushing the same self-signed batch
-    through the production verify_batch seam, plus the 2-process x
-    4-device global-mesh leg (jax.distributed over loopback).  Every
+    through the production verify_batch seam.  Every
     leg is a fresh process because XLA fixes the device count at
-    backend init.  Returns {"rows", "global", "failures", ...};
+    backend init.  Returns {"rows", "failures", ...};
     scaling_efficiency is rate_N / (N * rate_1) against the 1-device
     leg.  A leg that dies or times out lands in "failures" with its
     log tail — the callers degrade it to a host-fallback line (rc=0),
     never a crash."""
-    import socket
     import subprocess
     import tempfile
 
@@ -1755,9 +1663,9 @@ def run_mesh_scaling(counts=(1, 2, 4, 8), batch=None, rounds=None,
     tmp = tempfile.mkdtemp(prefix="bench_mesh_")
     me = os.path.abspath(__file__)
 
-    def spawn(ndev, tag, nproc=1, coord="", pid=0):
-        out = os.path.join(tmp, f"leg_{tag}.{pid}.json")
-        log = os.path.join(tmp, f"leg_{tag}.{pid}.log")
+    def spawn(ndev, tag):
+        out = os.path.join(tmp, f"leg_{tag}.json")
+        log = os.path.join(tmp, f"leg_{tag}.log")
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
@@ -1766,10 +1674,7 @@ def run_mesh_scaling(counts=(1, 2, 4, 8), batch=None, rounds=None,
         env.update({"BENCH_MESH_WORKER": str(ndev),
                     "BENCH_MESH_OUT": out,
                     "BENCH_MESH_BATCH": str(batch),
-                    "BENCH_MESH_ROUNDS": str(rounds),
-                    "BENCH_MESH_NPROC": str(nproc),
-                    "BENCH_MESH_PID": str(pid),
-                    "BENCH_MESH_COORD": coord})
+                    "BENCH_MESH_ROUNDS": str(rounds)})
         return subprocess.Popen([sys.executable, me], env=env,
                                 stdout=open(log, "wb"),
                                 stderr=subprocess.STDOUT), out, log
@@ -1801,22 +1706,12 @@ def run_mesh_scaling(counts=(1, 2, 4, 8), batch=None, rounds=None,
         if recs:
             rows.append(recs[0])
 
-    gl = None
-    if include_global and os.environ.get("BENCH_MESH_GLOBAL") != "0":
-        with socket.socket() as s:
-            s.bind(("127.0.0.1", 0))
-            coord = f"127.0.0.1:{s.getsockname()[1]}"
-        recs = harvest([spawn(4, "global", nproc=2, coord=coord, pid=k)
-                        for k in range(2)], "global")
-        if recs:
-            gl = recs[0]  # pid 0's record; both verified identically
-
     base = next((r for r in rows if r["ndev"] == 1), None)
-    for r in rows + ([gl] if gl else []):
+    for r in rows:
         if base and base["sigs_per_s"]:
             r["scaling_efficiency"] = round(
                 r["sigs_per_s"] / (r["ndev"] * base["sigs_per_s"]), 3)
-    return {"rows": rows, "global": gl, "failures": failures,
+    return {"rows": rows, "failures": failures,
             "batch": batch, "rounds": rounds}
 
 
@@ -1824,8 +1719,8 @@ def _mesh_main():
     """Mesh-scaling config (BENCH_MESH=1, ADR-027, bench_report
     config17): per-device-count sigs/s through the production
     verify_batch seam on forced host devices, the staging
-    chunk_overlap ratio, scaling efficiency vs the 1-device leg, and
-    the 2-process global-mesh leg.  One JSON line per leg that ran,
+    chunk_overlap ratio and scaling efficiency vs the 1-device leg.
+    One JSON line per leg that ran,
     each appended to bench_history so bench_trend gets a
     per-device-count series; a dead leg prints no line and the mode
     exits non-zero."""
@@ -1855,29 +1750,11 @@ def _mesh_main():
             "note": (f"path={row.get('path')} shards={row.get('shards')} "
                      f"forced host devices, batch={r['batch']}"),
         })
-    gl = r["global"]
-    if gl is not None:
-        note = (f"global-mesh 2proc x 4dev, batch={r['batch']}"
-                if gl.get("path") == "global-mesh" else
-                "global plane latched off (backend lacks multi-process "
-                f"computations), local-mesh degrade path={gl.get('path')}")
-        _emit({
-            "metric": "ed25519_mesh_verify_global_2x4",
-            "value": gl["sigs_per_s"],
-            "unit": "sigs/s",
-            "vs_baseline": round(gl["sigs_per_s"] / cpu_rate, 2),
-            "median_value": gl["median_sigs_per_s"],
-            "chunk_overlap": gl.get("chunk_overlap"),
-            "scaling_efficiency": gl.get("scaling_efficiency"),
-            "global_latched_off": gl.get("global_latched_off"),
-            "note": note,
-        })
     for f in r["failures"]:
         print(f"# mesh leg {f['leg']} failed rc={f['rc']}: {f['tail']}",
               file=sys.stderr)
     print(f"# mesh bench: cpu_baseline={cpu_rate:.0f}/s "
           f"legs={[row['ndev'] for row in r['rows']]} "
-          f"global={'ok' if gl else 'failed/skipped'} "
           f"total_bench_s={time.time()-t_start:.0f}", file=sys.stderr)
     if r["failures"]:
         # a dead leg has no line: its number is missing, not the host's
@@ -1919,9 +1796,6 @@ def main():
         return
     if os.environ.get("BENCH_BLOCKSYNC") == "1":
         _blocksync_main()
-        return
-    if os.environ.get("BENCH_RLC") == "1":
-        _rlc_main()
         return
     if os.environ.get("BENCH_SCHED") == "1":
         _sched_main()

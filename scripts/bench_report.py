@@ -364,51 +364,6 @@ def config6_verify_commit_100k(n=100_000, cpu_sample=4000):
             "speedup": round(cpu_100k_s / best, 1), **_launch_cols(base)}
 
 
-def config7_rlc_sharded(n=8192):
-    """Mesh-sharded RLC/MSM fast path through the production
-    ops/ed25519.verify_batch seam: per-shard partial Pippenger bucket
-    sums reduced on the local mesh before the single cofactored check.
-    Reports which path actually ran (rlc-sharded / rlc-single / per-sig)
-    so a capture where the policy declined or the combination fell back
-    is visible as such."""
-    from bench import _make_batch_selfhosted
-    from tendermint_tpu.ops import ed25519 as edops
-    from tendermint_tpu.ops import msm
-    from tendermint_tpu.parallel.sharding import data_plane
-
-    pubs, msgs, sigs = _make_batch_selfhosted(n)
-    prev_rlc = msm._enabled_override
-    msm.set_enabled(True)
-    try:
-        # warm (compiles the MSM shape bucket; cached per process)
-        assert edops.verify_batch(pubs, msgs, sigs).all()
-        t0 = time.perf_counter()
-        reps = 5
-        for _ in range(reps):
-            assert edops.verify_batch(pubs, msgs, sigs).all()
-        dt = (time.perf_counter() - t0) / reps
-        route = msm.last_route()
-    finally:
-        msm.set_enabled(prev_rlc)  # restore, don't clobber
-    plane = data_plane()
-    # path is only honest when outcome == "vouched": a dispatch that
-    # overflowed fell back to (and timed) the per-sig ladder — then the
-    # occupancy that matters is the per-sig LAUNCH's (last_launch
-    # records it), not the bounced RLC attempt's
-    if route.get("outcome") == "vouched":
-        path, nb, n_real = route.get("path"), route.get("nb"), route["n"]
-    else:
-        path = "per-sig"
-        rec = edops.last_launch()
-        nb, n_real = rec.get("nb"), rec.get("n")
-    return {"config": f"7: sharded-RLC MSM ({n} sigs)",
-            "wall_s": round(dt, 3), "sigs_per_s": round(n / dt),
-            "path": path, "outcome": route.get("outcome"),
-            "occupancy": round(n_real / nb, 3) if nb else None,
-            "shards": route.get("shards"),
-            "mesh_devices": plane.nshard if plane is not None else 1}
-
-
 def config8_scheduler(n_subs=16, per_sub=64):
     """VerifyScheduler pipelined-vs-sync (crypto/scheduler.py): n_subs
     concurrent consumers each holding a per_sub-signature fragment —
@@ -679,9 +634,9 @@ def config16_light(validators=48, heights=12, clients=16):
 
 
 def config17_mesh(counts=(1, 2, 4), batch=1024):
-    """Global mesh data plane (parallel/sharding.py, ADR-027): forced-
-    host-device scaling legs through the production verify_batch seam
-    plus the 2-process global-mesh leg, each in its own subprocess
+    """The local mesh data plane (parallel/sharding.py, ADR-027): forced-
+    host-device scaling legs through the production verify_batch seam,
+    each in its own subprocess
     (XLA fixes the device count at backend init, so in-process legs
     are impossible).  Columns mirror the BENCH_MESH=1 bench.py lines:
     per-device-count sigs/s, the staging chunk_overlap ratio, and
@@ -697,12 +652,6 @@ def config17_mesh(counts=(1, 2, 4), batch=1024):
         line[f"scaling_eff_{nd}dev"] = row.get("scaling_efficiency")
         if row.get("chunk_overlap") is not None:
             line[f"chunk_overlap_{nd}dev"] = row["chunk_overlap"]
-    gl = r.get("global")
-    if gl:
-        line["global_sigs_per_s"] = gl["sigs_per_s"]
-        line["global_path"] = gl.get("path")
-        line["global_latched_off"] = gl.get("global_latched_off")
-        line["global_scaling_eff"] = gl.get("scaling_efficiency")
     if r["failures"]:
         line["failed_legs"] = [f["leg"] for f in r["failures"]]
     return line
@@ -721,7 +670,7 @@ def main():
         cpu_line = "cpu_openssl=unavailable (no cryptography package)"
     print(f"# platform={platform} {cpu_line}", flush=True)
     fns = (config2_commit_150, config3_light_10k, config4_blocksync,
-           config5_mixed, config6_verify_commit_100k, config7_rlc_sharded,
+           config5_mixed, config6_verify_commit_100k,
            config8_scheduler, config9_comb, config10_mempool,
            config11_consensus, config12_statesync, config13_control,
            config14_propose, config15_gossip, config16_light,

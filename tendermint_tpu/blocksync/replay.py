@@ -222,22 +222,9 @@ def _replay_window(sp, executor, store, state, blocks, certifiers,
         if collected >= 1:
             # replay class on the shared verify scheduler (coalesces
             # with whatever consensus/light work is in flight, below
-            # their priority); exact BatchVerifier semantics either way.
-            # On a multi-process runtime (jax.distributed initialized)
-            # this is a lockstep-safe site: every process replays the
-            # same window in the same order, so the batch may enter the
-            # global mesh collective (ADR-027) — coordinated=True skips
-            # the scheduler, whose coalescing with process-local
-            # traffic would break the cross-process shape agreement
-            from tendermint_tpu.parallel import sharding
-            if sharding.global_mesh_ready():
-                with sharding.lockstep():
-                    all_ok, _bits = vsched.verify_items(
-                        items, vsched.Priority.BLOCKSYNC,
-                        coordinated=True)
-            else:
-                all_ok, _bits = vsched.verify_items(
-                    items, vsched.Priority.BLOCKSYNC)
+            # their priority); exact BatchVerifier semantics either way
+            all_ok, _bits = vsched.verify_items(
+                items, vsched.Priority.BLOCKSYNC)
             if all_ok:
                 sp.add(path="coalesced")
                 for i in range(collected):

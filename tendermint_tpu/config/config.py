@@ -171,11 +171,6 @@ class BatchVerifierConfig:
     """TPU data-plane routing (no reference analog — the new component)."""
     tpu_threshold: int = 32
     enable: bool = True
-    # opt-in to the cofactored RLC batch fast path (ops/msm.py).  OFF by
-    # default for wire-compat: RLC uses ZIP-215/cofactored semantics, the
-    # reference Go verifier is cofactorless, and a mixed fleet could be
-    # chain-split by an adversarial small-order-component signature.
-    rlc: bool = False
     # secp256k1 TPU lane (ops/secp.py).  ON by default since ADR-015:
     # verdicts are exact either way, the lane only engages when an
     # accelerator is attached, and it runs under the full degradation
@@ -619,7 +614,6 @@ serve_burst = {self.state_sync.serve_burst}
 [batch_verifier]
 tpu_threshold = {self.batch_verifier.tpu_threshold}
 enable = {str(self.batch_verifier.enable).lower()}
-rlc = {str(self.batch_verifier.rlc).lower()}
 secp_lane = {str(self.batch_verifier.secp_lane).lower()}
 comb = {str(self.batch_verifier.comb).lower()}
 table_cache_mb = {self.batch_verifier.table_cache_mb}
@@ -796,7 +790,6 @@ propose_max_bytes = {c.propose_max_bytes}
         cfg.batch_verifier = BatchVerifierConfig(
             tpu_threshold=bv.get("tpu_threshold", 32),
             enable=bv.get("enable", True),
-            rlc=bool(bv.get("rlc", False)),
             secp_lane=bool(bv.get("secp_lane", True)),
             comb=bool(bv.get("comb", True)),
             table_cache_mb=int(bv.get("table_cache_mb", 256)),

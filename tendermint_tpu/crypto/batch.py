@@ -395,7 +395,7 @@ def _context_priority_name() -> str:
 
 def _device_verifier(tname: str):
     """The TPU lane for a key scheme, or None if that scheme stays on the
-    host.  ed25519: the fused ladder / RLC MSM stack (ops/ed25519.py);
+    host.  ed25519: the comb / fused ladder routes (ops/ed25519.py);
     sr25519: same curve, ristretto lane (ops/sr25519.py); secp256k1:
     the Jacobian Straus lane (ops/secp.py), default-on since ADR-015 —
     TM_TPU_SECP_LANE=0 / [batch_verifier] secp_lane=false is the
@@ -457,8 +457,7 @@ def _host_verify_items(tname: str, items, assume_miss: bool = False,
 
 
 def verify_sigs_bulk(pubs: Sequence[PubKey], msgs, sigs: Sequence[bytes],
-                     tpu_threshold: int = 32,
-                     coordinated: bool = False) -> np.ndarray:
+                     tpu_threshold: int = 32) -> np.ndarray:
     """Bitmap for n (pub, msg, sig) triples without per-item _Item objects
     — the whole-commit path (types/validator_set.py), where n can be 100k+
     and BatchVerifier's per-item add/dispatch bookkeeping would cost more
@@ -478,32 +477,10 @@ def verify_sigs_bulk(pubs: Sequence[PubKey], msgs, sigs: Sequence[bytes],
     device alone), and the (n, 32) raw-pubkey-matrix input — that is
     the validator-set per-block hot path whose device-resident pubkey
     cache ships 96 B/sig with zero per-key objects (ADR-008), and
-    coalescing could only add copies and restage resident keys.
-
-    coordinated=True: the caller asserts every process of a
-    multi-process runtime performs this exact bulk verify in the same
-    order (a coordinated catch-up / audit sweep, ADR-027): the call
-    runs inside a sharding.lockstep() window so the batch may enter
-    the global mesh collective, and the scheduler is skipped (its
-    coalescing with process-local traffic would break cross-process
-    shape agreement)."""
-    from contextlib import ExitStack
-    with ExitStack() as stack:
-        if coordinated:
-            from tendermint_tpu.parallel import sharding
-            if sharding.global_mesh_ready():
-                stack.enter_context(sharding.lockstep())
-            else:
-                coordinated = False
-        return _verify_sigs_bulk(pubs, msgs, sigs, tpu_threshold,
-                                 coordinated)
-
-
-def _verify_sigs_bulk(pubs, msgs, sigs, tpu_threshold: int,
-                      coordinated: bool) -> np.ndarray:
+    coalescing could only add copies and restage resident keys."""
     n = len(pubs)
     sch = None
-    if n and not coordinated and not isinstance(pubs, np.ndarray):
+    if n and not isinstance(pubs, np.ndarray):
         from tendermint_tpu.crypto import scheduler as vsched
         sch = vsched.running()
     if sch is not None and n <= sch.max_batch:

@@ -455,33 +455,20 @@ class DeviceLaneRuntime:
         self.metrics.device_launches.inc(site=site)
         # the launch runs on the lane worker thread: capture the caller's
         # span id HERE so the worker's span links into the caller's tree
-        # (the thread-local stack doesn't cross the pool boundary).
-        # The lockstep mark (parallel/sharding, ADR-027) is thread-local
-        # for the same reason and crosses the boundary the same way —
-        # without re-arming it on the worker, a coordinated caller's
-        # batch would silently lose its global-mesh eligibility here
+        # (the thread-local stack doesn't cross the pool boundary)
         parent = trace.current_id()
-        from tendermint_tpu.parallel import sharding
-        locked = sharding.in_lockstep()
 
         def _launch():
             _lane_tls.clock = self._compile_clock
             with trace.span("device.launch", parent=parent, site=site):
                 fail.inject(site)
-                if locked:
-                    with sharding.lockstep():
-                        return fn(*args)
                 return fn(*args)
         try:
-            f = self._get_pool().submit(_launch)
+            return self._get_pool().submit(_launch)
         except Exception as e:  # noqa: BLE001 - e.g. pool at shutdown
             f = _cf.Future()
             f.set_exception(e)
-        # collect() reads this on a wedge: a lockstep launch that times
-        # out is the global collective's signature hang (a peer never
-        # entered), and the latch must trip on the FIRST one
-        f.tm_lockstep = locked
-        return f
+            return f
 
     def _await(self, fut: _cf.Future):
         """fut.result() under the launch deadline, its clock stopped
@@ -542,18 +529,6 @@ class DeviceLaneRuntime:
                     reason = "timeout"
                     self._quarantine_pool()
                     fut.cancel()
-                    from tendermint_tpu.parallel import sharding
-                    if getattr(fut, "tm_lockstep", False) and \
-                            sharding.global_mesh_ready():
-                        # a coordinated launch wedged past the deadline
-                        # on a multi-process runtime means a collective
-                        # a peer never entered: latch the global plane
-                        # off NOW (and poison it job-wide) rather than
-                        # burning one launch deadline per subsequent
-                        # batch — the worst case for a purely local
-                        # wedge is an overly cautious fallback,
-                        # verification stays exact either way
-                        sharding.disable_global_plane()
             except Exception as e:  # noqa: BLE001 - any fault degrades
                 reason = "integrity" if isinstance(e, DeviceLaneError) \
                     else "raise"
@@ -642,9 +617,9 @@ def reset():
 
 def publish_route(path, outcome, n=None, nb=None, compile_s=None):
     """The ONE bridge from a dispatch-route decision (ops/ed25519
-    _record_launch, ops/msm _set_route) into CryptoMetrics: route
-    counter at set time (labeled by outcome, so a bounced RLC attempt
-    is never mistaken for the fast path engaging), lane occupancy, and
+    _record_launch and the routes' declines) into CryptoMetrics: route
+    counter at set time (labeled by outcome, so a declined route is
+    never mistaken for one that launched), lane occupancy, and
     the first-launch compile split.  Swallows everything —
     observability must never break verification."""
     try:

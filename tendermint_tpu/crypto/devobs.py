@@ -12,8 +12,7 @@ or what each cost (tens of seconds per lane bucket).  This
 module is the launch-level twin of consensus/observatory.py: a bounded
 ring of per-launch records with a phase decomposition, fed by every
 dispatch that funnels through ops/ed25519._set_last_launch (the ladder,
-comb, split and mesh paths via _record_launch, and the RLC/MSM route
-mirror from ops/msm._set_route).
+comb, split and mesh paths via _record_launch).
 
 Per-launch phases (seconds; a path records the ones it can honestly
 measure — see the instrumentation notes in ops/ed25519.verify_batch and
@@ -115,7 +114,7 @@ def shard_fields(n: int, nb: int, shards: int) -> dict:
     ceil(n/per) shards holding real rows.  Exact for single-chunk
     launches (the overwhelmingly common case); chunked mesh launches
     reuse it as an approximation of the total per-shard-position load.
-    Shared by ops/ed25519._comb_try and both parallel/sharding mesh
+    Shared by ops/ed25519._run_comb and both parallel/sharding mesh
     paths so the model can't drift between them."""
     if shards <= 1 or nb < shards:
         return {}
@@ -263,8 +262,8 @@ class DevObs:
                 else:
                     inv["hits"] += 1
                     # a record may claim first_launch for a key the
-                    # inventory saw without a wall (an RLC route
-                    # mirror): attribute the compile wall once
+                    # inventory saw without a wall: attribute the
+                    # compile wall once
                     if r.get("first_launch") and \
                             inv.get("compile_s") is None:
                         inv["compile_s"] = r.get("wall_s")

@@ -988,20 +988,13 @@ def context_priority(default: Priority) -> Tuple[Priority, Optional[float]]:
 
 def verify_items(items: Sequence, prio: Priority = Priority.COMMIT,
                  deadline: Optional[float] = None,
-                 populate_cache: bool = True,
-                 coordinated: bool = False) -> Tuple[bool, np.ndarray]:
+                 populate_cache: bool = True) -> Tuple[bool, np.ndarray]:
     """Drop-in synchronous wrapper with BatchVerifier.verify()'s exact
     (all_valid, bitmap) contract.  Routes through the global scheduler
     when it is running; otherwise — or if the scheduler sheds, stops, or
     times out mid-flight — verifies directly through a private
-    BatchVerifier, so callers never observe a behavior change.
-
-    coordinated=True: the caller is inside a sharding.lockstep() window
-    (every process of a multi-process runtime walks this exact call,
-    ADR-027) — SKIP the scheduler, whose coalescing would merge
-    process-local traffic into the batch and break the cross-process
-    shape agreement the global mesh collective requires."""
-    s = None if coordinated else running()
+    BatchVerifier, so callers never observe a behavior change."""
+    s = running()
     if s is not None:
         try:
             fut = s.submit(items, prio, deadline=deadline,

@@ -135,7 +135,6 @@ LOCK_ORDER = {
     "tendermint_tpu/ops/ed25519.py:DeviceLRU._lock": 48,
     "tendermint_tpu/ops/ed25519.py:_base_comb_lock": 52,
     "tendermint_tpu/ops/ed25519.py:_launch_lock": 54,
-    "tendermint_tpu/ops/msm.py:_route_lock": 56,
     "tendermint_tpu/parallel/sharding.py:_PLANE_LOCK": 57,
     "tendermint_tpu/parallel/sharding.py:_DataPlane._lock": 58,
 

@@ -136,7 +136,7 @@ RULES = [
          "A jnp array construction, np/jnp.pad, or jitted-kernel call "
          "whose size derives from a raw `len(batch)` instead of the "
          "registered bucket helpers (bucket_size, _comb_k_pad, "
-         "msm_bucket, chunk constants).  Every such site mints a fresh "
+         "chunk constants).  Every such site mints a fresh "
          "XLA shape class per batch size and silently burns the tier-1 "
          "compile budget."),
     Rule("TM102", "uncached-jit-in-function", "ops/, parallel/",

@@ -148,15 +148,28 @@ def find_fstring_backslashes(src: str) -> List[Tuple[int, str]]:
     backslash escape INSIDE an open f-string (e.g. the seed-era
     f"{chr(10).join(...)}" written as f"{'\\n'.join(...)}") — tracked
     via fstring depth so the rule still fires for a developer editing
-    on a newer interpreter than the 3.10 container."""
+    on a newer interpreter than the 3.10 container.  A BARE backslash
+    in the expression part (f"{x\t}") is no token at all there: the
+    tokenizer stops with "unexpected character after line continuation
+    character", and an error at a position inside an open f-string is
+    the finding."""
     out: List[Tuple[int, str]] = []
-    try:
-        tokens = list(tokenize.generate_tokens(
-            io.StringIO(src).readline))
-    except (tokenize.TokenError, IndentationError, SyntaxError):
-        return out
     fstart = getattr(tokenize, "FSTRING_START", None)
     fend = getattr(tokenize, "FSTRING_END", None)
+    tokens = []
+    try:
+        for tok in tokenize.generate_tokens(io.StringIO(src).readline):
+            tokens.append(tok)
+    except tokenize.TokenError as e:
+        open_fstrings = sum((t.type == fstart) - (t.type == fend)
+                            for t in tokens)
+        if fstart is not None and open_fstrings > 0 and len(e.args) > 1 \
+                and "line continuation" in str(e.args[0]):
+            line = e.args[1][0]
+            out.append((line, src.splitlines()[line - 1].strip()[:40]))
+        return out
+    except (IndentationError, SyntaxError):
+        return out
     fdepth = 0
     for tok in tokens:
         if fstart is not None:

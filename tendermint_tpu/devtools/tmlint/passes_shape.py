@@ -49,8 +49,7 @@ BUCKET_HELPERS = {
     "bucket_size",        # ops/ed25519: pow2 lane bucket, floor MIN_BUCKET
     "_comb_k_pad",        # ops/ed25519: validator-axis pow2 bucket
     "_pad_dev",           # ops/ed25519: pad staged dict to a bucket
-    "msm_bucket",         # parallel/sharding: mesh MSM bucket policy
-    "worth_sharding_msm",  # parallel/sharding: bucket-memory policy
+    "_split_chunk",       # ops/ed25519: lanes per split-path launch
 }
 
 # jit entries callable across module boundaries (module-local entries

@@ -45,7 +45,6 @@ ENTRY_NAMES = [
     ("tendermint_tpu.ops.ed25519", "verify_kernel"),
     ("tendermint_tpu.ops.ed25519", "comb_kernel"),
     ("tendermint_tpu.ops.ed25519", "comb_build_kernel"),
-    ("tendermint_tpu.ops.msm", "_msm_core"),
     ("tendermint_tpu.ops.sr25519", "_verify_core"),
     ("tendermint_tpu.ops.secp", "_verify_core"),
 ]

@@ -54,21 +54,19 @@ _fired: Dict[Tuple[str, str], int] = {}
 
 REGISTERED_SITES = frozenset({
     # device-kernel entry seams (ops/)
-    "ops.ed25519.verify_batch",   # the ladder/RLC/comb dispatch seam
+    "ops.ed25519.verify_batch",   # the ladder/comb dispatch seam
     "ops.ed25519.comb",           # the fixed-base comb route (ADR-013)
     "ops.sr25519.verify_batch",   # the ristretto lane seam
     "ops.secp.verify_batch",      # the secp256k1 Straus lane seam
     #                               (default-on since ADR-015)
     # mesh data-plane seams (parallel/sharding.py, ADR-027): the
-    # overlapped per-shard staging of the local compact path, the mesh
-    # comb dispatch, and the cross-process global plane — a raise at
-    # any of them degrades that batch to the next-inner path
-    # (single-device ladder / single-device comb / local mesh) with
-    # exact bitmaps, caught inside ops/ed25519 rather than escaping to
-    # the degrade runtime
+    # overlapped per-shard staging of the local compact path and the
+    # mesh comb dispatch — a raise at either degrades that batch to
+    # the next-inner path (single-device ladder / single-device comb)
+    # with exact bitmaps, caught inside ops/ed25519 rather than
+    # escaping to the degrade runtime
     "sharding.mesh_stage",
     "sharding.mesh_comb",
-    "sharding.global_plane",
     # degradation-runtime lane sites (crypto/degrade.py submit/run):
     # one per (consumer, scheme) lane family — enumerated so the chaos
     # coverage gate can demand at least one exercised site per family

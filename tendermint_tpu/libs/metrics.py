@@ -458,19 +458,17 @@ class CryptoMetrics:
             "crypto", "device_launch_seconds",
             "Wall-clock of successful device verify launches.",
             labels=("site",), buckets=exp_buckets(0.001, 4, 10))
-        # promoted from ad-hoc module globals (ops/msm.last_route and
-        # friends) so /metrics alone answers "did the sharded RLC path
-        # actually engage in production" without polling test hooks
+        # /metrics alone answers "which route did the batches take".
+        # The name is historical (it first counted the withdrawn RLC/MSM
+        # route, ADR-009); perfbench/data.py and chip_smoke.py read it,
+        # so renaming it is a benchmark change
         self.msm_route = reg.counter(
             "crypto", "msm_route_total",
             "Verify dispatch routes taken, by path "
-            "(rlc-sharded/rlc-single/mesh-sharded/mesh-xla/global-mesh/"
-            "pallas/xla/...) and "
-            "outcome — only outcome=\"vouched\" means an RLC route "
-            "actually stood in for per-signature verification; "
-            "overflow/decode-failed/rejected bounced to the per-sig "
-            "ladder, and plain kernel launches count as "
-            "outcome=\"executed\".",
+            "(comb/mesh-comb/mesh-xla/mesh-pallas/pallas-split/pallas/"
+            "xla/...) and outcome: a kernel launch counts as "
+            "outcome=\"executed\", a route that handed the batch to the "
+            "next one as \"declined\" or \"error\".",
             labels=("path", "outcome"))
         self.batch_occupancy = reg.gauge(
             "crypto", "batch_occupancy_ratio",

@@ -95,8 +95,6 @@ def get_lib():
                 lib.tm_mod_l.argtypes = [u8p, u8p, u64]
                 lib.tm_challenge_prefixed.argtypes = [u8p, u8p, u64, u8p, u64]
                 lib.tm_challenge_batch.argtypes = [u8p, u8p, u64p, u8p, u64]
-                lib.tm_rlc_scalars.argtypes = [u8p, u8p, u8p, u8p, u8p, u64]
-                lib.tm_rlc_scalars.restype = None
                 i64p = ctypes.POINTER(ctypes.c_int64)
                 lib.tm_vote_sign_bytes.argtypes = [
                     i64p, i64p, u8p, u8p, u64, u8p, u64, u8p, u64,
@@ -215,25 +213,6 @@ def mod_l(digests: np.ndarray) -> np.ndarray | None:
     out = np.empty((B, 32), dtype=np.uint8)
     lib.tm_mod_l(_u8p(digests), _u8p(out), ctypes.c_uint64(B))
     return out
-
-
-def rlc_scalars(z: np.ndarray, k: np.ndarray, s: np.ndarray):
-    """RLC batch staging: zk[i] = z[i]*k[i] mod L and zs = sum z[i]*s[i]
-    mod L (native/staging.c tm_rlc_scalars).  z: (B, 16), k/s: (B, 32)
-    LE uint8.  Returns (zk (B, 32) uint8, zs 32-byte array) or None."""
-    lib = get_lib()
-    if lib is None:
-        return None
-    z = np.ascontiguousarray(z, dtype=np.uint8)
-    k = np.ascontiguousarray(k, dtype=np.uint8)
-    s = np.ascontiguousarray(s, dtype=np.uint8)
-    B = z.shape[0]
-    assert z.shape == (B, 16) and k.shape == (B, 32) and s.shape == (B, 32)
-    zk = np.empty((B, 32), dtype=np.uint8)
-    zs = np.empty(32, dtype=np.uint8)
-    lib.tm_rlc_scalars(_u8p(z), _u8p(k), _u8p(s), _u8p(zk), _u8p(zs),
-                       ctypes.c_uint64(B))
-    return zk, zs
 
 
 def challenge_scalars(prefix: np.ndarray, msgs) -> np.ndarray | None:

@@ -135,7 +135,7 @@ KNOWN_SPANS = frozenset({
     "consensus.finalize_commit", "consensus.preverify",
     "consensus.quorum", "consensus.step", "consensus.vote",
     # ops/ — kernel routing
-    "comb.prewarm_failed", "msm.route", "ops.ed25519.verify_batch",
+    "comb.prewarm_failed", "ops.ed25519.verify_batch",
     "table_build",
     # state/pipeline.py — the block application pipeline (ADR-017)
     "pipeline.apply", "pipeline.commit", "pipeline.drain",

@@ -300,75 +300,6 @@ EXPORT void tm_scalar_canonical(const uint8_t *s, uint8_t *out, uint64_t n) {
     }
 }
 
-/* ------------------------------------------------------- RLC batch staging */
-
-/* zk[i] = (z[i] * k[i]) mod L and zs_sum = sum_i (z[i] * s[i]) mod L for
- * random-linear-combination batch verification (the host side of
- * ops/msm.py).  z: (n, 16) LE 128-bit coefficients; k, s: (n, 32) LE
- * scalars < L.  The 128x256-bit product is 384 bits, which mod_l_one's
- * 512-bit reducer handles after zero-padding. */
-static void mul_2x4_mod_l(const uint8_t *z16, const uint8_t *v32,
-                          uint8_t *out32) {
-    uint64_t zw[2], vw[4], pw[6] = {0, 0, 0, 0, 0, 0};
-    memcpy(zw, z16, 16);
-    memcpy(vw, v32, 32);
-    for (int i = 0; i < 2; i++) {
-        uint64_t carry = 0;
-        for (int j = 0; j < 4; j++) {
-            __uint128_t cur = (__uint128_t)zw[i] * vw[j] + pw[i + j] + carry;
-            pw[i + j] = (uint64_t)cur;
-            carry = (uint64_t)(cur >> 64);
-        }
-        pw[i + 4] = carry;
-    }
-    uint8_t wide[64];
-    memcpy(wide, pw, 48);
-    memset(wide + 48, 0, 16);
-    mod_l_one(wide, out32);
-}
-
-/* 256-bit a += b (mod L); a, b < L so a+b < 2L needs at most one
- * conditional subtract and never carries out of 256 bits (L < 2^253). */
-static void add_mod_l(uint64_t a[4], const uint64_t b[4]) {
-    static const uint64_t LW[4] = {0x5812631a5cf5d3edULL,
-                                   0x14def9dea2f79cd6ULL, 0ULL,
-                                   0x1000000000000000ULL};
-    uint64_t carry = 0;
-    for (int j = 0; j < 4; j++) {
-        __uint128_t cur = (__uint128_t)a[j] + b[j] + carry;
-        a[j] = (uint64_t)cur;
-        carry = (uint64_t)(cur >> 64);
-    }
-    int ge = 1;
-    for (int j = 3; j >= 0; j--) {
-        if (a[j] > LW[j]) { ge = 1; break; }
-        if (a[j] < LW[j]) { ge = 0; break; }
-    }
-    if (ge) {
-        __int128 v, bor = 0;
-        for (int j = 0; j < 4; j++) {
-            v = (__int128)a[j] - LW[j] + bor;
-            a[j] = (uint64_t)v;
-            bor = v >> 64; /* arithmetic shift: -1 on borrow */
-        }
-    }
-}
-
-EXPORT void tm_rlc_scalars(const uint8_t *z, const uint8_t *k,
-                           const uint8_t *s, uint8_t *zk_out,
-                           uint8_t *zs_sum, uint64_t n) {
-    uint64_t acc[4] = {0, 0, 0, 0};
-    for (uint64_t i = 0; i < n; i++) {
-        mul_2x4_mod_l(z + 16 * i, k + 32 * i, zk_out + 32 * i);
-        uint8_t zs[32];
-        uint64_t zsw[4];
-        mul_2x4_mod_l(z + 16 * i, s + 32 * i, zs);
-        memcpy(zsw, zs, 32);
-        add_mod_l(acc, zsw);
-    }
-    memcpy(zs_sum, acc, 32);
-}
-
 /* ------------------------------------------------- vote sign-bytes batch */
 
 /* Protobuf uvarint; returns number of bytes written. */

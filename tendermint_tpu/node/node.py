@@ -518,14 +518,8 @@ class Node(BaseService):
             self.log.info("verify scheduler started",
                           window_ms=vs.window_ms, max_batch=vs.max_batch,
                           max_pending=vs.max_pending)
-        # the node's config decides the cofactored RLC fast path in BOTH
-        # directions: a stale TM_TPU_RLC=1 env must not override an
-        # operator's rlc=false (the env remains the knob only for
-        # node-less tooling: benches, tests)
-        from tendermint_tpu.ops import msm
-        msm.set_enabled(self.config.batch_verifier.rlc)
-        # same pattern for the secp256k1 device lane: the operator's
-        # config wins over any stale env in BOTH directions
+        # the secp256k1 device lane: the operator's config wins over
+        # any stale env in BOTH directions
         from tendermint_tpu.ops import secp as secp_ops
         secp_ops.set_lane_enabled(self.config.batch_verifier.secp_lane)
         # host-lane verify pool size (crypto/lanepool.py, ADR-015):

@@ -30,12 +30,14 @@ import threading
 import time
 from functools import partial
 from types import MappingProxyType
+from typing import NamedTuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from tendermint_tpu.libs import trace
+from tendermint_tpu.crypto import degrade, devobs
+from tendermint_tpu.libs import fail, trace
 from . import field as F
 from . import curve as C
 
@@ -121,7 +123,6 @@ def _base_comb():
     # HBM residency ledger (ADR-021): refreshed on every access, not
     # just the build — a comb user in a process whose tables another
     # consumer built must still see the pool accounted
-    from tendermint_tpu.crypto import devobs
     devobs.ledger_set("base_comb", sum(int(t.nbytes) for t in cache))
     return cache
 
@@ -637,7 +638,6 @@ def launch_kernel(fn, *args, **static):
         with _launch_lock:
             cold = key not in _compiled
         if cold:
-            from tendermint_tpu.crypto import degrade
             t0 = time.perf_counter()
             with degrade.compiling():
                 fn.lower(*args, **static).compile()
@@ -688,7 +688,6 @@ def _set_last_launch(rec: dict):
         _launch_seq += 1
         snap = dict(rec, seq=_launch_seq)
         _last_launch = MappingProxyType(snap)
-    from tendermint_tpu.crypto import devobs
     devobs.record(snap)
     devobs.publish_pending()
 
@@ -716,7 +715,6 @@ def _record_launch(path: str, n: int, nb: int, wall_s: float,
     if extra:
         rec.update(extra)
     _set_last_launch(rec)
-    from tendermint_tpu.crypto import degrade
     degrade.publish_route(path, "executed", n=n, nb=nb,
                           compile_s=compile_s or None)
     trace.current().add(path=path, n=n, nb=nb,
@@ -779,10 +777,6 @@ def verify_packed_pipelined(packed: np.ndarray, nsub: int = 4,
     unoverlapped first put) and chunks — so the caller can record the
     chunk-overlap ratio without ever serializing the pipeline with an
     extra block."""
-    import jax
-
-    from tendermint_tpu.crypto import devobs
-
     from . import pallas_ed25519 as pe
 
     tile = tile or PALLAS_TILE
@@ -970,7 +964,6 @@ def _pub_cache_get(pub_rows: np.ndarray, nsub: int):
         pub_rows[:, j * sub:(j + 1) * sub]).view(np.int8)))
         for j in range(nsub)]
     chunks = _pub_cache.put(key, chunks, nbytes=int(pub_rows.nbytes))
-    from tendermint_tpu.crypto import devobs
     devobs.ledger_set("pub_cache", _pub_cache.total_bytes)
     return chunks
 
@@ -996,7 +989,7 @@ def set_comb_config(enabled: bool = None, table_cache_mb: int = None,
                     min_batch: int = None):
     """Node-assembly override of the comb-path knobs (None leaves a knob
     on its env/default; the env stays the knob only for node-less
-    tooling — benches, tests — same contract as msm.set_enabled)."""
+    tooling — benches, tests)."""
     global _comb_enabled_override, _comb_min_override, \
         _table_budget_override
     if enabled is not None:
@@ -1069,7 +1062,6 @@ def _table_evicted(set_hash, entry):
             freed += cached[2]
             setattr(entry, slot, None)
     if freed:
-        from tendermint_tpu.crypto import devobs
         devobs.ledger_add("mesh_tables", -freed)
     with _table_key_lock:
         for kb in entry.index:
@@ -1086,8 +1078,6 @@ def _table_evicted(set_hash, entry):
                     break
             else:
                 del _table_key_index[kb]
-    from tendermint_tpu.crypto import degrade
-    from tendermint_tpu.crypto import devobs
     degrade.publish_table_cache(bytes_=_table_cache.total_bytes,
                                 evicted=True)
     devobs.ledger_set("table_cache", _table_cache.total_bytes)
@@ -1118,8 +1108,6 @@ def _table_build(uniq: np.ndarray, set_hash: bytes):
     plane (sharding.comb_mesh_mode, ADR-027), which charges its extra
     per-device copies — or the budget-fallback sharded slices — to the
     mesh_tables ledger pool against the same budget at launch time."""
-    from tendermint_tpu.crypto import degrade
-
     k = uniq.shape[0]
     k_pad = _comb_k_pad(k)
     nbytes = k_pad * _TABLE_BYTES_PER_KEY
@@ -1149,7 +1137,6 @@ def _table_build(uniq: np.ndarray, set_hash: bytes):
         for kb, i in entry.index.items():
             _table_key_index[kb] = set_hash
     degrade.publish_table_cache(bytes_=_table_cache.total_bytes)
-    from tendermint_tpu.crypto import devobs
     devobs.ledger_set("table_cache", _table_cache.total_bytes)
     return entry
 
@@ -1227,7 +1214,6 @@ def prewarm(pubkeys, warm_kernel: bool = True) -> bool:
 
 
 def _prewarm_failed(e: BaseException):
-    from tendermint_tpu.crypto import degrade
     degrade.publish_route("comb-prewarm", "error")
     trace.instant("comb.prewarm_failed", error=type(e).__name__)
 
@@ -1259,16 +1245,31 @@ def prewarm_async(pubkeys) -> None:
     t.start()
 
 
-def _comb_try(pubkeys, msgs, sigs, cache_pubs: bool, plane):
-    """The comb route: engage iff every key resolves to one cached set
-    (building the set on a cache_pubs batch >= comb_min_batch()).
-    Returns the bitmap, or None to fall through to the ladder paths.
-    Runs under the same degrade lane as every other device dispatch, so
-    breaker/timeout/host-fallback and the corrupt-bitmap integrity
-    check apply unchanged (site ops.ed25519.comb)."""
-    from tendermint_tpu.crypto import degrade
-    from tendermint_tpu.libs import fail
+class _CombSet(NamedTuple):
+    """A batch every key of which resolved to one resident table set."""
+    entry: CombTables
+    pub_m: np.ndarray   # (n, 32) the batch's keys
+    vidx: np.ndarray    # (n,) int32: each lane's row in the tables
+    built: bool         # this call built the tables
 
+
+class _Launched(NamedTuple):
+    """What a route hands verify_batch to read back and record."""
+    out: object          # device array still in flight, or the host
+    #                      bitmap of a route that blocked inside
+    host_ok: np.ndarray  # (n,) the host-side screens (lengths, s < L)
+    nb: int              # padded lanes launched
+    phases: dict         # devobs phase walls + the route's own fields
+    t0: float            # perf_counter at the start of the route's bracket
+    path: str = None     # set when the route ran as a variant of itself
+    shards: int = 1
+
+
+def _comb_resolve(pubkeys, cache_pubs: bool):
+    """What verify_batch observes for the comb: the table set every key
+    of the batch resolves to (building it on a cache_pubs batch >=
+    comb_min_batch()), or None: unknown keys, mixed sets, evicted
+    tables, a blown HBM budget, the comb disabled."""
     n = len(pubkeys)
     if n == 0 or not comb_enabled():
         return None
@@ -1305,30 +1306,80 @@ def _comb_try(pubkeys, msgs, sigs, cache_pubs: bool, plane):
         built = True
     else:
         degrade.publish_table_cache(hit=True)
+    return _CombSet(entry, pub_m, remap[inverse].astype(np.int32), built)
+
+
+def _comb_buckets(n: int) -> list:
+    """Lane buckets of the single-device comb's launches: chunked like
+    every other device path (split_chunked_launch, the nb > MAX_CHUNK
+    pipelined sub-batching) — one unbounded launch for a huge batch
+    would mint a fresh XLA bucket shape per size class and outgrow
+    degrade timeouts tuned for <= MAX_CHUNK."""
+    return [bucket_size(min(a + MAX_CHUNK, n) - a)
+            for a in range(0, n, MAX_CHUNK)]
+
+
+def _stage_phases(t0: float, c0: float) -> dict:
+    """The staging bracket a route opened with (perf_counter,
+    thread_time) at its entry, closed now."""
+    return {"stage_cpu_s": time.thread_time() - c0,
+            "stage_s": time.perf_counter() - t0}
+
+
+def _launch_serial(obs_on: bool, phases: dict, put, launch):
+    """One monolithic launch: `put()` issues the host->device copies
+    and returns the operands, `launch(*operands)` dispatches on them.
+    Launch decomposition (ADR-021): with the observatory enabled the
+    two are bracketed apart — the bracket opens BEFORE the jnp.asarray
+    conversions, which are what actually issue the copy, then
+    dispatch->block is the compute share — into phases["h2d_s"] /
+    ["compute_s"] (summed over a route's launches), at the price of one
+    extra block_until_ready on the staged buffers; these paths are
+    already device_put -> dispatch -> full block, so nothing is
+    serialized that wasn't.  Disabled, it is put -> dispatch and
+    nothing else."""
+    if not obs_on:
+        return launch(*put())
+    t_put = time.perf_counter()
+    args = put()
+    for arg in args:
+        arg.block_until_ready()
+    t_h2d = time.perf_counter()
+    phases["h2d_s"] = phases.get("h2d_s", 0.0) + (t_h2d - t_put)
+    out = launch(*args)
+    out.block_until_ready()
+    phases["compute_s"] = phases.get("compute_s", 0.0) + \
+        (time.perf_counter() - t_h2d)
+    return out
+
+
+def _run_comb(comb: _CombSet, msgs, sigs, plane, obs_on: bool):
+    """The comb route (ADR-013) on a resolved set.  Runs under the same
+    degrade lane as every other device dispatch, so breaker / timeout /
+    host-fallback and the corrupt-bitmap integrity check apply
+    unchanged (site ops.ed25519.comb).  `plane`: the local mesh when
+    the batch is worth sharding, else None."""
     # chaos seam: a raise/latency armed here fails exactly the comb
     # dispatch (the ladder is NOT retried in-process — the degradation
     # runtime owns the fallback, preserving bitmap identity)
     fail.inject("ops.ed25519.comb")
-    from tendermint_tpu.crypto import devobs
-    obs_on = devobs.is_enabled()
-    vidx = remap[inverse].astype(np.int32)
+    entry, pub_m, vidx, built = comb
+    n = pub_m.shape[0]
     t0, c0 = time.perf_counter(), time.thread_time()
     _, r_b, s_b, kscal, host_ok = _stage_rows(
         pub_m, _to_u8_matrix(sigs, 64), msgs)
     s_digits = scalars_to_digits(s_b)
     k_digits = scalars_to_digits(kscal)
-    use_mesh = plane is not None and plane.worth_sharding(n)
-    phases = {"stage_cpu_s": time.thread_time() - c0,
-              "stage_s": time.perf_counter() - t0} if obs_on else {}
-    res, path, nb, shards = None, "comb", 0, 1
-    if use_mesh:
+    phases = _stage_phases(t0, c0) if obs_on else {}
+    res, path, nb, shards = None, None, 0, 1
+    if plane is not None:
         # the data plane takes the FULL batch: it owns the chunking
         # (double-buffered per-shard staging, ADR-027) and the
         # budget-aware table layout; None (budget declined) or a chaos
         # fault at its seam falls back to the single-device comb below
         # — the tables are resident on the build device, so declining
         # to the ladder would throw the cached work away
-        probe = {} if obs_on else None
+        probe = {}
         try:
             mesh_out = plane.verify_comb(r_b, s_digits, k_digits, vidx,
                                          entry, _base_comb(),
@@ -1340,7 +1391,7 @@ def _comb_try(pubkeys, msgs, sigs, cache_pubs: bool, plane):
             res, nb, shards, path = mesh_out
             if obs_on:
                 phases.update(_overlap_phases({
-                    "stage_s": phases.get("stage_s", 0.0),
+                    "stage_s": phases["stage_s"],
                     "dma_s": probe.get("dma_s", 0.0),
                     "dma_first_s": probe.get("dma_first_s", 0.0),
                     "chunks": probe.get("chunks", 1)}))
@@ -1348,69 +1399,52 @@ def _comb_try(pubkeys, msgs, sigs, cache_pubs: bool, plane):
                     phases["shard_h2d_s"] = probe["shard_h2d_s"]
                 phases.update(devobs.shard_fields(n, nb, shards))
     if res is None:
-        # chunk like every other device path (split_chunked_launch, the
-        # nb > MAX_CHUNK pipelined sub-batching): one unbounded launch
-        # for a huge batch would mint a fresh XLA bucket shape per size
-        # class and outgrow degrade timeouts tuned for <= MAX_CHUNK
-        parts, nb, shards, path = [], 0, 1, "comb"
-        for a in range(0, n, MAX_CHUNK):
-            b = min(a + MAX_CHUNK, n)
-            rc, sc, kc, vc = (r_b[a:b], s_digits[a:b], k_digits[a:b],
-                              vidx[a:b])
-            m = b - a
-            cnb = bucket_size(m)
+        by, bm, bt = _base_comb()
+        tb = entry.tables
+        parts, a = [], 0
+        for cnb in _comb_buckets(n):
+            m = min(MAX_CHUNK, n - a)
+            rc, sc, kc, vc = (r_b[a:a + m], s_digits[a:a + m],
+                              k_digits[a:a + m], vidx[a:a + m])
             if cnb != m:
                 pad = [(0, cnb - m), (0, 0)]
                 rc = np.pad(rc, pad)
                 sc = np.pad(sc, pad)
                 kc = np.pad(kc, pad)
                 vc = np.pad(vc, (0, cnb - m))
-            by, bm, bt = _base_comb()
+            # the operands are the per-launch transfer (the tables are
+            # device-resident already: they are the cache)
+            out = _launch_serial(
+                obs_on, phases,
+                lambda: (jnp.asarray(rc), jnp.asarray(sc),
+                         jnp.asarray(kc), jnp.asarray(vc)),
+                lambda *ops: launch_kernel(
+                    comb_kernel, *ops, tb.ypx, tb.ymx, tb.z, tb.t2d,
+                    entry.dec_ok, by, bm, bt))
+            t_col = time.perf_counter()
+            parts.append(np.asarray(out)[:m])
             if obs_on:
-                # per-launch operand transfer bracket — opened BEFORE
-                # the jnp.asarray conversions, which are what actually
-                # issue the host->device copy (the tables are device-
-                # resident already: they are the cache, not the
-                # transfer); then dispatch->block is the compute share
-                t_put = time.perf_counter()
-                args = (jnp.asarray(rc), jnp.asarray(sc),
-                        jnp.asarray(kc), jnp.asarray(vc))
-                for arg in args:
-                    arg.block_until_ready()
-                t_h2d = time.perf_counter()
-                phases["h2d_s"] = phases.get("h2d_s", 0.0) + \
-                    (t_h2d - t_put)
-                out = launch_kernel(comb_kernel, *args,
-                                    entry.tables.ypx, entry.tables.ymx,
-                                    entry.tables.z, entry.tables.t2d,
-                                    entry.dec_ok, by, bm, bt)
-                out.block_until_ready()
-                phases["compute_s"] = phases.get("compute_s", 0.0) + \
-                    (time.perf_counter() - t_h2d)
-                t_col = time.perf_counter()
-                part = np.asarray(out)[:m]
                 phases["collect_s"] = phases.get("collect_s", 0.0) + \
                     (time.perf_counter() - t_col)
-            else:
-                out = launch_kernel(
-                    comb_kernel, jnp.asarray(rc), jnp.asarray(sc),
-                    jnp.asarray(kc), jnp.asarray(vc),
-                    entry.tables.ypx, entry.tables.ymx,
-                    entry.tables.z, entry.tables.t2d,
-                    entry.dec_ok, by, bm, bt)
-                part = np.asarray(out)[:m]
-            parts.append(np.asarray(part))
+            a += m
             nb += cnb
         res = parts[0] if len(parts) == 1 else np.concatenate(parts)
-    _record_launch(path, n, nb, time.perf_counter() - t0, shards=shards,
-                   extra=dict(phases, table_build=built, set_k=entry.k,
-                              k_pad=entry.k_pad))
+    phases.update(table_build=built, set_k=entry.k, k_pad=entry.k_pad)
     res = fail.corrupt_bitmap("ops.ed25519.comb",
                               np.asarray(res[:n], dtype=bool))
-    return res & host_ok
+    return _Launched(res, host_ok, nb, phases, t0, path, shards)
 
 
 SPLIT_CHUNK = 16384  # chunk size of the staged split-path pipeline
+
+
+def _split_chunk(n: int) -> int:
+    """Lanes per launch of the split path.  A batch pads to a multiple
+    of the chunk, NOT to a power-of-two bucket: every launch has the
+    same (96, chunk) shape (one compile), and a 100k batch pads to
+    7x16384 = 114,688 lanes instead of 131,072 — the power-of-two
+    rounding wasted 31% of the kernel floor."""
+    return min(SPLIT_CHUNK, max(PALLAS_TILE, bucket_size(n)))
 
 
 def _msgs_slice(msgs, a: int, b: int):
@@ -1438,18 +1472,10 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     staging walls (stage_s) and DMA walls (dma_s / dma_first_s /
     chunks), measured without adding any synchronization — the
     decomposition must never serialize the pipeline it measures."""
-    import jax
-
-    from tendermint_tpu.crypto import devobs
-
     from . import pallas_ed25519 as pe
 
     n = len(pubkeys)
-    # pad to a multiple of the chunk, NOT to a power-of-two bucket: every
-    # launch has the same (96, chunk) shape (one compile), and a 100k
-    # batch pads to 7x16384 = 114,688 lanes instead of 131,072 — the
-    # power-of-two rounding wasted 31% of the kernel floor
-    chunk = min(SPLIT_CHUNK, max(PALLAS_TILE, bucket_size(n)))
+    chunk = _split_chunk(n)
     nb = -(-n // chunk) * chunk
     nsub = nb // chunk
     pub_m = _to_u8_matrix(pubkeys, 32)
@@ -1519,199 +1545,199 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     return outs, host_ok[:n], n
 
 
+class Route(NamedTuple):
+    """One rung of verify_batch's ladder, as select_routes names it."""
+    path: str      # what the launch record, crypto_msm_route_total and
+    #                the benchmark's ledger print
+    nb: int        # padded lanes of the whole call (the record's nb);
+    #                None on the mesh: the plane buckets per shard
+    launches: int  # kernel launches the call makes; None on the mesh
+
+
+def select_routes(n: int, cache_pubs: bool, *, pallas: bool,
+                  comb_resident: bool, plane_worth: bool) -> tuple:
+    """The routes verify_batch tries for a batch of n rows, in order;
+    it takes the first, and the next only when that one declines (a
+    comb bug, a chaos fault at the mesh seam).  Pure: decided by what
+    the caller observed and the module's thresholds, nothing else.
+
+      comb          every key resolves to one device-resident table set
+                    (ADR-013), whatever the backend
+      mesh-*        the local plane says the batch is worth sharding
+                    (parallel/sharding.data_plane; off on TPU while
+                    sharding.MESH_ON_TPU is)
+      pallas-split  on TPU, a cache_pubs batch of >= PUB_CACHE_MIN rows:
+                    pubkey rows device-resident, 96 B/sig on the wire,
+                    launches of _split_chunk(n) lanes
+      pallas        on TPU otherwise: one packed launch of the
+                    power-of-two bucket, MAX_CHUNK sub-launches past it
+      xla           every other backend: the XLA-composed kernel
+
+    The last is always a single-device route, which never declines."""
+    routes = []
+    if comb_resident:
+        buckets = _comb_buckets(n)
+        routes.append(Route("comb", sum(buckets), len(buckets)))
+    if plane_worth:
+        routes.append(Route("mesh-pallas" if pallas else "mesh-xla",
+                            None, None))
+    if not pallas:
+        routes.append(Route("xla", bucket_size(n), 1))
+    elif cache_pubs and n >= PUB_CACHE_MIN:
+        chunk = _split_chunk(n)
+        launches = -(-n // chunk)
+        routes.append(Route("pallas-split", launches * chunk, launches))
+    else:
+        nb = max(PALLAS_TILE, bucket_size(n))
+        routes.append(Route("pallas", nb, max(1, nb // MAX_CHUNK)))
+    return tuple(routes)
+
+
+def _run_split(pubkeys, msgs, sigs, route: Route, obs_on: bool):
+    t0 = time.perf_counter()
+    probe = {}
+    outs, host_ok, _ = split_chunked_launch(pubkeys, msgs, sigs,
+                                            probe=probe)
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    return _Launched(out, host_ok, route.nb,
+                     _overlap_phases(probe) if obs_on else {}, t0)
+
+
+def _run_pallas(pubkeys, msgs, sigs, route: Route, obs_on: bool):
+    from . import pallas_ed25519 as pe
+
+    t0, c0 = time.perf_counter(), time.thread_time()
+    packed, host_ok = prepare_batch_packed(pubkeys, sigs, msgs)
+    n, nb = host_ok.shape[0], route.nb
+    if nb != n:  # pad the trailing (lane) axis
+        packed = np.pad(packed, [(0, 0), (0, nb - n)])
+    phases = _stage_phases(t0, c0) if obs_on else {}
+    if route.launches > 1:
+        # huge batches (100k-validator VerifyCommit) run as MAX_CHUNK
+        # sub-batches with transfer/compute pipelining — same lane
+        # buckets the headline path uses, and the DMA of chunk j+1
+        # overlaps the kernel of chunk j
+        probe = {}
+        out = jnp.concatenate(verify_packed_pipelined(
+            packed, nsub=route.launches, probe=probe))
+        if obs_on:
+            phases.update(_overlap_phases(probe))
+    else:
+        out = _launch_serial(
+            obs_on, phases, lambda: (jnp.asarray(packed),),
+            lambda buf: launch_kernel(pe.verify_packed_pallas, buf,
+                                      tile=min(PALLAS_TILE, nb)))
+    return _Launched(out, host_ok, nb, phases, t0)
+
+
+def _run_xla(pubkeys, msgs, sigs, route: Route, obs_on: bool):
+    t0, c0 = time.perf_counter(), time.thread_time()
+    dev, host_ok = prepare_batch(pubkeys, sigs, msgs)
+    dev = _pad_dev(dev, host_ok.shape[0], route.nb)
+    phases = _stage_phases(t0, c0) if obs_on else {}
+    out = _launch_serial(
+        obs_on, phases, lambda: [jnp.asarray(dev[k]) for k in _XLA_ARGS],
+        lambda *arrs: launch_kernel(verify_kernel, *arrs))
+    return _Launched(out, host_ok, route.nb, phases, t0)
+
+
+# stage + launch of each single-device route: (pubkeys, msgs, sigs,
+# route, obs_on) -> _Launched with the result still in flight
+_SINGLE_DEVICE = {"pallas-split": _run_split, "pallas": _run_pallas,
+                  "xla": _run_xla}
+
+
+def _comb_policy(sp, fn, *args):
+    """The comb's error policy around its two steps (_comb_resolve,
+    _run_comb).  A comb fault degrades like any other device fault:
+    chaos AND real device faults (XlaRuntimeError subclasses
+    RuntimeError) must reach the degrade runtime wrapping this dispatch
+    — re-dispatching the batch through the ladder on the same
+    possibly-dead device would just burn a doomed launch before the
+    breaker sees the failure.  A comb BUG (shape / typing / indexing)
+    must not kill verification: it is routed, and None sends the batch
+    down the ladder."""
+    try:
+        return fn(*args)
+    except (fail.InjectedFault, RuntimeError):
+        raise
+    except Exception as e:  # noqa: BLE001 - see above
+        degrade.publish_route("comb", "error")
+        sp.add(comb_error=type(e).__name__)
+        return None
+
+
+def _local_plane():
+    # sharding imports this module at load
+    from tendermint_tpu.parallel.sharding import data_plane
+    return data_plane()
+
+
 def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
     """End-to-end batched verify (host staging + device kernel).
     Returns a (B,) bool validity bitmap.
 
-    On TPU the fused Pallas kernel (ops/pallas_ed25519.py) runs the whole
-    verification in VMEM (~3.5x the XLA-composed kernel); elsewhere the
-    XLA kernel is used.  On a multi-device host the batch shards across
-    the local mesh (parallel/sharding.data_plane; held off on TPU by
-    sharding.MESH_ON_TPU until chip_smoke.py passes with it on) — this
-    function is the single seam every verifier in the node goes through,
-    so multi-chip is the production path, not a side demo.
+    Select, run, record.  select_routes says which route a batch takes
+    and why; on TPU the fused Pallas kernel (ops/pallas_ed25519.py)
+    runs the whole verification in VMEM, elsewhere the XLA kernel; a set
+    whose comb tables are resident takes the comb; on a multi-device
+    host the batch shards across the local mesh.  This function is the
+    single seam every verifier in the node goes through.
 
     cache_pubs: the caller asserts the pubkey set recurs across calls
     (validator-set paths — crypto/batch.verify_sigs_bulk): the (32, B)
     pubkey rows are kept device-resident keyed by content hash, so
     steady-state VerifyCommit ships 96 B/sig instead of 128."""
-    from tendermint_tpu.libs import fail
-    from tendermint_tpu.parallel import sharding
-    from tendermint_tpu.parallel.sharding import data_plane
-
     # chaos seam: the degradation runtime (crypto/degrade.py) wraps every
     # dispatch into this function, so an injected raise/latency here is
     # indistinguishable from a real device fault to the callers
     fail.inject("ops.ed25519.verify_batch")
     _take_compile_s()
-
-    from . import msm
-
-    with trace.span("ops.ed25519.verify_batch", n=len(pubkeys)) as sp:
-        # the GLOBAL plane outranks everything, but only answers inside
-        # a lockstep() window on a multi-process runtime (ADR-027):
-        # blocksync replay_window and the coordinated bulk verify, where
-        # every process is known to walk the same batches in the same
-        # order.  A chaos fault at its seam degrades this batch to the
-        # local paths below — on THIS process only; peers entering the
-        # collective without it rely on their own degrade timeouts, the
-        # price of testing a collective's failure path per-process.
-        gplane = sharding.global_plane()
-        if gplane is not None and gplane.worth_sharding(len(pubkeys)):
-            try:
-                return gplane.verify_batch(pubkeys, msgs, sigs)
-            except fail.InjectedFault:
-                from tendermint_tpu.crypto import degrade
-                degrade.publish_route("global-mesh", "declined")
-                sp.add(global_mesh_fault=True)
-            except Exception as e:  # noqa: BLE001 - collective runtime fault
-                # a REAL failure of the cross-process plane (most
-                # commonly a backend without multi-process computation
-                # support, e.g. the CPU backend of current jaxlib)
-                # latches the global plane off for the process: the
-                # compile is deterministic, so retrying every batch
-                # would pay the failed lowering forever.  Verification
-                # stays exact on the local paths below.
-                from tendermint_tpu.crypto import degrade
-                sharding.disable_global_plane()
-                degrade.publish_route("global-mesh", "declined")
-                sp.add(global_mesh_fault=True, global_mesh_err=type(e).__name__)
-        # the mesh data plane is consulted FIRST, and the RLC fast path
-        # dispatches THROUGH it: on a multi-chip host the Pippenger
-        # bucket accumulation runs as per-shard partial MSMs with an
-        # on-mesh reduction (parallel/sharding.msm_window_sums), so the
-        # highest-throughput verifier uses every local chip instead of
-        # leaving N-1 idle.  RLC-ineligible batches (non-canonical
-        # encodings, failed combination, MSM shapes the plane policy
-        # declines) fall through to the sharded per-signature ladder for
-        # check-all attribution (docs/adr/009).
-        plane = data_plane()
-        if msm.use_rlc(len(pubkeys)):
-            if msm.verify_batch_rlc(pubkeys, msgs, sigs, plane=plane):
-                return np.ones(len(pubkeys), dtype=bool)
-            sp.add(rlc_fallback=True)
-        # fixed-base comb (ADR-013): engages when every key resolves to
-        # one device-resident table set (built on cache_pubs batches >=
-        # comb_min_batch()); unknown keys, mixed sets, evicted tables or
-        # a blown HBM budget fall through to the ladder below.  A comb
-        # fault degrades like any other device fault: the raise
-        # propagates to the degradation runtime wrapping this dispatch.
-        try:
-            out = _comb_try(pubkeys, msgs, sigs, cache_pubs, plane)
-        except (fail.InjectedFault, RuntimeError):
-            # chaos AND real device faults (XlaRuntimeError subclasses
-            # RuntimeError) must reach the degrade runtime wrapping this
-            # dispatch — re-dispatching the batch through the ladder on
-            # the same possibly-dead device would just burn a doomed
-            # launch before the breaker sees the failure
-            raise
-        except Exception as e:  # noqa: BLE001 - a comb BUG (shape /
-            # typing / indexing) must not kill verification: route it,
-            # fall back to the ladder
-            from tendermint_tpu.crypto import degrade
-            degrade.publish_route("comb", "error")
-            sp.add(comb_error=type(e).__name__)
-            out = None
-        if out is not None:
-            return out
-        if plane is not None and plane.worth_sharding(len(pubkeys)):
-            try:
-                return plane.verify_batch(pubkeys, msgs, sigs)
-            except fail.InjectedFault:
-                # chaos at the mesh staging seam
-                # (sharding.mesh_stage): degrade THIS batch to the
-                # single-device paths below, bitmap identical
-                from tendermint_tpu.crypto import degrade
-                degrade.publish_route(plane.MESH_PATH, "declined")
-                sp.add(mesh_fault=True)
-        from tendermint_tpu.crypto import devobs
-
-        # launch decomposition (ADR-021): with the observatory enabled
-        # the monolithic paths bracket staging / H2D / compute / D2H
-        # explicitly (one extra block_until_ready on the staged buffers
-        # — these paths are already device_put -> dispatch -> full
-        # block, so nothing is serialized that wasn't), and the
-        # double-buffered paths record the non-serializing DMA probe
-        # instead.  Disabled, the code path is byte-identical to the
-        # pre-ADR-021 shape.
+    n = len(pubkeys)
+    with trace.span("ops.ed25519.verify_batch", n=n) as sp:
+        plane = _local_plane()
+        worth = plane is not None and plane.worth_sharding(n)
+        comb = _comb_policy(sp, _comb_resolve, pubkeys, cache_pubs)
+        routes = select_routes(n, cache_pubs, pallas=_use_pallas(),
+                               comb_resident=comb is not None,
+                               plane_worth=worth)
         obs_on = devobs.is_enabled()
-        phases = {}
-        t0, c0 = time.perf_counter(), time.thread_time()
-        if _use_pallas():
-            from . import pallas_ed25519 as pe
-            if cache_pubs and len(pubkeys) >= PUB_CACHE_MIN:
-                probe = {} if obs_on else None
-                outs, host_ok, n = split_chunked_launch(pubkeys, msgs,
-                                                        sigs, probe=probe)
-                out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-                if probe:
-                    phases = _overlap_phases(probe)
-                path = "pallas-split"
+        for route in routes:
+            if route.path == "comb":
+                launched = _comb_policy(sp, _run_comb, comb, msgs, sigs,
+                                        plane if worth else None, obs_on)
+            elif route.path in _SINGLE_DEVICE:
+                launched = _SINGLE_DEVICE[route.path](
+                    pubkeys, msgs, sigs, route, obs_on)
             else:
-                packed, host_ok = prepare_batch_packed(pubkeys, sigs, msgs)
-                n = host_ok.shape[0]
-                nb = max(PALLAS_TILE, bucket_size(n))
-                if nb != n:  # pad the trailing (lane) axis
-                    packed = np.pad(packed, [(0, 0), (0, nb - n)])
-                if obs_on:
-                    phases["stage_cpu_s"] = time.thread_time() - c0
-                    phases["stage_s"] = time.perf_counter() - t0
-                if nb > MAX_CHUNK:
-                    # huge batches (100k-validator VerifyCommit) run as
-                    # MAX_CHUNK sub-batches with transfer/compute
-                    # pipelining — same lane buckets the headline path
-                    # uses, and the DMA of chunk j+1 overlaps the
-                    # kernel of chunk j
-                    probe = {} if obs_on else None
-                    outs = verify_packed_pipelined(packed,
-                                                   nsub=nb // MAX_CHUNK,
-                                                   probe=probe)
-                    out = jnp.concatenate(outs)
-                    if probe:
-                        phases.update(_overlap_phases(probe))
-                else:
-                    buf = jnp.asarray(packed)
-                    if obs_on:
-                        buf.block_until_ready()
-                        t_h2d = time.perf_counter()
-                        phases["h2d_s"] = t_h2d - t0 - phases["stage_s"]
-                        out = launch_kernel(pe.verify_packed_pallas, buf,
-                                            tile=min(PALLAS_TILE, nb))
-                        out.block_until_ready()
-                        phases["compute_s"] = time.perf_counter() - t_h2d
-                    else:
-                        out = launch_kernel(pe.verify_packed_pallas, buf,
-                                            tile=min(PALLAS_TILE, nb))
-                path = "pallas"
+                # the mesh stages, launches, reads back and records on
+                # its own (parallel/sharding)
+                try:
+                    return plane.verify_batch(pubkeys, msgs, sigs)
+                except fail.InjectedFault:
+                    # chaos at the mesh staging seam
+                    # (sharding.mesh_stage): degrade THIS batch to the
+                    # single-device route, bitmap identical
+                    degrade.publish_route(route.path, "declined")
+                    sp.add(mesh_fault=True)
+                    launched = None
+            if launched is not None:
+                break
+        out, host_ok, nb, phases, t0, path, shards = launched
+        if isinstance(out, np.ndarray):
+            res = out  # the route blocked inside and read back itself
         else:
-            dev, host_ok = prepare_batch(pubkeys, sigs, msgs)
-            n = host_ok.shape[0]
-            dev = _pad_dev(dev, n, bucket_size(n))
+            t_col = time.perf_counter()
+            res = np.asarray(out)  # blocks: wall below includes execution
             if obs_on:
-                phases["stage_cpu_s"] = time.thread_time() - c0
-                t_st = time.perf_counter()
-                phases["stage_s"] = t_st - t0
-                arrs = [jnp.asarray(dev[k]) for k in _XLA_ARGS]
-                for a in arrs:
-                    a.block_until_ready()
-                t_h2d = time.perf_counter()
-                phases["h2d_s"] = t_h2d - t_st
-                out = launch_kernel(verify_kernel, *arrs)
-                out.block_until_ready()
-                phases["compute_s"] = time.perf_counter() - t_h2d
-            else:
-                out = launch_kernel(
-                    verify_kernel, *(jnp.asarray(dev[k]) for k in _XLA_ARGS))
-            path = "xla"
-        t_col = time.perf_counter()
-        res = np.asarray(out)  # blocks: wall below includes execution
-        if obs_on:
-            # paths that bracketed compute have only the readback left
-            # here (collect_s); the double-buffered paths block for the
-            # FIRST time here, so the wait is residual compute + D2H
-            # merged — recorded as drain_s, never mislabeled collect
-            key = "collect_s" if "compute_s" in phases else "drain_s"
-            phases[key] = time.perf_counter() - t_col
-        _record_launch(path, n, res.shape[0], time.perf_counter() - t0,
-                       extra=phases or None)
+                # routes that bracketed compute have only the readback
+                # left here (collect_s); the double-buffered ones block
+                # for the FIRST time here, so the wait is residual
+                # compute + D2H merged — recorded as drain_s, never
+                # mislabeled collect
+                key = "collect_s" if "compute_s" in phases else "drain_s"
+                phases[key] = time.perf_counter() - t_col
+        _record_launch(path or route.path, n, nb,
+                       time.perf_counter() - t0, shards=shards,
+                       extra=phases)
         return res[:n] & host_ok

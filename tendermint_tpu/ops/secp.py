@@ -50,8 +50,8 @@ _lane_override: "bool | None" = None
 
 def set_lane_enabled(on: "bool | None"):
     """Config-driven override of the device-lane default (wins over the
-    env, both directions — mirrors msm.set_enabled).  None clears the
-    override so TM_TPU_SECP_LANE governs again."""
+    env, both directions).  None clears the override so
+    TM_TPU_SECP_LANE governs again."""
     global _lane_override
     _lane_override = None if on is None else bool(on)
 

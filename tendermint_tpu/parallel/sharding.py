@@ -6,22 +6,19 @@ build adds a true data-parallel axis the reference lacks: a verification
 batch (pubkey/sig/digit arrays) sharded across a `jax.sharding.Mesh`, with
 XLA inserting the collectives — an all-gather of the per-lane bitmap and a
 `psum`-style reduction for the commit-level all-valid bit — over ICI
-(intra-pod) or DCN (multi-host).  This is the analog of the reference's
-blocksync fan-out (blocksync/pool.go:374), but over chips instead of peers.
+(intra-pod).  This is the analog of the reference's blocksync fan-out
+(blocksync/pool.go:374), but over chips instead of peers.
 
-Two verifier shapes ride the same mesh: the per-signature kernel (batch
-rows split across devices, bitmap all-gathered) and, since round 6, the
-RLC/Pippenger MSM fast path (ops/msm.py) — per-shard partial bucket sums
-with an on-mesh reduction, so the highest-throughput verifier also uses
-every local chip instead of leaving N-1 idle.
+One process owns the mesh: every device of jax.local_devices().  The
+per-signature kernels ride it (batch rows split across devices, bitmap
+all-gathered): the ladder (mesh-xla / mesh-pallas) and the fixed-base
+comb (mesh-comb*).
 """
 from __future__ import annotations
 
 import os
 import threading
 import time
-from contextlib import contextmanager
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -48,16 +45,6 @@ def make_mesh(devices=None, axis: str = BATCH_AXIS) -> Mesh:
 # launches stay inside the known compile-bucket shapes (tmlint
 # CompileSentinel) — additive knob steps still move the effective
 # chunk whenever they cross a power-of-two boundary.
-#
-# The knob governs the LOCAL plane only.  The global plane's chunk
-# count is part of a cross-process collective's shape: every process
-# must launch the same chunks in the same order, and the knob is
-# steered per-process (each controller reads its own chunk_overlap,
-# each process its own TM_TPU_MESH_CHUNK), so two peers whose knobs
-# drift across a power-of-two boundary would dispatch mismatched
-# collectives — a deadlock.  _GlobalDataPlane therefore pins its
-# chunk size to the code-constant default (_static_chunk_lanes),
-# identical on every process by construction.
 # ---------------------------------------------------------------------------
 
 MESH_CHUNK_DEFAULT = edops.SPLIT_CHUNK  # per-shard lanes per H2D chunk
@@ -86,16 +73,6 @@ def mesh_chunk_lanes() -> int:
     return 1 << (v.bit_length() - 1)
 
 
-def _static_chunk_lanes() -> int:
-    """The chunk size with every per-process input excluded — no env
-    var, no override, no governed knob, just the code-constant default
-    clamped and floored exactly like mesh_chunk_lanes().  This is the
-    only chunk value safe to bake into a cross-process collective's
-    shape: identical on every process running the same build."""
-    v = max(_MESH_CHUNK_MIN, min(MESH_CHUNK_DEFAULT, edops.MAX_CHUNK))
-    return 1 << (v.bit_length() - 1)
-
-
 def set_mesh_chunk(lanes=None):
     """Node-config / control-plane seam for the staging chunk.  None
     reverts to the env/default (TM_TPU_MESH_CHUNK, same contract as
@@ -117,7 +94,6 @@ MESH_ON_TPU = False
 
 _PLANE = None
 _PLANE_KEY = None      # local-topology fingerprint the plane latched on
-_GLOBAL_PLANE = None
 _PLANE_LOCK = threading.Lock()
 
 
@@ -136,9 +112,7 @@ def data_plane():
     every call, so every BatchVerifier in the node — consensus vote
     coalescing, blocksync replay, VerifyCommit — shards across all LOCAL
     devices automatically.  Scoped to jax.local_devices(): each node
-    process verifies its own batches; the global multi-controller mesh
-    lives behind global_plane() and is reachable only from coordinated
-    lockstep() call sites (ADR-027).  Thread-safe (reactors call
+    process verifies its own batches.  Thread-safe (reactors call
     verify_batch concurrently).  TM_TPU_NO_MESH=1 forces single-device,
     and so does a TPU backend while MESH_ON_TPU is off (above).
     The latch is topology-keyed: degrade's backend re-probe calls
@@ -171,7 +145,7 @@ def invalidate_on_topology_change() -> bool:
     degrade.backend_available() on every successful probe; rebuilding
     happens lazily on the next data_plane() call.  Returns True when a
     stale plane was dropped."""
-    global _PLANE, _PLANE_KEY, _GLOBAL_PLANE
+    global _PLANE, _PLANE_KEY
     with _PLANE_LOCK:
         if _PLANE is None:
             return False
@@ -180,176 +154,7 @@ def invalidate_on_topology_change() -> bool:
             return False
         _PLANE = None
         _PLANE_KEY = None
-        _GLOBAL_PLANE = None
-    _clear_poison()
     return True
-
-
-# ---------------------------------------------------------------------------
-# the global (multi-process) plane, gated to lockstep call sites
-# (ADR-027).  A collective over jax.devices() requires EVERY process to
-# enter the same computation in the same order; reactor-driven traffic
-# cannot guarantee that, so global_plane() only answers inside a
-# lockstep() window — blocksync replay_window and the coordinated bulk
-# verify, where the caller knows all processes walk the same batches.
-# ---------------------------------------------------------------------------
-
-_lockstep_tls = threading.local()
-
-
-@contextmanager
-def lockstep():
-    """Mark the calling thread as inside a COORDINATED verify window:
-    every participating process is entering the same verification calls
-    in the same order.  Only such windows may reach the global plane —
-    a collective one process skips deadlocks the rest (ADR-027)."""
-    prev = getattr(_lockstep_tls, "depth", 0)
-    _lockstep_tls.depth = prev + 1
-    try:
-        yield
-    finally:
-        _lockstep_tls.depth = prev
-
-
-def in_lockstep() -> bool:
-    return getattr(_lockstep_tls, "depth", 0) > 0
-
-
-def global_mesh_ready() -> bool:
-    """True when jax.distributed is initialized with >1 process and the
-    mesh is not disabled — the precondition for the global plane.
-    Never raises (callers probe it on hot paths)."""
-    if os.environ.get("TM_TPU_NO_MESH") == "1" or \
-            os.environ.get("TM_TPU_NO_GLOBAL_MESH") == "1":
-        return False
-    try:
-        return jax.process_count() > 1
-    except Exception:  # noqa: BLE001 - uninitialized runtime
-        return False
-
-
-def global_plane():
-    """The cross-process mesh plane over jax.devices(), or None.  Only
-    returned INSIDE a lockstep() window on a multi-process runtime —
-    everywhere else callers get None and stay on the local plane.  A
-    peer's latch-off poisons a coordination-service key; the throttled
-    check here latches THIS process too, so one faulted participant
-    costs the job at most the in-flight batch instead of one degrade
-    timeout per peer per batch (ADR-027)."""
-    global _GLOBAL_PLANE
-    if not in_lockstep() or not global_mesh_ready():
-        return None
-    if _GLOBAL_PLANE is None:
-        with _PLANE_LOCK:
-            if _GLOBAL_PLANE is None:
-                try:
-                    devs = jax.devices()
-                except Exception:  # noqa: BLE001 - backend down
-                    return None
-                _GLOBAL_PLANE = _GlobalDataPlane(make_mesh(devs)) \
-                    if len(devs) > 1 else False
-    if _GLOBAL_PLANE and _peer_latched_off():
-        with _PLANE_LOCK:
-            _GLOBAL_PLANE = False
-    return _GLOBAL_PLANE or None
-
-
-def _coord_client():
-    """The jax.distributed coordination-service client, or None when
-    the runtime is single-process / uninitialized."""
-    from jax._src import distributed
-    return distributed.global_state.client
-
-
-# every process that latches the global plane off writes a key under
-# this directory; peers poll it (throttled, non-blocking dir listing)
-# so a persistent per-process latch converges across the job instead
-# of draining one degrade timeout per peer per batch
-_GMESH_POISON_DIR = "tm_tpu_gmesh_disabled"
-_POISON_CHECK_EVERY_S = 2.0
-_poison_next_check = 0.0
-_poison_seen = False
-
-
-def disable_global_plane():
-    """Latch the global plane OFF for this process (ops/ed25519 calls
-    this when a real — non-chaos — collective/compile fault surfaces,
-    e.g. a backend without multi-process computation support; degrade's
-    settle calls it when a lockstep launch wedges past the launch
-    deadline).  The latch holds until a topology change re-probe
-    (invalidate_on_topology_change) clears it.  Best-effort, the latch
-    is also published to the coordination service so healthy peers stop
-    routing lockstep batches into a collective this process will never
-    enter again (see global_plane)."""
-    global _GLOBAL_PLANE
-    with _PLANE_LOCK:
-        _GLOBAL_PLANE = False
-    client = _coord_client()
-    if client is None:
-        return
-    try:
-        pid = jax.process_index()
-    except Exception:  # noqa: BLE001 - runtime shutting down
-        pid = 0
-    try:
-        client.key_value_set(f"{_GMESH_POISON_DIR}/{pid}", "1")
-    except Exception:  # noqa: BLE001 - poison publication is advisory;
-        pass            # peers still converge on their own timeouts
-
-
-def _peer_latched_off() -> bool:
-    """True when any process of the job has published a global-plane
-    latch-off.  Non-blocking (key_value_dir_get lists what exists now)
-    and throttled to one coordination-service round trip per
-    _POISON_CHECK_EVERY_S; never raises."""
-    global _poison_next_check, _poison_seen
-    if _poison_seen:
-        return True
-    client = _coord_client()
-    if client is None:
-        return False
-    now = time.monotonic()
-    if now < _poison_next_check:
-        return False
-    _poison_next_check = now + _POISON_CHECK_EVERY_S
-    try:
-        entries = client.key_value_dir_get(_GMESH_POISON_DIR)
-    except Exception:  # noqa: BLE001 - coordinator unreachable: the
-        return False    # per-process latches still converge
-    _poison_seen = bool(entries)
-    return _poison_seen
-
-
-def _clear_poison():
-    """Topology re-probe cleared the local latch: drop the published
-    poison keys too (best-effort — a re-probe is the one event that
-    declares the collective worth retrying, ADR-027)."""
-    global _poison_next_check, _poison_seen
-    _poison_seen = False
-    _poison_next_check = 0.0
-    client = _coord_client()
-    if client is None:
-        return
-    try:
-        client.key_value_delete(f"{_GMESH_POISON_DIR}/")
-    except Exception:  # noqa: BLE001 - stale poison then re-latches
-        pass            # via _peer_latched_off, never crashes a probe
-
-
-def _barrier(name: str, timeout_ms: int = 240_000):
-    """Cross-process rendezvous on the jax.distributed coordination
-    service (no-op single-process / uninitialized): the global plane
-    barriers after each ahead-of-time kernel compile so no process
-    dispatches into a collective a peer is still compiling.  A REAL
-    rendezvous failure — timeout, missing peer, mismatched barrier
-    name — must propagate: proceeding would dispatch into a collective
-    a peer never entered, the exact hazard the barrier guards against.
-    verify_batch's exception handler turns the raise into a latched
-    local fallback."""
-    client = _coord_client()
-    if client is None:
-        return
-    client.wait_at_barrier(name, timeout_ms)
 
 
 class _DataPlane:
@@ -362,14 +167,7 @@ class _DataPlane:
         self.mesh = mesh
         self.nshard = int(mesh.devices.size)
         self._fns = {}
-        self._lock = __import__("threading").Lock()
-
-    def _chunk_lanes(self) -> int:
-        """Per-shard lanes of one staging chunk.  The local plane reads
-        the live governed knob; the global plane overrides with the
-        static code constant — its chunk count is collective shape and
-        must match on every process (module comment above)."""
-        return mesh_chunk_lanes()
+        self._lock = threading.Lock()
 
     def worth_sharding(self, n: int) -> bool:
         """Small hot-path batches (a consensus vote window) stay on one
@@ -381,157 +179,19 @@ class _DataPlane:
             return n >= self.nshard * edops.PALLAS_TILE
         return n >= self.nshard
 
-    # -- RLC / Pippenger MSM over the mesh ---------------------------------
-
-    MSM_MIN_PER_SHARD = 32
-
-    def worth_sharding_msm(self, n: int) -> bool:
-        """MSM sharding policy: bucket memory / scan depth, NOT lane
-        count.  The MSM's device wall clock and working set are the
-        layered bucket fill — T unified adds over K_pad bucket lanes,
-        with T * K_pad * 3 coords of niels rows materialized per pass —
-        and sharding splits the M items nshard ways while keeping a full
-        bucket table per shard.  It therefore only wins while the
-        per-shard mean bucket load still dominates the Poisson tail
-        margin baked into T: below that, every shard scans almost as many
-        layers as the single device would and the mesh dispatch is pure
-        overhead.  Shard when the per-shard scan work (T_s * K_pad_s
-        lane-steps, which is also the bucket-memory ratio) models at
-        least a ~1.5x speedup — a 2-shard mesh tops out just under 2x
-        (the tail margin doesn't halve), so demanding 2x would
-        permanently exclude it."""
-        from tendermint_tpu.ops import ed25519 as edops
-        from tendermint_tpu.ops import msm as msmops
-
-        if self.nshard < 2:
-            return False
-        # minimum REAL rows per shard (pad rows are dead weight): below
-        # this the dispatch overhead can't amortize regardless of model
-        if -(-n // self.nshard) < self.MSM_MIN_PER_SHARD:
-            return False
-        # cost model over the plans that would actually EXECUTE — the
-        # bucketed per-shard rows and the c each dispatch would pick —
-        # not the raw n (the two can disagree near bucket boundaries)
-        n_s = self.msm_bucket(n) // self.nshard
-        nb1 = edops.bucket_size(n)
-        shard_plan = msmops.Plan(n_s, msmops._pick_c(n_s))
-        single_plan = msmops.Plan(nb1, msmops._pick_c(nb1))
-        return 3 * shard_plan.T * shard_plan.K_pad <= \
-            2 * single_plan.T * single_plan.K_pad
-
-    def msm_bucket(self, n: int) -> int:
-        """Padded batch size for a sharded MSM: the usual power-of-two
-        compile bucket, rounded up so every shard gets an equal row
-        count (remainder lanes become zero-scalar basepoint pad rows —
-        msm._pad_rows)."""
-        from tendermint_tpu.ops import ed25519 as edops
-
-        nb = max(edops.bucket_size(n), self.nshard)
-        return -(-nb // self.nshard) * self.nshard
-
-    def _msm_fn(self, c: int, use_pallas: bool):
-        """Cached jitted sharded MSM for window width c: each shard runs
-        the full Pippenger pipeline (ops/msm._msm_pipeline) on its batch
-        rows, producing PARTIAL window sums; the cross-shard reduction
-        happens on-mesh before anything returns to the host.  Batch
-        sizes are bucketed by the caller (msm_bucket), so jit's shape
-        cache stays one entry per (c, bucket).
-
-        The window sums are curve points, so their reduction is group
-        addition, not an arithmetic psum: all-gather the nshard partials
-        and tree-add them replicated (nshard-1 unified adds over W
-        lanes — negligible next to the per-shard scan).  The two scalar
-        verdicts (decode-ok, bucket overflow) ARE arithmetic and reduce
-        with a true psum."""
-        key = ("msm", c, use_pallas)
-        with self._lock:
-            fn = self._fns.get(key)
-        if fn is not None:
-            return fn
-        from tendermint_tpu.ops import curve as Cv
-        from tendermint_tpu.ops import msm as msmops
-
-        nshard = self.nshard
-
-        def body(r, pub, zk, z, zs):
-            # per-shard blocks: r/pub/zk (nb/nshard, 32), z (nb/nshard,
-            # 16), zs (1, 32) — only shard 0 carries the real [sum z_i
-            # s_i]B scalar, the rest hold zeros (their B items land in
-            # the weight-0 trash bucket), so the B term enters the total
-            # exactly once
-            ws, ok, ovf = msmops._msm_pipeline(r, pub, zk, z, zs[0], c,
-                                               use_pallas)
-            allw = jax.lax.all_gather(ws, BATCH_AXIS)  # (nshard, 4, ...)
-            total = Cv.Ext(*(allw[0, j] for j in range(4)))
-            for s in range(1, nshard):
-                total = Cv.add_cached(
-                    total,
-                    Cv.to_cached(Cv.Ext(*(allw[s, j] for j in range(4)))))
-            ok_all = jax.lax.psum(ok.astype(jnp.int32),
-                                  BATCH_AXIS) == nshard
-            ovf_any = jax.lax.psum(ovf.astype(jnp.int32), BATCH_AXIS) > 0
-            return jnp.stack(list(total)), ok_all, ovf_any
-
-        f = jax.jit(jax.shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(BATCH_AXIS, None),) * 5,
-            out_specs=(P(), P(), P()), check_vma=False))
-        with self._lock:
-            self._fns.setdefault(key, f)
-            return self._fns[key]
-
-    def msm_window_sums(self, r_bytes, pub_m, zk, z, zs, c: int,
-                        use_pallas: bool = False, probe: dict = None):
-        """Mesh-sharded equivalent of msm._msm_core: identical combined
-        window sums (as group elements), batch rows split across devices
-        by explicit per-shard device_puts (_put_sharded — each shard's
-        block lands directly on its device instead of one monolithic
-        put XLA re-slices).  Inputs are the padded staged arrays (batch
-        divisible by nshard); `probe` (devobs) receives the H2D wall
-        and per-shard put walls.  The MSM stays a SINGLE collective
-        launch — its output is one reduced window-sum set, so chunking
-        would demand a host-side group-add accumulation pass the comb
-        and ladder paths don't need (ADR-027).  Returns (window sums
-        (4, NLIMB, W), decode_ok_all, overflow)."""
-        import numpy as np
-
-        nb = r_bytes.shape[0]
-        assert nb % self.nshard == 0, (nb, self.nshard)
-        zs_rows = np.zeros((self.nshard, 32), dtype=np.uint8)
-        zs_rows[0] = zs
-        fn = self._msm_fn(c, use_pallas)
-        walls = []
-        args = self._put_sharded(
-            (np.asarray(r_bytes), np.asarray(pub_m), np.asarray(zk),
-             np.asarray(z), zs_rows),
-            (P(BATCH_AXIS, None),) * 5, walls=walls)
-        if probe is not None and walls:
-            probe["h2d_s"] = round(sum(walls), 6)
-            probe["shard_h2d_s"] = [round(w, 6) for w in walls]
-        return fn(*args)
-
     # -- explicit per-shard staging (ADR-027) ------------------------------
 
     def _put_sharded(self, arrays, specs, walls=None):
         """Stage a tuple of batch-major operands shard by shard: slice
-        each operand's rows for every ADDRESSABLE mesh position,
-        device_put the slices onto that device, and assemble the global
-        arrays with jax.make_array_from_single_device_arrays.  On a
-        multi-process mesh each process stages ONLY its own shards —
-        this is what lets the global plane run without any process
-        holding the full batch's device buffers.  Appends one put wall
-        per local shard position to `walls` (the devobs per-shard H2D
+        each operand's rows for every mesh position, device_put the
+        slices onto that device, and assemble the sharded arrays with
+        jax.make_array_from_single_device_arrays.  Appends one put wall
+        per shard position to `walls` (the devobs per-shard H2D
         decomposition and shard_h2d imbalance gauge)."""
         import numpy as np
 
-        try:
-            pid = jax.process_index()
-        except Exception:  # noqa: BLE001 - single-process runtime
-            pid = 0
         bufs = [[] for _ in arrays]
         for pos, d in enumerate(self.mesh.devices.flat):
-            if getattr(d, "process_index", pid) != pid:
-                continue
             t_put = time.perf_counter()
             for ai, a in enumerate(arrays):
                 per = a.shape[0] // self.nshard
@@ -616,7 +276,7 @@ class _DataPlane:
             degrade.publish_route("mesh-comb", "declined")
             return None
         # chaos seam: a raise here degrades this batch to the
-        # single-device comb in ops/ed25519._comb_try (exact bitmap)
+        # single-device comb in ops/ed25519._run_comb (exact bitmap)
         fail.inject("sharding.mesh_comb")
         if mode == "shard":
             out = self._verify_comb_sharded(r_b, s_digits, k_digits,
@@ -674,7 +334,7 @@ class _DataPlane:
                          probe):
         """Double-buffered chunk driver for the replicated mesh comb:
         pad to the usual pow2 bucket rounded to a shard multiple, split
-        into chunks of nshard * _chunk_lanes() rows when that
+        into chunks of nshard * mesh_chunk_lanes() rows when that
         divides the bucket (it always does for pow2 shard counts), and
         issue chunk j+1's per-shard device_puts right after chunk j's
         dispatch so H2D hides behind compute — the same discipline as
@@ -686,7 +346,7 @@ class _DataPlane:
 
         nshard = self.nshard
         n = r_b.shape[0]
-        lanes = min(self._chunk_lanes(),
+        lanes = min(mesh_chunk_lanes(),
                     max(1, edops.MAX_CHUNK // nshard))
         chunk_max = nshard * lanes
         nb = max(-(-edops.bucket_size(n) // nshard) * nshard, nshard)
@@ -891,7 +551,6 @@ class _DataPlane:
     # -- overlapped compact ladder (ADR-027) -------------------------------
 
     MESH_PATH = "mesh-xla"
-    FAIL_SITE = "sharding.mesh_stage"
 
     def _step_fn(self, nb: int):
         """Cached jitted compact-ladder step for one chunk shape:
@@ -899,8 +558,7 @@ class _DataPlane:
         outputs replicated — the bitmap all-gather replaces the host
         stitch, and the jnp.all over live lanes lowers to the psum'd
         all-valid bit (pad lanes read as valid so a padded bucket can
-        still report all-valid).  The global plane compiles this ahead
-        of the first collective call and barriers (_seal)."""
+        still report all-valid)."""
         key = ("step", nb)
         with self._lock:
             fn = self._fns.get(key)
@@ -915,22 +573,15 @@ class _DataPlane:
 
         f = jax.jit(step, in_shardings=(batch_sharded,) * 5,
                     out_shardings=(repl, repl))
-        f = self._seal(f, nb)
         with self._lock:
             self._fns.setdefault(key, f)
             return self._fns[key]
 
-    def _seal(self, f, nb: int):
-        """Local plane: jit compiles lazily on first call (no peers to
-        coordinate with).  The global plane overrides with an AOT
-        compile + barrier."""
-        return f
-
     def _verify_compact(self, dev, host_ok):
         """Overlapped compact-ladder mesh launch (the portable path —
-        CPU mesh tests, non-TPU backends, and the global plane): pad to
+        CPU mesh tests, non-TPU backends): pad to
         the usual pow2 bucket rounded to a shard multiple, then launch
-        double-buffered chunks of nshard * _chunk_lanes() rows —
+        double-buffered chunks of nshard * mesh_chunk_lanes() rows —
         chunk j+1's per-shard device_puts are issued right after chunk
         j's dispatch, so H2D hides behind compute exactly like
         split_chunked_launch, and the put walls feed the devobs
@@ -944,7 +595,7 @@ class _DataPlane:
         t0, c0 = time.perf_counter(), time.thread_time()
         # chaos seam: a raise here degrades this batch to the
         # single-device ladder in ops/ed25519.verify_batch
-        fail.inject(self.FAIL_SITE)
+        fail.inject("sharding.mesh_stage")
         obs_on = devobs.is_enabled()
         n = host_ok.shape[0]
         nshard = self.nshard
@@ -952,7 +603,7 @@ class _DataPlane:
         padded = edops._pad_dev(dict(dev), n, nb)
         live = np.zeros(nb, dtype=bool)
         live[:n] = True
-        chunk_max = nshard * self._chunk_lanes()
+        chunk_max = nshard * mesh_chunk_lanes()
         if not (chunk_max < nb and nb % chunk_max == 0):
             chunk_max = nb
         starts = list(range(0, nb, chunk_max))
@@ -991,9 +642,8 @@ class _DataPlane:
             if len(outs) > 1 else np.asarray(outs[0])
         all_valid = all(bool(np.asarray(f)) for f in flags)
         drain_s = time.perf_counter() - t_col
-        # all_valid is the device-reduced verdict every process of a
-        # global mesh observes identically (the psum'd bit of the
-        # acceptance criteria); recorded even with devobs off
+        # all_valid is the device-reduced verdict (the psum'd bit);
+        # recorded even with devobs off
         extra = {"all_valid": all_valid}
         if obs_on:
             probe = {"stage_s": stage_s}
@@ -1089,50 +739,6 @@ class _DataPlane:
                              time.perf_counter() - t0, shards=self.nshard,
                              extra=extra)
         return res[:n] & host_ok
-
-
-class _GlobalDataPlane(_DataPlane):
-    """The cross-process execution plane (ADR-027): the same sharded
-    compact ladder as _DataPlane but over ALL processes' devices
-    (jax.devices()), with each process staging only its addressable
-    shards (_put_sharded skips non-local mesh positions) and both
-    outputs replicated — the bitmap all-gather and the psum'd all-valid
-    bit arrive identically on every process.  Kernels compile AHEAD of
-    the first collective call with a coordination-service barrier after
-    the compile, so no process dispatches into a collective a peer is
-    still compiling.  Only reachable through global_plane(), i.e. from
-    inside a lockstep() window (blocksync replay_window, coordinated
-    bulk verify) — reactor-driven traffic keeps the local plane."""
-
-    MESH_PATH = "global-mesh"
-    FAIL_SITE = "sharding.global_plane"
-
-    def _chunk_lanes(self) -> int:
-        # the chunk count is part of the cross-process collective's
-        # shape: the per-process governed knob (and TM_TPU_MESH_CHUNK)
-        # is excluded here — two peers steered across a power-of-two
-        # boundary would otherwise launch mismatched chunk sequences
-        # into the same collective and deadlock the job
-        return _static_chunk_lanes()
-
-    def _seal(self, f, nb: int):
-        import numpy as np
-
-        batch_sharded = NamedSharding(self.mesh, P(BATCH_AXIS))
-        shapes = (((nb, 32), np.uint8), ((nb, 32), np.uint8),
-                  ((nb, 64), np.int8), ((nb, 64), np.int8),
-                  ((nb,), np.bool_))
-        args = [jax.ShapeDtypeStruct(s, d, sharding=batch_sharded)
-                for s, d in shapes]
-        compiled = f.lower(*args).compile()
-        _barrier(f"tm_tpu_gmesh_step_{nb}")
-        return compiled
-
-    def verify_batch(self, pubkeys, msgs, sigs):
-        # the compact ladder is the one kernel shape proven over DCN;
-        # the fused Pallas path stays per-process for now (ADR-027)
-        dev, host_ok = edops.prepare_batch(pubkeys, sigs, msgs)
-        return self._verify_compact(dev, host_ok)
 
 
 def make_sharded_verifier(mesh: Mesh, axis: str = BATCH_AXIS):

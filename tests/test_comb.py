@@ -21,7 +21,6 @@ for another kernel family — the guard tests below pin exactly that):
 """
 from __future__ import annotations
 
-import hashlib
 import threading
 
 import numpy as np
@@ -303,8 +302,7 @@ def test_comb_routing_build_hit_subset_mixed(monkeypatch):
 
     pubs, msgs, sigs = _batch(24)
     # below the build threshold without tables: ladder, no build
-    assert edops._comb_try(pubs[:4], msgs[:4], sigs[:4], True,
-                           None) is None
+    assert edops._comb_resolve(pubs[:4], True) is None
     assert "builds" not in rec
 
     # build + engage
@@ -592,7 +590,7 @@ def test_ladder_bound_batch_skips_distinct_key_sort(monkeypatch):
     real_unique = np.unique
     np.unique = boom
     try:
-        assert edops._comb_try(other, omsgs, osigs, False, None) is None
+        assert edops._comb_resolve(other, False) is None
     finally:
         np.unique = real_unique
     # a known-set batch still resolves (the probe passes, unique runs)
@@ -647,30 +645,6 @@ def _eager_kernels(monkeypatch):
     monkeypatch.setattr(edops, "verify_kernel", edops.verify_staged)
 
 
-def _order8_point():
-    from test_msm import _order8_point as f
-    return f()
-
-
-def _torsion_residual_sig(seed, msg):
-    """The ADR-009 divergence vector: R' = [r]B + T8 — cofactorless
-    reject (comb AND ladder must agree on it)."""
-    pub = _edref.pubkey_from_seed(seed)
-    h = hashlib.sha512(seed).digest()
-    a = int.from_bytes(h[:32], "little")
-    a &= (1 << 254) - 8
-    a |= 1 << 254
-    T8 = _order8_point()
-    r_nonce = int.from_bytes(
-        hashlib.sha512(b"comb torsion nonce").digest(), "little") % _edref.L
-    r_enc = _edref._encode(_edref._add(_edref._mul(r_nonce, _edref.BASE),
-                                       T8))
-    k = int.from_bytes(
-        hashlib.sha512(r_enc + pub + msg).digest(), "little") % _edref.L
-    s = (r_nonce + k * a) % _edref.L
-    return pub, r_enc + s.to_bytes(32, "little")
-
-
 @pytest.mark.slow
 def test_comb_bitmap_identity_sweep(monkeypatch):
     """Comb vs ladder vs host bignum oracle over every encoding class:
@@ -703,11 +677,13 @@ def test_comb_bitmap_identity_sweep(monkeypatch):
         y += 1
     pubs[7] = y.to_bytes(32, "little")
     # 8: torsion (order-8) pubkey with an honest-format signature
-    T8 = _order8_point()
+    from edvectors import order8_point, torsion_residual_sig
+    T8 = order8_point()
     pubs[8] = _edref._encode(T8)
     # 9: torsion-residual signature (ADR-009 divergence vector)
     tseed = (0x7E01).to_bytes(32, "little")
-    pubs[9], sigs[9] = _torsion_residual_sig(tseed, msgs[9])
+    pubs[9], sigs[9] = torsion_residual_sig(tseed, msgs[9],
+                                            b"comb torsion nonce")
     # 10: non-canonical pubkey y_enc = p (accepted-and-reduced to the
     # y = 0 order-4 point, matching Go's fe.SetBytes — the comb TABLES
     # are built from the same decompress, so the verdict must agree)

@@ -1,7 +1,6 @@
 """Overlapped mesh data plane (ADR-027): chunk-knob arithmetic, the
 budget ladder for comb table placement, topology-keyed plane
-invalidation, global-plane gating/latching, lockstep propagation across
-the degrade lane-worker boundary, and chaos at all three mesh seams —
+invalidation, and chaos at the two mesh seams —
 plus the slow-tier bitmap-identity sweeps with REAL kernels across
 shard counts, ragged remainders, chunked double-buffered staging, and
 the comb repl/shard/eviction matrix.
@@ -11,8 +10,6 @@ Tier-1 keeps to host-side structure and the pre-compile chaos seams
 slow-tier, same budget discipline as tests/test_comb.py.
 """
 from __future__ import annotations
-
-import os
 
 import numpy as np
 import pytest
@@ -35,26 +32,20 @@ def _mesh_state():
     far, and replacing it with None would force each later test file
     to recompile those buckets (tens of seconds per file)."""
     with sharding._PLANE_LOCK:
-        saved = (sharding._PLANE, sharding._PLANE_KEY,
-                 sharding._GLOBAL_PLANE)
+        saved = (sharding._PLANE, sharding._PLANE_KEY)
     sharding.set_mesh_chunk(None)
-    sharding._poison_seen = False
-    sharding._poison_next_check = 0.0
     fail.reset()
     edops._comb_enabled_override = None
     edops._comb_min_override = None
     edops._table_budget_override = None
     yield
     sharding.set_mesh_chunk(None)
-    sharding._poison_seen = False
-    sharding._poison_next_check = 0.0
     fail.reset()
     edops._comb_enabled_override = None
     edops._comb_min_override = None
     edops._table_budget_override = None
     with sharding._PLANE_LOCK:
-        (sharding._PLANE, sharding._PLANE_KEY,
-         sharding._GLOBAL_PLANE) = saved
+        sharding._PLANE, sharding._PLANE_KEY = saved
     degrade.reset()
 
 
@@ -169,11 +160,11 @@ def test_topology_invalidation_drops_stale_plane(monkeypatch):
     assert sharding.invalidate_on_topology_change() is False
     assert sharding.data_plane() is plane
     # the device list the plane latched on is gone (backend flap):
-    # the next probe drops all three latches for lazy rebuild
+    # the next probe drops the latch for lazy rebuild
     with sharding._PLANE_LOCK:
         sharding._PLANE_KEY = ("stale", -1)
     assert sharding.invalidate_on_topology_change() is True
-    assert sharding._PLANE is None and sharding._GLOBAL_PLANE is None
+    assert sharding._PLANE is None
     fresh = sharding.data_plane()
     assert fresh is not None and fresh is not plane
 
@@ -213,74 +204,7 @@ def test_a_tpu_host_stays_on_one_device_until_the_mesh_is_switched_on(
 
 
 # ---------------------------------------------------------------------------
-# tier-1: global-plane gating, the lockstep window, the failure latch
-# ---------------------------------------------------------------------------
-
-
-def test_global_plane_gating_and_failure_latch(monkeypatch):
-    """global_plane() answers ONLY inside a lockstep() window on a
-    multi-process runtime; a real collective fault latches it off
-    until a topology re-probe clears the latch."""
-    monkeypatch.delenv("TM_TPU_NO_MESH", raising=False)
-    # single-process runtime: never ready, lockstep or not
-    assert sharding.global_mesh_ready() is False
-    with sharding.lockstep():
-        assert sharding.global_plane() is None
-
-    # pretend a multi-process runtime: still gated on lockstep
-    monkeypatch.setattr(sharding.jax, "process_count", lambda: 2)
-    assert sharding.global_mesh_ready() is True
-    assert sharding.global_plane() is None          # not in lockstep
-    with sharding.lockstep():
-        assert sharding.in_lockstep()
-        with sharding.lockstep():                   # re-entrant
-            assert sharding.in_lockstep()
-        gp = sharding.global_plane()
-        assert gp is not None and gp.MESH_PATH == "global-mesh"
-        # a real (non-chaos) collective fault latches the plane off
-        sharding.disable_global_plane()
-        assert sharding.global_plane() is None
-    assert not sharding.in_lockstep()
-    # the kill switches win over everything
-    with sharding._PLANE_LOCK:
-        sharding._GLOBAL_PLANE = None
-    monkeypatch.setenv("TM_TPU_NO_GLOBAL_MESH", "1")
-    with sharding.lockstep():
-        assert sharding.global_plane() is None
-
-
-def test_lockstep_propagates_across_lane_worker():
-    """degrade.submit captures the caller's lockstep depth and re-arms
-    it inside the lane worker (same discipline as the trace parent
-    span): without it, every production dispatch would observe
-    in_lockstep() == False on the worker thread and the global plane
-    would be unreachable from the one call site built for it."""
-    from tendermint_tpu.libs.metrics import Registry
-
-    rt = degrade.configure(registry=Registry("mesh_lockstep"))
-    try:
-        seen = {}
-
-        def probe():
-            seen["locked"] = sharding.in_lockstep()
-            return np.ones(4, dtype=bool)
-
-        with sharding.lockstep():
-            out = rt.run("batch.ed25519", probe,
-                         lambda: np.zeros(4, dtype=bool))
-        assert np.asarray(out).all()
-        assert seen["locked"] is True
-
-        out = rt.run("batch.ed25519", probe,
-                     lambda: np.zeros(4, dtype=bool))
-        assert np.asarray(out).all()
-        assert seen["locked"] is False
-    finally:
-        degrade.reset()
-
-
-# ---------------------------------------------------------------------------
-# tier-1: chaos at the three mesh seams (pre-compile, so cheap)
+# tier-1: chaos at the two mesh seams (pre-compile, so cheap)
 # ---------------------------------------------------------------------------
 
 
@@ -316,7 +240,7 @@ def test_chaos_mesh_stage_degrades_to_single_device(monkeypatch):
 def test_chaos_mesh_comb_seam_fires_before_any_launch():
     """The sharding.mesh_comb inject sits after the budget decision and
     before any staging/dispatch: arming it raises out of verify_comb
-    (ops/ed25519._comb_try catches and runs the single-device comb)."""
+    (ops/ed25519._run_comb catches and runs the single-device comb)."""
     plane = sharding.data_plane()
     assert plane is not None
     edops._table_budget_override = 10 ** 12     # mode 'repl' for sure
@@ -367,81 +291,6 @@ class _FakeCoord:
         self.barriers.append(name)
 
 
-def test_global_plane_pins_static_chunk_lanes(monkeypatch):
-    """The chunk count is part of the cross-process collective's
-    shape, and the knob/env are steered PER-PROCESS: the global plane
-    must pin the code-constant default while the local plane keeps
-    following the governed knob — otherwise two peers steered across a
-    power-of-two boundary launch mismatched chunk sequences into the
-    same collective and deadlock."""
-    monkeypatch.delenv("TM_TPU_MESH_CHUNK", raising=False)
-    gp = sharding._GlobalDataPlane(
-        sharding.make_mesh(sharding.jax.local_devices()))
-    local = sharding.data_plane()
-    assert local is not None
-    static = sharding._static_chunk_lanes()
-    assert static == sharding.mesh_chunk_lanes()  # untouched knob
-
-    sharding.set_mesh_chunk(static // 2)           # steer the knob
-    assert local._chunk_lanes() == static // 2
-    assert gp._chunk_lanes() == static             # pinned
-    monkeypatch.setenv("TM_TPU_MESH_CHUNK", str(static // 4))
-    sharding.set_mesh_chunk(None)                  # env now governs
-    assert local._chunk_lanes() == static // 4
-    assert gp._chunk_lanes() == static             # still pinned
-
-
-def test_barrier_propagates_real_rendezvous_failure(monkeypatch):
-    """_barrier exists so no process dispatches into a collective a
-    peer is still compiling: a REAL rendezvous failure (timeout,
-    missing peer) must propagate so verify_batch's handler latches the
-    plane off — only the no-service cases are silent no-ops."""
-    boom = _FakeCoord(barrier_error=RuntimeError("barrier deadline"))
-    monkeypatch.setattr(sharding, "_coord_client", lambda: boom)
-    with pytest.raises(RuntimeError, match="barrier deadline"):
-        sharding._barrier("tm_tpu_gmesh_step_64")
-    # single-process / uninitialized runtime: no peers, no-op
-    monkeypatch.setattr(sharding, "_coord_client", lambda: None)
-    sharding._barrier("tm_tpu_gmesh_step_64")
-
-
-def test_latch_poison_propagates_cross_process(monkeypatch):
-    """disable_global_plane publishes a per-process poison key;
-    global_plane() on a HEALTHY peer sees it and latches too — one
-    faulted participant costs the job at most the in-flight batch, not
-    one degrade timeout per peer per batch — and the topology re-probe
-    that clears the local latch clears the poison directory with it."""
-    coord = _FakeCoord()
-    monkeypatch.setattr(sharding, "_coord_client", lambda: coord)
-    monkeypatch.setattr(sharding.jax, "process_count", lambda: 2)
-    monkeypatch.delenv("TM_TPU_NO_MESH", raising=False)
-    monkeypatch.delenv("TM_TPU_NO_GLOBAL_MESH", raising=False)
-
-    # the faulting process publishes its latch
-    sharding.disable_global_plane()
-    assert any(k.startswith(sharding._GMESH_POISON_DIR)
-               for k in coord.kv)
-
-    # a healthy peer with a LIVE plane latches on sight of the poison
-    gp = sharding._GlobalDataPlane(
-        sharding.make_mesh(sharding.jax.local_devices()))
-    with sharding._PLANE_LOCK:
-        sharding._GLOBAL_PLANE = gp
-    sharding._poison_seen = False
-    sharding._poison_next_check = 0.0
-    with sharding.lockstep():
-        assert sharding.global_plane() is None
-    assert sharding._GLOBAL_PLANE is False
-
-    # topology re-probe clears the local latch AND the poison keys
-    assert sharding.data_plane() is not None   # populate _PLANE
-    with sharding._PLANE_LOCK:
-        sharding._PLANE_KEY = ("stale", -1)
-    assert sharding.invalidate_on_topology_change() is True
-    assert not coord.kv
-    assert sharding._poison_seen is False
-
-
 def test_mesh_tables_ledger_charges_once_under_race():
     """Two threads racing the first comb-table replication both
     device_put (benign — one copy wins the slot) but the mesh_tables
@@ -490,65 +339,6 @@ def test_mesh_tables_ledger_charges_once_under_race():
     finally:
         devobs.reset()
         devobs.enable()
-
-
-def test_lockstep_wedge_latches_global_plane_on_first_timeout():
-    """A coordinated (lockstep) launch that wedges past the launch
-    deadline on a multi-process runtime is the global collective's
-    signature hang — a peer never entered, and the worker thread never
-    returns, so verify_batch's exception handler can't latch.  The
-    degrade settle latches on the FIRST such timeout, bounding the
-    job-wide convergence to one hung batch per process instead of one
-    launch deadline per subsequent batch."""
-    import threading as th
-    import unittest.mock as mock
-
-    from tendermint_tpu.libs.metrics import Registry
-
-    cfg = degrade.DegradeConfig()
-    cfg.launch_timeout_s = 0.05
-    rt = degrade.configure(cfg, registry=Registry("mesh_wedge"))
-    release = th.Event()
-
-    def wedged():
-        release.wait(5.0)
-        return np.ones(4, dtype=bool)
-
-    try:
-        with mock.patch.object(sharding.jax, "process_count",
-                               lambda: 2):
-            with sharding._PLANE_LOCK:
-                sharding._GLOBAL_PLANE = None
-            with sharding.lockstep():
-                out = rt.run("batch.ed25519", wedged,
-                             lambda: np.zeros(4, dtype=bool))
-            assert not np.asarray(out).any()       # host fallback
-            assert sharding._GLOBAL_PLANE is False  # first wedge latched
-
-            # a NON-lockstep wedge never touches the global latch
-            with sharding._PLANE_LOCK:
-                sharding._GLOBAL_PLANE = None
-            out = rt.run("batch.ed25519", wedged,
-                         lambda: np.zeros(4, dtype=bool))
-            assert not np.asarray(out).any()
-            assert sharding._GLOBAL_PLANE is None
-    finally:
-        release.set()
-        degrade.reset()
-
-
-def test_chaos_global_plane_seam_fires_before_any_collective():
-    """sharding.global_plane injects at the top of the global compact
-    launch — BEFORE the AOT compile/barrier — so a chaos raise degrades
-    the batch without ever entering a collective a peer would wait
-    on."""
-    gp = sharding._GlobalDataPlane(
-        sharding.make_mesh(sharding.jax.local_devices()))
-    fail.set_mode("sharding.global_plane", "raise")
-    pubs, msgs, sigs = _batch(9, tag=b"gchaos")
-    with pytest.raises(fail.InjectedFault):
-        gp.verify_batch(pubs, msgs, sigs)
-    assert fail.fired("sharding.global_plane", "raise") >= 1
 
 
 # ---------------------------------------------------------------------------
