@@ -113,8 +113,11 @@ KNOWN_SPANS = frozenset({
     # light.client.verify is the root of one request (attrs target,
     # anchor, hops, refused_skips, fetched, saved), light.fetch one
     # block from the primary, light.detect the witness cross-check, and
-    # the store's load / save (attr bytes) / prune (attr deleted)
+    # the store's load / save (attr bytes) / prune (attr deleted); inside
+    # a save its encode and inside a load its decode, one a block (attr
+    # record: columns / generic / legacy, light/record.py)
     "light.client.verify", "light.detect", "light.fetch",
+    "light.store.decode", "light.store.encode",
     "light.store.load", "light.store.prune", "light.store.save",
     # types/validator_set.py + light/verifier.py — the host work around
     # a commit's one batched launch, each ONE span per call: the set's

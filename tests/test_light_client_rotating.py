@@ -251,6 +251,45 @@ def test_the_sqlite_file_reopened_gives_the_last_target(tmp_path):
         db.close()
 
 
+def test_a_client_reopened_on_the_file_continues_from_the_columns(tmp_path):
+    """Closed and opened again on the same file, a client goes on from a
+    block the store's own record holds: the anchor of its next request
+    is decoded from columns, and the request is the reference's."""
+    from tendermint_tpu.light import record
+    first, second = targets(256, 2)
+    blocks = blocks_for(43, [first, second])
+    path = str(tmp_path / "light.db")
+    db = SQLiteDB(path)
+    client, plain = make_pair(blocks, 1000, db=db)
+    assert ask(client, first)[0] is blocks[first]
+    assert plain.verify_to_height(first, NOW).verdict == reference.OK
+    db.close()
+    db = SQLiteDB(path)
+    try:
+        assert all(v[:len(record.MAGIC)] == record.MAGIC
+                   for _, v in db.iterate_prefix(b"lb/"))
+        client, _ = make_pair(blocks, 1000, db=db)   # finds its store
+        assert client.primary.asked == []            # full: fetched nothing
+        assert client.last_trusted_height() == first
+        seq = trace.last_seq()
+        got, asked, calls, saved = ask(client, second)
+        res = plain.verify_to_height(second, NOW)
+        assert res.verdict == reference.OK and got is blocks[second]
+        assert asked == res.fetched
+        assert calls == [(to, outcome) for _, to, outcome in res.checks]
+        assert saved == res.saved and client.store.heights() == res.store
+        spans = trace.snapshot(since=seq)
+        decoded = [r["attrs"] for r in spans
+                   if r["name"] == "light.store.decode"]
+        assert decoded == [{"height": first, "record": record.COLUMNS}]
+        root = [r for r in spans if r["name"] == "light.client.verify"][-1]
+        assert root["attrs"]["anchor"] == first
+        # the anchor came back without a root: the request hashed it anew
+        assert client.store.get(first).validators._hash_memo is None
+    finally:
+        db.close()
+
+
 def test_value_reads_a_request_do_not_grow_with_the_store():
     wanted = targets(64, 6)
     client, _ = make_pair(blocks_for(31, wanted), 1000)
