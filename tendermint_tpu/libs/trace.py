@@ -122,7 +122,8 @@ KNOWN_SPANS = frozenset({
     # types/validator_set.py + light/verifier.py — the host work around
     # a commit's one batched launch, each ONE span per call: the set's
     # merkle hash (attr `memo`: answered by the memo on the validators
-    # list, nothing computed), the commit's structural checks, the
+    # list, nothing computed), the commit's structural checks
+    # (Commit.validate_basic itself, whoever calls it), the
     # trusting path's match by address, the >2/3 tally, and sign-bytes +
     # pubkey rows up to the call of verify_sigs_bulk
     "commit.collect", "commit.match", "commit.prefix",
@@ -137,8 +138,10 @@ KNOWN_SPANS = frozenset({
     # consensus/state.py
     "consensus.finalize_commit", "consensus.preverify",
     "consensus.quorum", "consensus.step", "consensus.vote",
-    # ops/ — kernel routing
-    "comb.prewarm_failed", "ops.ed25519.verify_batch",
+    # ops/ — kernel routing: comb.resolve is verify_batch looking the
+    # batch's keys up in the comb's tables ahead of the launch bracket
+    # (attrs n, outcome: resident / built / declined / unknown)
+    "comb.prewarm_failed", "comb.resolve", "ops.ed25519.verify_batch",
     "table_build",
     # state/pipeline.py — the block application pipeline (ADR-017)
     "pipeline.apply", "pipeline.commit", "pipeline.drain",

@@ -947,15 +947,24 @@ _PUB_CACHE_MAX = 4
 _pub_cache = DeviceLRU(max_entries=_PUB_CACHE_MAX)
 
 
-def _pub_cache_get(pub_rows: np.ndarray, nsub: int):
+def _pub_cache_get(pub_rows: np.ndarray, nsub: int, probe: dict = None):
     """pub_rows: (32, NB) uint8, already padded; nsub: pipeline chunk
     count.  Returns a list of nsub (32, NB/nsub) device arrays (the
     pipelined launch shape), uploading on first sight.  Thread-safe:
     multiple verifier threads (consensus, light client) route through
     verify_sigs_bulk concurrently; a racing double upload resolves to
-    one resident copy (DeviceLRU.put is first-wins)."""
+    one resident copy (DeviceLRU.put is first-wins).
+
+    `probe` (optional dict) is told whether the rows were found on the
+    device (pub_rows_cached) and, when they were not, the bytes this
+    call uploaded (pub_rows_bytes): a commit whose signers differ from
+    the last one's is new content, and pays the upload again."""
     key = (hashlib.sha256(pub_rows.tobytes()).digest(), nsub)
     chunks = _pub_cache.get(key)
+    if probe is not None:
+        probe["pub_rows_cached"] = chunks is not None
+        if chunks is None:
+            probe["pub_rows_bytes"] = int(pub_rows.nbytes)
     if chunks is not None:
         return chunks
     # upload outside the cache lock (device_put blocks on the copy)
@@ -1269,18 +1278,30 @@ def _comb_resolve(pubkeys, cache_pubs: bool):
     """What verify_batch observes for the comb: the table set every key
     of the batch resolves to (building it on a cache_pubs batch >=
     comb_min_batch()), or None: unknown keys, mixed sets, evicted
-    tables, a blown HBM budget, the comb disabled."""
+    tables, a blown HBM budget, the comb disabled.  One span a batch,
+    `outcome` resident / built / declined (the budget refused the
+    build) / unknown (every other None): a large cache_pubs batch pays
+    the key matrix, the distinct-key sort and a sha256 here on every
+    call, ahead of the launch's own bracket, whatever the outcome."""
+    with trace.span("comb.resolve", n=len(pubkeys)) as sp:
+        comb, outcome = _comb_lookup(pubkeys, cache_pubs)
+        sp.add(outcome=outcome)
+    return comb
+
+
+def _comb_lookup(pubkeys, cache_pubs: bool):
+    """(_CombSet or None, the outcome _comb_resolve's span names)."""
     n = len(pubkeys)
     if n == 0 or not comb_enabled():
-        return None
+        return None, "unknown"
     can_build = cache_pubs and n >= comb_min_batch()
     # cheap short-circuit: with nothing cached and no build possible,
     # don't pay the key-matrix conversion on every ladder-bound batch
     if len(_table_cache) == 0 and not can_build:
-        return None
+        return None, "unknown"
     pub_m = _to_u8_matrix(pubkeys, 32)
     if pub_m.shape != (n, 32):
-        return None
+        return None, "unknown"
     if not can_build:
         # a batch can only resolve to a cached set if EVERY key is in
         # the key-level index (_table_build indexes all of a set's
@@ -1290,23 +1311,24 @@ def _comb_resolve(pubkeys, cache_pubs: bool):
         # some unrelated set is cached
         with _table_key_lock:
             if pub_m[0].tobytes() not in _table_key_index:
-                return None
+                return None, "unknown"
     uniq, inverse = np.unique(pub_m, axis=0, return_inverse=True)
     inverse = np.asarray(inverse).reshape(-1)
     entry, remap = _table_lookup(uniq)
     built = False
     if entry is None:
         if not can_build:
-            return None
+            return None, "unknown"
         entry = _table_build(uniq,
                              hashlib.sha256(uniq.tobytes()).digest())
         if entry is None:
-            return None
+            return None, "declined"
         remap = np.arange(uniq.shape[0], dtype=np.int32)
         built = True
     else:
         degrade.publish_table_cache(hit=True)
-    return _CombSet(entry, pub_m, remap[inverse].astype(np.int32), built)
+    return (_CombSet(entry, pub_m, remap[inverse].astype(np.int32), built),
+            "built" if built else "resident")
 
 
 def _comb_buckets(n: int) -> list:
@@ -1471,7 +1493,8 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     `probe` (optional dict, ADR-021): filled with the summed per-chunk
     staging walls (stage_s) and DMA walls (dma_s / dma_first_s /
     chunks), measured without adding any synchronization — the
-    decomposition must never serialize the pipeline it measures."""
+    decomposition must never serialize the pipeline it measures — and
+    with what _pub_cache_get found (pub_rows_cached / pub_rows_bytes)."""
     from . import pallas_ed25519 as pe
 
     n = len(pubkeys)
@@ -1483,7 +1506,7 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     pub_rows = np.ascontiguousarray(pub_m.T)
     if nb != n:
         pub_rows = np.pad(pub_rows, [(0, 0), (0, nb - n)])
-    pub_chunks = _pub_cache_get(pub_rows, nsub)
+    pub_chunks = _pub_cache_get(pub_rows, nsub, probe)
     host_ok = np.zeros(nb, dtype=bool)
 
     stage_walls = []
@@ -1599,8 +1622,15 @@ def _run_split(pubkeys, msgs, sigs, route: Route, obs_on: bool):
     outs, host_ok, _ = split_chunked_launch(pubkeys, msgs, sigs,
                                             probe=probe)
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-    return _Launched(out, host_ok, route.nb,
-                     _overlap_phases(probe) if obs_on else {}, t0)
+    phases = _overlap_phases(probe) if obs_on else {}
+    # what _pub_cache_get found: facts, not timings, so on the launch
+    # record and on verify_batch's span whether or not the observatory
+    # brackets the launch
+    rows = {k: probe[k] for k in ("pub_rows_cached", "pub_rows_bytes")
+            if k in probe}
+    phases.update(rows)
+    trace.current().add(**rows)
+    return _Launched(out, host_ok, route.nb, phases, t0)
 
 
 def _run_pallas(pubkeys, msgs, sigs, route: Route, obs_on: bool):

@@ -13,6 +13,7 @@ from typing import List, Optional
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.libs import protodec as pd
 from tendermint_tpu.libs import protoenc as pe
+from tendermint_tpu.libs import trace
 
 from .basic import BlockID, BlockIDFlag, SignedMsgType, Timestamp
 from .canonical import canonical_vote_bytes
@@ -131,17 +132,24 @@ class Commit:
             [s.proto() for s in self.signatures])
 
     def validate_basic(self):
-        if self.height < 0:
-            raise ValueError("negative height")
-        if self.round < 0:
-            raise ValueError("negative round")
-        if self.height >= 1:
-            if self.block_id.is_zero():
-                raise ValueError("commit cannot be for nil block")
-            if not self.signatures:
-                raise ValueError("no signatures in commit")
-            for i, sig in enumerate(self.signatures):
-                try:
-                    sig.validate_basic()
-                except ValueError as e:
-                    raise ValueError(f"wrong CommitSig #{i}: {e}") from e
+        # ONE span a commit, around the loop over its signatures: the
+        # light verifier (through SignedHeader.validate_basic) and the
+        # node (Block.validate_basic, a LastCommit before verify_commit)
+        # both come through here
+        with trace.span("commit.validate_basic",
+                        sigs=len(self.signatures)):
+            if self.height < 0:
+                raise ValueError("negative height")
+            if self.round < 0:
+                raise ValueError("negative round")
+            if self.height >= 1:
+                if self.block_id.is_zero():
+                    raise ValueError("commit cannot be for nil block")
+                if not self.signatures:
+                    raise ValueError("no signatures in commit")
+                for i, sig in enumerate(self.signatures):
+                    try:
+                        sig.validate_basic()
+                    except ValueError as e:
+                        raise ValueError(
+                            f"wrong CommitSig #{i}: {e}") from e

@@ -1,0 +1,215 @@
+"""The `pallas-split` route as a pipeline of several chunks, and what
+verify_batch says of a batch on its way there (ISSUE 31): the chunks carry
+real verdicts with bad lanes on both sides of a seam; `pub_rows_cached` on
+the launch record and on the `ops.ed25519.verify_batch` span follows the
+CONTENT of the batch's pubkey rows and the cache's four entries; and
+`comb.resolve` names each way the comb's look-up can end.
+
+The fused kernel compiles for a TPU only, so the route is taken here with
+the chunk patched small (as tests/test_ed25519.py::test_pub_cache_routing
+does) and the XLA kernel standing in for the Pallas one, chunk by chunk, on
+the very rows the route staged for it: what is tested is the route, its
+seams and its records, not the kernel (tests/test_pallas_ed25519.py,
+tests/test_tpu_lowering.py)."""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+from tendermint_tpu.crypto import _edref, degrade
+from tendermint_tpu.libs import trace
+from tendermint_tpu.ops import ed25519 as edops
+from tendermint_tpu.ops import pallas_ed25519 as pe
+
+CHUNK = 128
+
+
+def xla_on_split_rows(pub_t, rsk, tile=None):
+    """verify_packed_split_pallas's contract ((32, B) pubkey rows, (96, B)
+    R | s | k rows -> (B,) bool) met by the XLA kernel."""
+    import jax.numpy as jnp
+
+    assert pub_t.shape == (32, CHUNK) and rsk.shape == (96, CHUNK)
+    pub = np.ascontiguousarray(np.asarray(pub_t).view(np.uint8).T)
+    rows = np.asarray(rsk).view(np.uint8)
+    r, s, k = (np.ascontiguousarray(rows[a:a + 32].T) for a in (0, 32, 64))
+    return edops.verify_kernel(
+        jnp.asarray(pub), jnp.asarray(r),
+        jnp.asarray(edops.scalars_to_digits(s)),
+        jnp.asarray(edops.scalars_to_digits(k)))
+
+
+@pytest.fixture
+def split_route(monkeypatch):
+    monkeypatch.setattr(edops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(edops, "PUB_CACHE_MIN", 64)
+    monkeypatch.setattr(edops, "SPLIT_CHUNK", CHUNK)
+    monkeypatch.setattr(edops, "PALLAS_TILE", 32)
+    monkeypatch.setattr(edops, "_pub_cache",
+                        edops.DeviceLRU(max_entries=edops._PUB_CACHE_MAX))
+    monkeypatch.setattr(edops, "_comb_enabled_override", False)
+    from tendermint_tpu.parallel import sharding
+    monkeypatch.setattr(sharding, "_PLANE", False)
+    trace.enable()
+    trace.reset()
+    yield
+    trace.disable()
+    trace.reset()
+
+
+def batch(n, first=0, tag=b"split"):
+    seeds = [(0x5100 + first + i).to_bytes(32, "little") for i in range(n)]
+    msgs = [b"%s %d" % (tag, first + i) for i in range(n)]
+    return ([_edref.pubkey_from_seed(s) for s in seeds], msgs,
+            [_edref.sign(s, m) for s, m in zip(seeds, msgs)])
+
+
+def all_true(pub_t, rsk, tile=None):
+    import jax.numpy as jnp
+    return jnp.ones(rsk.shape[1], dtype=bool)
+
+
+def launch_facts():
+    """(the last launch record, the verify_batch span's attrs)."""
+    (span,) = [r for r in trace.snapshot()
+               if r["name"] == "ops.ed25519.verify_batch"]
+    trace.reset()
+    return edops.last_launch(), span["attrs"]
+
+
+def test_three_chunks_carry_real_verdicts_across_their_seams(
+        split_route, monkeypatch):
+    monkeypatch.setattr(pe, "verify_packed_split_pallas", xla_on_split_rows)
+    n = 300
+    pubs, msgs, sigs = batch(n)
+    # both ends, both sides of the first seam, one in the third chunk
+    bad = [0, CHUNK - 1, CHUNK, 2 * CHUNK + 7, n - 1]
+    sigs = [bytes([s[0] ^ 1]) + s[1:] if i in bad else s
+            for i, s in enumerate(sigs)]
+    out = edops.verify_batch(pubs, msgs, sigs, cache_pubs=True)
+    want = np.array([_edref.verify(p, m, s)
+                     for p, m, s in zip(pubs, msgs, sigs)])
+    assert [int(i) for i in np.flatnonzero(~want)] == bad
+    assert np.array_equal(out, want)
+    rec, attrs = launch_facts()
+    assert (rec["path"], rec["n"], rec["nb"]) == ("pallas-split", n, 384)
+    assert rec["chunks"] == 3 and rec["drain_s"] >= 0 and rec["h2d_s"] >= 0
+    assert 0.0 <= rec["chunk_overlap"] <= 1.0
+    assert rec["pub_rows_cached"] is False
+    assert rec["pub_rows_bytes"] == 32 * 384
+    assert attrs["pub_rows_cached"] is False
+    assert attrs["pub_rows_bytes"] == 32 * 384
+
+
+def test_pub_rows_cached_follows_the_content_of_the_rows(
+        split_route, monkeypatch):
+    """The same rows again are found on the device; the same set with one
+    signer absent is new content, and is uploaded again."""
+    monkeypatch.setattr(pe, "verify_packed_split_pallas", all_true)
+    pubs, msgs, sigs = batch(200)
+    seen = []
+    for rows in (range(200), range(200), [i for i in range(200) if i != 77],
+                 range(200)):
+        edops.verify_batch([pubs[i] for i in rows], [msgs[i] for i in rows],
+                           [sigs[i] for i in rows], cache_pubs=True)
+        rec, attrs = launch_facts()
+        assert attrs["pub_rows_cached"] is rec["pub_rows_cached"]
+        assert ("pub_rows_bytes" in rec) is not rec["pub_rows_cached"]
+        seen.append(rec["pub_rows_cached"])
+    assert seen == [False, True, False, True]
+    assert len(edops._pub_cache) == 2
+
+
+def test_a_fifth_set_of_rows_evicts_the_first(split_route, monkeypatch):
+    monkeypatch.setattr(pe, "verify_packed_split_pallas", all_true)
+    pubs, msgs, sigs = batch(205)
+    seen = []
+    # five commits of one set, another validator absent in each, then the
+    # first again: the cache is four deep
+    for absent in (200, 201, 202, 203, 204, 200, 204):
+        rows = [i for i in range(205) if i != absent]
+        edops.verify_batch([pubs[i] for i in rows], [msgs[i] for i in rows],
+                           [sigs[i] for i in rows], cache_pubs=True)
+        seen.append(launch_facts()[0]["pub_rows_cached"])
+    assert seen == [False] * 6 + [True]
+    assert len(edops._pub_cache) == edops._PUB_CACHE_MAX
+
+
+def test_a_route_that_keeps_no_rows_says_nothing_of_them(split_route,
+                                                         monkeypatch):
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(edops, "_use_pallas", lambda: False)
+    monkeypatch.setattr(edops, "verify_kernel", lambda pub, r, s, k:
+                        jnp.ones(pub.shape[0], dtype=bool))
+    pubs, msgs, sigs = batch(40)
+    edops.verify_batch(pubs, msgs, sigs, cache_pubs=True)
+    rec, attrs = launch_facts()
+    assert rec["path"] == "xla"
+    assert "pub_rows_cached" not in rec and "pub_rows_cached" not in attrs
+
+
+# ---------------------------------------------------------------------------
+# comb.resolve
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def comb_world(monkeypatch):
+    import jax.numpy as jnp
+    from tendermint_tpu.ops import curve as C
+
+    edops.table_cache_clear()
+    monkeypatch.setattr(edops, "_comb_enabled_override", None)
+    monkeypatch.setattr(edops, "_comb_min_override", 8)
+    monkeypatch.setattr(edops, "_table_budget_override", None)
+    monkeypatch.setattr(
+        edops, "comb_build_kernel",
+        lambda pub: (C.Cached(None, None, None, None),
+                     jnp.ones(pub.shape[0], dtype=bool)))
+    trace.enable()
+    trace.reset()
+    yield
+    trace.disable()
+    trace.reset()
+    edops.table_cache_clear()
+    degrade.reset()
+
+
+def resolve(pubs, cache_pubs):
+    trace.reset()
+    comb = edops._comb_resolve(pubs, cache_pubs)
+    (span,) = [r for r in trace.snapshot() if r["name"] == "comb.resolve"]
+    assert span["attrs"]["n"] == len(pubs)
+    return comb, span["attrs"]["outcome"]
+
+
+@pytest.mark.parametrize("outcome", ["built", "resident", "declined",
+                                     "unknown"])
+def test_comb_resolve_names_each_way_the_lookup_ends(comb_world, outcome,
+                                                     monkeypatch):
+    pubs, _, _ = batch(24, tag=b"resolve")
+    if outcome == "built":
+        comb, got = resolve(pubs, True)
+        assert comb is not None and comb.built
+    elif outcome == "resident":
+        resolve(pubs, True)
+        # found again, by a batch that may not build and by a subset
+        comb, got = resolve(pubs, False)
+        assert comb is not None and not comb.built
+        assert resolve(pubs[:5], False)[1] == "resident"
+    elif outcome == "declined":
+        # the budget holds no table of 24 keys (32 padded)
+        monkeypatch.setattr(edops, "_table_budget_override",
+                            8 * edops._TABLE_BYTES_PER_KEY)
+        comb, got = resolve(pubs, True)
+        assert comb is None
+    else:
+        # keys no table holds, in a batch that may not build; the comb
+        # switched off; an empty batch
+        resolve(pubs, True)
+        others, _, _ = batch(24, first=500, tag=b"resolve")
+        comb, got = resolve(others, False)
+        assert comb is None
+        monkeypatch.setattr(edops, "_comb_enabled_override", False)
+        assert resolve(pubs, True) == (None, "unknown")
+    assert got == outcome
