@@ -140,7 +140,9 @@ KNOWN_SPANS = frozenset({
     "consensus.quorum", "consensus.step", "consensus.vote",
     # ops/ — kernel routing: comb.resolve is verify_batch looking the
     # batch's keys up in the comb's tables ahead of the launch bracket
-    # (attrs n, outcome: resident / built / declined / unknown)
+    # (attrs n, outcome: resident / built / declined / unknown, and
+    # early: true when the batch left by the bound on its distinct keys,
+    # ops/ed25519._comb_over_cap, ahead of the distinct-key sort)
     "comb.prewarm_failed", "comb.resolve", "ops.ed25519.verify_batch",
     "table_build",
     # state/pipeline.py — the block application pipeline (ADR-017)

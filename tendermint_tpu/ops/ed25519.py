@@ -1109,6 +1109,40 @@ def _comb_k_pad(k: int) -> int:
     return max(8, 1 << (k - 1).bit_length())
 
 
+def _comb_key_cap() -> int:
+    """The most distinct keys any table set can hold right now: the
+    largest k whose padded tables fit the budget (_table_build's own
+    test: 1,024 at the default 256 MB, 0 under 8 padded keys), or the
+    largest k resident, whichever is larger: an entry built under a
+    budget that set_comb_config has since shrunk is still found by
+    _table_lookup."""
+    fit = table_cache_budget_bytes() // _TABLE_BYTES_PER_KEY
+    cap = 1 << (fit.bit_length() - 1) if fit >= 8 else 0
+    for set_hash in _table_cache.keys():
+        entry = _table_cache.peek(set_hash)
+        if entry is not None:
+            cap = max(cap, entry.k)
+    return cap
+
+
+def _comb_over_cap(pub_m: np.ndarray) -> bool:
+    """True when the key matrix provably holds more distinct keys than
+    any table set can (_comb_key_cap), from work that is O(cap): the
+    distinct 8-byte prefixes of the first 4 * (cap + 1) rows are a
+    lower bound on the batch's distinct keys.  Over the cap no look-up
+    can succeed (an entry holds every distinct key of a batch it
+    answers) and no build fit (_comb_k_pad is monotone), so the caller
+    leaves ahead of the distinct-key sort with the answer the sort
+    would have reached.  False decides nothing: a head of repeated keys
+    falls through to the full look-up.  A batch of <= cap rows counts
+    nothing."""
+    cap = _comb_key_cap()
+    if pub_m.shape[0] <= cap:
+        return False
+    head = np.ascontiguousarray(pub_m[:4 * (cap + 1), :8])
+    return np.unique(head.view("<u8")).size > cap
+
+
 def _table_build(uniq: np.ndarray, set_hash: bytes):
     """Build + cache the comb tables for a distinct-key matrix (K, 32).
     Returns the CombTables entry, or None when the HBM budget says no
@@ -1202,6 +1236,9 @@ def prewarm(pubkeys, warm_kernel: bool = True) -> bool:
     pub_m = _to_u8_matrix(keys, 32)
     if pub_m.shape != (len(keys), 32):
         return False
+    if _comb_over_cap(pub_m):
+        degrade.publish_route("comb", "declined")
+        return False
     uniq = np.unique(pub_m, axis=0)
     entry, _ = _table_lookup(uniq)
     if entry is None:
@@ -1280,28 +1317,32 @@ def _comb_resolve(pubkeys, cache_pubs: bool):
     comb_min_batch()), or None: unknown keys, mixed sets, evicted
     tables, a blown HBM budget, the comb disabled.  One span a batch,
     `outcome` resident / built / declined (the budget refused the
-    build) / unknown (every other None): a large cache_pubs batch pays
-    the key matrix, the distinct-key sort and a sha256 here on every
-    call, ahead of the launch's own bracket, whatever the outcome."""
+    build) / unknown (every other None), and `early`: true when the
+    batch left by the bound (_comb_over_cap: more distinct keys in its
+    head than any table set can hold, so it paid the key matrix and a
+    count of O(cap) prefixes), false when it paid the distinct-key sort
+    and a sha256 over all its rows, as a batch the tables might hold
+    does on every call, ahead of the launch's own bracket."""
     with trace.span("comb.resolve", n=len(pubkeys)) as sp:
-        comb, outcome = _comb_lookup(pubkeys, cache_pubs)
-        sp.add(outcome=outcome)
+        comb, outcome, early = _comb_lookup(pubkeys, cache_pubs)
+        sp.add(outcome=outcome, early=early)
     return comb
 
 
 def _comb_lookup(pubkeys, cache_pubs: bool):
-    """(_CombSet or None, the outcome _comb_resolve's span names)."""
+    """(_CombSet or None, the outcome and the `early` that
+    _comb_resolve's span names)."""
     n = len(pubkeys)
     if n == 0 or not comb_enabled():
-        return None, "unknown"
+        return None, "unknown", False
     can_build = cache_pubs and n >= comb_min_batch()
     # cheap short-circuit: with nothing cached and no build possible,
     # don't pay the key-matrix conversion on every ladder-bound batch
     if len(_table_cache) == 0 and not can_build:
-        return None, "unknown"
+        return None, "unknown", False
     pub_m = _to_u8_matrix(pubkeys, 32)
     if pub_m.shape != (n, 32):
-        return None, "unknown"
+        return None, "unknown", False
     if not can_build:
         # a batch can only resolve to a cached set if EVERY key is in
         # the key-level index (_table_build indexes all of a set's
@@ -1311,24 +1352,30 @@ def _comb_lookup(pubkeys, cache_pubs: bool):
         # some unrelated set is cached
         with _table_key_lock:
             if pub_m[0].tobytes() not in _table_key_index:
-                return None, "unknown"
+                return None, "unknown", False
+    if _comb_over_cap(pub_m):
+        # what the sort, the look-up and _table_build would answer
+        if not can_build:
+            return None, "unknown", True
+        degrade.publish_route("comb", "declined")
+        return None, "declined", True
     uniq, inverse = np.unique(pub_m, axis=0, return_inverse=True)
     inverse = np.asarray(inverse).reshape(-1)
     entry, remap = _table_lookup(uniq)
     built = False
     if entry is None:
         if not can_build:
-            return None, "unknown"
+            return None, "unknown", False
         entry = _table_build(uniq,
                              hashlib.sha256(uniq.tobytes()).digest())
         if entry is None:
-            return None, "declined"
+            return None, "declined", False
         remap = np.arange(uniq.shape[0], dtype=np.int32)
         built = True
     else:
         degrade.publish_table_cache(hit=True)
     return (_CombSet(entry, pub_m, remap[inverse].astype(np.int32), built),
-            "built" if built else "resident")
+            "built" if built else "resident", False)
 
 
 def _comb_buckets(n: int) -> list:

@@ -3,7 +3,9 @@ verify_batch says of a batch on its way there (ISSUE 31): the chunks carry
 real verdicts with bad lanes on both sides of a seam; `pub_rows_cached` on
 the launch record and on the `ops.ed25519.verify_batch` span follows the
 CONTENT of the batch's pubkey rows and the cache's four entries; and
-`comb.resolve` names each way the comb's look-up can end.
+`comb.resolve` names each way the comb's look-up can end, among them the
+early exit of a batch no table set can hold (ISSUE 32), which is held to
+the full look-up's answer and shown not to sort.
 
 The fused kernel compiles for a TPU only, so the route is taken here with
 the chunk patched small (as tests/test_ed25519.py::test_pub_cache_routing
@@ -213,3 +215,146 @@ def test_comb_resolve_names_each_way_the_lookup_ends(comb_world, outcome,
         monkeypatch.setattr(edops, "_comb_enabled_override", False)
         assert resolve(pubs, True) == (None, "unknown")
     assert got == outcome
+
+
+# ---------------------------------------------------------------------------
+# the early exit of the look-up (ISSUE 32): a batch whose head already
+# holds more distinct keys than any table set can hold leaves ahead of the
+# distinct-key sort, with the answer the sort would have reached
+# ---------------------------------------------------------------------------
+
+CAP = 16   # keys the budget below holds: 16 padded keys, none more
+
+
+def keys(n, first=0):
+    """Distinct 32-byte keys; the stubbed build never decodes them."""
+    import hashlib
+    return [hashlib.sha256(b"early %d" % (first + i)).digest()
+            for i in range(n)]
+
+
+def budget(monkeypatch, padded_keys):
+    monkeypatch.setattr(edops, "_table_budget_override",
+                        padded_keys * edops._TABLE_BYTES_PER_KEY)
+
+
+# name -> (batches resolved first, each (pubkeys, cache_pubs, budget in
+# padded keys); the batch asked about, its cache_pubs and budget; the
+# outcome and `early` its span must carry)
+EARLY_CASES = {
+    "distinct_keys_equal_the_cap":
+        ([], (keys(CAP), True, CAP), "built", False),
+    "a_long_batch_of_cap_distinct_keys":
+        ([], (keys(CAP) * 3, True, CAP), "built", False),
+    "distinct_keys_one_over_the_cap":
+        ([], (keys(CAP + 1), True, CAP), "declined", True),
+    "many_rows_of_a_few_resident_keys":
+        ([(keys(10), True, CAP)],
+         (keys(10) * 5, True, CAP), "resident", False),
+    "duplicates_fill_the_head_the_sample_reads":
+        ([], (keys(1) * (4 * (CAP + 1)) + keys(CAP + 4), True, CAP),
+         "declined", False),
+    "a_resident_set_larger_than_the_shrunk_budget":
+        ([(keys(24), True, 32)], (keys(24), False, 8), "resident", False),
+    "rows_of_that_set_beyond_its_size":
+        ([(keys(24), True, 32)], (keys(24) * 2, True, 8), "resident",
+         False),
+    "over_the_resident_set_and_the_shrunk_budget":
+        ([(keys(24), True, 32)], (keys(25), True, 8), "declined", True),
+    "a_batch_that_may_not_build":
+        ([(keys(10), True, CAP)],
+         (keys(10) + keys(20, first=100), False, CAP), "unknown", True),
+    "budget_zero":
+        ([], (keys(12), True, 0), "declined", True),
+}
+
+
+def lookup_in_a_fresh_world(monkeypatch, case, early_exit: bool):
+    """(comb, span attrs, comb/declined count) of the case's last batch."""
+    from tendermint_tpu.libs.metrics import Registry
+
+    before, asked, _, _ = EARLY_CASES[case]
+    edops.table_cache_clear()
+    rt = degrade.configure(registry=Registry("early_" + case))
+    with monkeypatch.context() as m:
+        if not early_exit:
+            m.setattr(edops, "_comb_over_cap", lambda pub_m: False)
+        for pubs, cache_pubs, padded in before + [asked]:
+            budget(m, padded)
+            trace.reset()
+            comb = edops._comb_resolve(pubs, cache_pubs)
+        (span,) = [r for r in trace.snapshot()
+                   if r["name"] == "comb.resolve"]
+    return comb, span["attrs"], rt.metrics.msm_route.value(
+        path="comb", outcome="declined")
+
+
+def comb_facts(comb):
+    if comb is None:
+        return None
+    return (comb.entry.k, comb.entry.k_pad, comb.entry.set_hash,
+            sorted(comb.entry.index.items()), comb.pub_m.tobytes(),
+            comb.vidx.tolist(), comb.built)
+
+
+@pytest.mark.parametrize("case", sorted(EARLY_CASES))
+def test_the_early_exit_answers_as_the_full_lookup_does(comb_world, case,
+                                                        monkeypatch):
+    _, _, outcome, early = EARLY_CASES[case]
+    comb, attrs, declined = lookup_in_a_fresh_world(monkeypatch, case, True)
+    full, full_attrs, full_declined = lookup_in_a_fresh_world(
+        monkeypatch, case, False)
+    assert (attrs["outcome"], attrs["early"]) == (outcome, early)
+    assert (full_attrs["outcome"], full_attrs["early"]) == (outcome, False)
+    assert comb_facts(comb) == comb_facts(full)
+    assert (comb is None) == (outcome in ("declined", "unknown"))
+    assert declined == full_declined == (outcome == "declined")
+
+
+def test_the_key_cap_is_the_budgets_or_a_residents(comb_world, monkeypatch):
+    per_key = edops._TABLE_BYTES_PER_KEY
+    for budget_bytes, cap in ((256 << 20, 1024), (2048 * per_key, 2048),
+                              (2048 * per_key - 1, 1024), (8 * per_key, 8),
+                              (8 * per_key - 1, 0), (0, 0), (-1, 0)):
+        monkeypatch.setattr(edops, "_table_budget_override", budget_bytes)
+        assert edops._comb_key_cap() == cap
+        # the cap is _table_build's own test, from the other side
+        for k, fits in ((cap, True), (cap + 1, False)):
+            if k:
+                assert (edops._comb_k_pad(k) * per_key
+                        <= budget_bytes) == fits
+    budget(monkeypatch, 32)
+    assert edops._comb_resolve(keys(24), True).built
+    budget(monkeypatch, 8)
+    assert edops._comb_key_cap() == 24
+    edops.table_cache_clear()
+    assert edops._comb_key_cap() == 8
+
+
+def test_an_early_decline_sorts_no_rows(comb_world, monkeypatch):
+    from tendermint_tpu.libs.metrics import Registry
+
+    real_unique = np.unique
+
+    def unique_of_one_axis_only(a, *args, **kwargs):
+        assert kwargs.get("axis") is None, "the distinct-key sort ran"
+        return real_unique(a, *args, **kwargs)
+
+    rt = degrade.configure(registry=Registry("early_no_sort"))
+    budget(monkeypatch, CAP)
+    pubs = keys(4 * CAP)
+    with monkeypatch.context() as m:
+        m.setattr(np, "unique", unique_of_one_axis_only)
+        # a set the tables hold does sort: the guard is armed
+        with pytest.raises(AssertionError, match="distinct-key sort"):
+            edops._comb_lookup(pubs[:CAP], True)
+        assert resolve(pubs, True) == (None, "declined")
+        (span,) = [r for r in trace.snapshot()
+                   if r["name"] == "comb.resolve"]
+        assert span["attrs"]["early"] is True
+        assert edops.prewarm(pubs) is False
+        # a matrix of key rows (verify_sigs_bulk's aligned batches)
+        rows = np.frombuffer(b"".join(pubs), dtype=np.uint8).reshape(-1, 32)
+        assert edops._comb_lookup(rows, True) == (None, "declined", True)
+    assert len(edops._table_cache) == 0
+    assert rt.metrics.msm_route.value(path="comb", outcome="declined") == 3
