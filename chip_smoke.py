@@ -7,7 +7,10 @@ edops.prewarm, blocksync.replay_window behind the BlockPipeline) at 150 /
 10,000 / 100,000 validators, checks every verdict against per-signature
 OpenSSL (the `cryptography` package, called directly — not through the repo's
 key types), and checks that each launch took the route the row count says it
-must.  The degrade ladder stays armed; any use of it fails the run.
+must.  One phase verifies a 10,000-validator commit whose keys are
+ed25519, secp256k1 and sr25519 in thirds, one device lane a scheme, against
+the benchmark's plain per-scheme reference.  The degrade ladder stays armed;
+any use of it fails the run.
 
 It refuses to start (exit 2, reason on stderr, nothing on stdout) unless JAX's
 platform is `tpu`, no TM_TPU_* variable steers the path, the native staging
@@ -23,6 +26,7 @@ Walls in it are set-up facts for planning, not results; nothing is claimed.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import faulthandler
 import hashlib
 import json
@@ -691,6 +695,47 @@ def phase_commit_100k(ph: Phase, world):
     return sum(1 for r in ph.launches if r["n"] >= edops.comb_min_batch())
 
 
+def phase_commit_10k_mixed(ph: Phase, world):
+    """ValidatorSet.verify_commit on a set whose keys are ed25519,
+    secp256k1 and sr25519 in thirds (BASELINE config 5): one device lane
+    a scheme, each on the route its row count says, the other two lanes'
+    first launch compiled on the lane worker inside the launch deadline's
+    stopped clock.  Then every case of `val10k-mixed-commit`'s check
+    (perfbench/traffic/mixed_commit.py `cases`): the program's verdicts
+    and its tampered bitmap against the plain per-scheme reference
+    (perfbench/reference/mixed_commit.py: OpenSSL, BIP-340 and schnorrkel
+    in Python integers)."""
+    from perfbench.traffic import mixed_commit as gen
+    from tendermint_tpu.ops import ed25519 as edops
+    from tendermint_tpu.ops import secp, sr25519
+
+    w = world["mixed"]
+    commit = w["commits"][-1]
+    rows = gen._scheme_rows(w, commit)
+    _, recs, wall = ph.capture(lambda: w["vset"].verify_commit(
+        CHAIN_ID, commit.block_id, commit.height, commit))
+    n_ed = len(rows["ed25519"])
+    want = sorted([
+        (n_ed,) + expected_route(n_ed, False, False, ph.nshard, ph.pallas),
+        (len(rows["secp256k1"]), secp.LANE_PATH,
+         edops.bucket_size(len(rows["secp256k1"])), 1),
+        (len(rows["sr25519"]), sr25519.LANE_PATH,
+         edops.bucket_size(len(rows["sr25519"])), 1)])
+    got = sorted((r["n"], r["path"], r["nb"], r["shards"]) for r in recs)
+    ph.check(got == want, f"verify_commit: launches (n, path, nb, shards) "
+                          f"{got}, expected {want}")
+    ph.facts["first_call_s"] = round(wall, 3)
+    ph.facts["lane_wall_s"] = {r["path"]: round(r["wall_s"], 3)
+                               for r in recs}
+    # not `repeat`: the check's short commit (2/3 of the rows) pads to
+    # the first call's buckets at 10,000 validators, and may not at a
+    # test's few hundred; a compile here shows under cold_launches
+    failures, _, wall = ph.capture(lambda: gen.check(w))
+    ph.facts["check_s"] = round(wall, 3)
+    for why in failures:
+        ph.check(False, why)
+
+
 # ---------------------------------------------------------------------------
 # the run
 # ---------------------------------------------------------------------------
@@ -715,6 +760,19 @@ def build_world(seed: int, n_mid: int, n_big: int) -> dict:
         w["vset" + tag] = vset
         w["commit" + tag] = signed_commit(vset, ordered, 9,
                                           block_id(tag.encode()))
+    # the mid-size set again in three key schemes, the k-th key of scheme
+    # k mod 3: the benchmark's own generator (keys and signatures by the
+    # plain signers beside its reference, in worker processes), one
+    # commit, 1% of the set absent
+    from perfbench.traffic import mixed_commit as gen
+    schemes = ("ed25519", "secp256k1", "sr25519")
+    w["mixed"] = gen.setup(
+        {"name": "chip-smoke-mixed", "chain_id": CHAIN_ID,
+         "validators": n_mid, "voting_power": 1, "absent_share": 0.01,
+         "key_types": {s: len(range(j, n_mid, 3))
+                       for j, s in enumerate(schemes)}},
+        {"ring": 0, "expect_launch": []}, seed, 0.0)
+    w["mixed"]["span"] = lambda name: contextlib.nullcontext()
     return w
 
 
@@ -757,6 +815,11 @@ def run_phases(seed: int, nshard: int, pallas: bool = True,
     declines = 0
     try:
         ladder_bits = run("commit_150", phase_commit_150, world, False)
+        # before the scheduler is up: its 9,900 rows are more than a
+        # scheduler window holds (max_batch 8,192) and take the direct
+        # path beside a running scheduler too; so placed, the small walk
+        # of the tests takes that path as well
+        run("commit_10k_mixed", phase_commit_10k_mixed, world)
         vs = cfg.verify_scheduler
         sched = vsched.install(vsched.VerifyScheduler(
             window_s=vs.window_ms / 1000.0, max_batch=vs.max_batch,

@@ -255,9 +255,7 @@ class BatchVerifier:
                     f"batch.{tname}", fut,
                     host_fn=partial(_host_verify_items, tname, items),
                     spot_check=_spot_check_items(items))
-                lane_times.append((tname, "device", t0,
-                                   done_at[0] if done_at
-                                   else time.monotonic()))
+                lane_times.append(_device_lane_wall(tname, fut, t0, done_at))
         _publish_lane_report(lane_times, sp, rt is not None)
         # remember the valid ones so later serial re-checks are cache hits
         with trace.span("batch.verdict") as vsp:
@@ -332,6 +330,19 @@ def _lane_done_stamp(fut) -> list:
         done_at.append(time.monotonic())
     fut.add_done_callback(_stamp)
     return done_at
+
+
+def _device_lane_wall(tname: str, fut, t0: float, done_at: list) -> tuple:
+    """A settled device lane's (scheme, kind, start, end) for the lane
+    report.  It starts when the lane worker took it up, not at its
+    submit: the worker is one thread, so with a device lane a scheme
+    (a set in three key schemes) the second and third wait in its queue
+    behind the first, and a bracket opened at submit would count that
+    wait as the lane's own wall and read lanes run one after another as
+    overlapped.  `t0` (the submit) stands in for a lane that never
+    started."""
+    return (tname, "device", getattr(fut, "started_at", t0),
+            done_at[0] if done_at else time.monotonic())
 
 
 _last_lanes: dict = {}

@@ -328,6 +328,10 @@ class _LaneWorker:
             fn, f = item
             if not f.set_running_or_notify_cancel():
                 continue
+            # when the worker took the task up: a lane queued behind
+            # another's launch began HERE, not at its submit (the lane
+            # report's wall brackets, crypto/batch._device_lane_wall)
+            f.started_at = time.monotonic()
             try:
                 f.set_result(fn())
             except BaseException as e:  # noqa: BLE001 - future carries it

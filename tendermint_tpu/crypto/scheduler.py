@@ -747,9 +747,8 @@ class VerifyScheduler(BaseService):
                             partial(_batch._host_verify_items,
                                     tname, items, assume_miss=True)),
                         spot_check=_batch._spot_check_items(items))
-                    lane_times.append((tname, "device", t0,
-                                       done_at[0] if done_at
-                                       else time.monotonic()))
+                    lane_times.append(
+                        _batch._device_lane_wall(tname, fut, t0, done_at))
             lane_rep = _batch._publish_lane_report(lane_times, sp,
                                                    rt is not None)
             if tracing and len(device_lanes) == 1:

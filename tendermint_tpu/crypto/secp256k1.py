@@ -5,11 +5,12 @@ secp256k1.go:134-146 Sign, :195-213 VerifySignature) over SHA-256(msg),
 64-byte R||S signatures, 33-byte compressed pubkeys, and Bitcoin-style
 addresses RIPEMD160(SHA256(pubkey)) (secp256k1.go:161-173).
 
-Host implementation (pure Python bignum).  secp256k1 verification is a tiny
-minority of a Tendermint workload (validator keys are overwhelmingly
-ed25519), so it rides the BatchVerifier's host lane; a TPU limb kernel like
-ops/ed25519.py would follow the same recipe if a chain weighted toward
-secp keys.
+Host implementation (pure Python bignum): the keys, the signer, and the
+per-signature verifier that serial call sites and the degrade ladder's last
+rung use.  A batch of secp256k1 signatures goes through the BatchVerifier:
+to the device lane where an accelerator is attached (ops/secp.py, one
+signature a vector lane, default-on since ADR-015), else to the host C lane
+(native/ecverify.c through crypto/lanepool.py).
 """
 from __future__ import annotations
 

@@ -145,6 +145,12 @@ KNOWN_SPANS = frozenset({
     # ops/ed25519._comb_over_cap, ahead of the distinct-key sort)
     "comb.prewarm_failed", "comb.resolve", "ops.ed25519.verify_batch",
     "table_build",
+    # ops/secp.py, ops/sr25519.py — the other two schemes' device lanes,
+    # ONE span a launch (attrs n, nb, path from its launch record), and
+    # inside it the lane's host staging: challenges, range / encoding
+    # screens, limb and digit packing (attr n)
+    "ops.secp.verify_batch", "ops.sr25519.verify_batch",
+    "secp.stage", "sr25519.stage",
     # state/pipeline.py — the block application pipeline (ADR-017)
     "pipeline.apply", "pipeline.commit", "pipeline.drain",
     "pipeline.stage", "pipeline.wait_staged",

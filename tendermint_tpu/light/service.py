@@ -359,8 +359,13 @@ class LightServe(BaseService):
                 break
         if vals is None or vals.is_nil_or_empty():
             return
+        from tendermint_tpu.crypto import ed25519 as edkeys
         from tendermint_tpu.ops import ed25519 as edops
-        edops.prewarm_async([v.pub_key.bytes() for v in vals.validators])
+        # the comb's tables are over ed25519 keys: of a set in several
+        # schemes it is asked about those alone, told apart by type,
+        # never by length (an sr25519 key is 32 bytes too)
+        edops.prewarm_async([v.pub_key.bytes() for v in vals.validators
+                             if v.pub_key.type_name == edkeys.KEY_TYPE])
         with self._stats_lock:
             self._stats["prewarms"] += 1
 

@@ -1225,13 +1225,21 @@ def prewarm(pubkeys, warm_kernel: bool = True) -> bool:
     the set is below the device-lane floor (batches that small never
     dispatch to the device, so tables — and the XLA compile a build
     pays — are pure waste; a dev-node stopping seconds after start
-    must not leave a background compile racing interpreter teardown)."""
+    must not leave a background compile racing interpreter teardown).
+    The tables are over ed25519 keys: a caller with a set in several
+    schemes hands in its ed25519 keys alone, chosen by
+    `pub_key.type_name` (a 32-byte sr25519 key cannot be told from one
+    by its length); a list that holds a key of another length is
+    declined, not raised on."""
     if not comb_enabled() or table_cache_budget_bytes() <= 0:
         return False
     keys = list(pubkeys)
     if len(keys) < min(PREWARM_MIN_KEYS, comb_min_batch()):
         return False
     if not keys:
+        return False
+    if any(len(k) != 32 for k in keys):
+        degrade.publish_route("comb", "declined")
         return False
     pub_m = _to_u8_matrix(keys, 32)
     if pub_m.shape != (len(keys), 32):
@@ -1420,6 +1428,39 @@ def _launch_serial(obs_on: bool, phases: dict, put, launch):
     phases["compute_s"] = phases.get("compute_s", 0.0) + \
         (time.perf_counter() - t_h2d)
     return out
+
+
+def lane_bracket() -> tuple:
+    """Open a scheme lane's launch bracket (launch_lane closes it): the
+    (perf_counter, thread_time) its staging starts at.  Compile seconds
+    a dispatch that raised left on this thread are dropped here, as
+    verify_batch drops them at its entry."""
+    _take_compile_s()
+    return time.perf_counter(), time.thread_time()
+
+
+def launch_lane(path: str, n: int, nb: int, bracket: tuple, operands,
+                kernel) -> np.ndarray:
+    """One monolithic launch of a scheme lane that lives outside this
+    module (ops/secp, ops/sr25519), launched and recorded as the routes
+    here are: `operands` are the staged host arrays of `kernel`, padded
+    to nb lanes, `bracket` the lane_bracket() taken before staging.  The
+    first launch of a (kernel, shapes) pair compiles inside
+    degrade.compiling() (launch_kernel); one devobs record under `path`
+    with the phases _run_xla's carry.  Returns the kernel's (nb,) bitmap
+    on the host."""
+    obs_on = devobs.is_enabled()
+    phases = _stage_phases(*bracket) if obs_on else {}
+    out = _launch_serial(
+        obs_on, phases, lambda: [jnp.asarray(a) for a in operands],
+        lambda *arrs: launch_kernel(kernel, *arrs))
+    t_col = time.perf_counter()
+    res = np.asarray(out)  # blocks: the wall below includes execution
+    if obs_on:
+        phases["collect_s"] = time.perf_counter() - t_col
+    _record_launch(path, n, nb, time.perf_counter() - bracket[0],
+                   extra=phases)
+    return res
 
 
 def _run_comb(comb: _CombSet, msgs, sigs, plane, obs_on: bool):

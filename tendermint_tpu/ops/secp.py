@@ -275,13 +275,56 @@ def _limbs_of_be(rows: np.ndarray) -> np.ndarray:
     return out
 
 
+LANE_PATH = "secp-xla"    # the lane's name in launch records and routes
+
+
+def _stage(pubs, msgs, sigs):
+    """Host staging of n well-formed rows: the BIP-340 range screens and
+    the tagged-hash challenge a row, then limb and digit packing of the
+    batch padded to its bucket.  Returns (the four operands of
+    _verify_core, host_ok (n,), the padded lanes)."""
+    from . import ed25519 as ed
+
+    n = len(pubs)
+    nb = ed.bucket_size(n)
+    px = np.zeros((nb, 32), dtype=np.uint8)
+    rx = np.zeros((nb, 32), dtype=np.uint8)
+    s_rows = np.zeros((nb, 32), dtype=np.uint8)
+    e_rows = np.zeros((nb, 32), dtype=np.uint8)
+    host_ok = np.zeros(n, dtype=bool)
+    for i in range(n):
+        pub = bytes(pubs[i])
+        sig = bytes(sigs[i])
+        px_i = int.from_bytes(pub[1:], "big")
+        r_i = int.from_bytes(sig[:32], "big")
+        s_i = int.from_bytes(sig[32:], "big")
+        if px_i >= P or r_i >= P or s_i >= N:
+            continue  # BIP-340 range screens
+        m32 = hashlib.sha256(bytes(msgs[i])).digest()
+        e_i = int.from_bytes(
+            _tagged_hash("BIP0340/challenge", sig[:32] + pub[1:] + m32),
+            "big") % N
+        px[i] = np.frombuffer(pub[1:], np.uint8)
+        rx[i] = np.frombuffer(sig[:32], np.uint8)
+        s_rows[i] = np.frombuffer(sig[32:], np.uint8)
+        e_rows[i] = np.frombuffer(e_i.to_bytes(32, "big"), np.uint8)
+        host_ok[i] = True
+    return (_limbs_of_be(px), _limbs_of_be(rx), _nibbles_be(s_rows),
+            _nibbles_be(e_rows)), host_ok, nb
+
+
 def verify_batch_device(pubs, msgs, sigs) -> np.ndarray:
     """Batched BIP-340 verify: host staging (tagged-hash challenge,
     scalar screens) + the device ladder.  pubs: 33-byte compressed keys
     (x-only semantics: the parity byte must parse, reference
     secp256k1.go:203-212); sigs: 64-byte (r, s) big-endian.  Malformed
-    lengths are rejected host-side without poisoning the batch."""
-    from tendermint_tpu.libs import fail
+    lengths are rejected host-side without poisoning the batch.  One
+    span and one launch record (path LANE_PATH) a launch, the first
+    launch of a bucket compiled inside degrade.compiling(), as the
+    ed25519 routes do it (ops/ed25519.launch_lane)."""
+    from tendermint_tpu.libs import fail, trace
+
+    from . import ed25519 as ed
 
     # chaos seam: same role as ops/ed25519.verify_batch's — it fires at
     # entry, BEFORE any staging or kernel dispatch, so an armed "raise"
@@ -303,39 +346,27 @@ def verify_batch_device(pubs, msgs, sigs) -> np.ndarray:
                                         [msgs[i] for i in good],
                                         [sigs[i] for i in good])
         return out
+    with trace.span("ops.secp.verify_batch", n=n):
+        bracket = ed.lane_bracket()
+        with trace.span("secp.stage", n=n):
+            operands, host_ok, nb = _stage(pubs, msgs, sigs)
+        out = ed.launch_lane(LANE_PATH, n, nb, bracket, operands,
+                             _verify_core)
+    return out[:n] & host_ok
 
-    px = np.zeros((n, 32), dtype=np.uint8)
-    rx = np.zeros((n, 32), dtype=np.uint8)
-    s_rows = np.zeros((n, 32), dtype=np.uint8)
-    e_rows = np.zeros((n, 32), dtype=np.uint8)
-    host_ok = np.zeros(n, dtype=bool)
-    for i in range(n):
-        pub = bytes(pubs[i])
-        sig = bytes(sigs[i])
-        px_i = int.from_bytes(pub[1:], "big")
-        r_i = int.from_bytes(sig[:32], "big")
-        s_i = int.from_bytes(sig[32:], "big")
-        if px_i >= P or r_i >= P or s_i >= N:
-            continue  # BIP-340 range screens
-        m32 = hashlib.sha256(bytes(msgs[i])).digest()
-        e_i = int.from_bytes(
-            _tagged_hash("BIP0340/challenge", sig[:32] + pub[1:] + m32),
-            "big") % N
-        px[i] = np.frombuffer(pub[1:], np.uint8)
-        rx[i] = np.frombuffer(sig[:32], np.uint8)
-        s_rows[i] = np.frombuffer(sig[32:], np.uint8)
-        e_rows[i] = np.frombuffer(e_i.to_bytes(32, "big"), np.uint8)
-        host_ok[i] = True
 
+def warm_bucket(n: int) -> int:
+    """Launch the bucket an n-row batch pads to, on rows that stage to
+    nothing (all-zero operands, every lane refused), by a direct call:
+    its one-time trace + compile is paid here, off any request, and the
+    (LANE_PATH, nb) bucket is marked seen.  What a benchmark's warm-up
+    calls, and what a node can call when a set with secp256k1 keys
+    comes into force.  Returns nb."""
     from . import ed25519 as ed
 
     nb = ed.bucket_size(n)
-    if nb != n:
-        pad = [(0, nb - n), (0, 0)]
-        px, rx = np.pad(px, pad), np.pad(rx, pad)
-        s_rows, e_rows = np.pad(s_rows, pad), np.pad(e_rows, pad)
-    out = _verify_core(jnp.asarray(_limbs_of_be(px)),
-                       jnp.asarray(_limbs_of_be(rx)),
-                       jnp.asarray(_nibbles_be(s_rows)),
-                       jnp.asarray(_nibbles_be(e_rows)))
-    return np.asarray(out)[:n] & host_ok
+    zeros = np.zeros((nb, 32), dtype=np.uint8)
+    ed.launch_lane(LANE_PATH, 0, nb, ed.lane_bracket(),
+                   (_limbs_of_be(zeros), _limbs_of_be(zeros),
+                    _nibbles_be(zeros), _nibbles_be(zeros)), _verify_core)
+    return nb

@@ -86,12 +86,41 @@ def _stage_host(pubs, msgs, sigs):
     return k, s, ok
 
 
+LANE_PATH = "sr25519-xla"   # the lane's name in launch records and routes
+
+
+def _stage(pubs, msgs, sigs):
+    """Host staging of n well-formed rows: the merlin challenge and the
+    marker / s < L screens a row (_stage_host), the ristretto byte
+    screens, digit packing, padding to the batch's bucket.  Returns (the
+    four operands of _verify_core, host_ok (n,), the padded lanes)."""
+    n = len(pubs)
+    nb = ed.bucket_size(n)
+    pub_m = ed._to_u8_matrix([bytes(p) for p in pubs], 32)
+    sig_m = ed._to_u8_matrix([bytes(s) for s in sigs], 64)
+    k, s, host_ok = _stage_host(pubs, msgs, sigs)
+    r_bytes = np.ascontiguousarray(sig_m[:, :32])
+    # ristretto byte screens (host-vectorized): encodings must be
+    # canonical (< p) and nonnegative (even)
+    host_ok = host_ok & ristretto.bytes_canonical_nonneg(pub_m) \
+        & ristretto.bytes_canonical_nonneg(r_bytes)
+    operands = (pub_m, r_bytes, ed.scalars_to_digits(s),
+                ed.scalars_to_digits(k))
+    if nb != n:
+        pad = [(0, nb - n), (0, 0)]
+        operands = tuple(np.pad(a, pad) for a in operands)
+    return operands, host_ok, nb
+
+
 def verify_batch_device(pubs, msgs, sigs) -> np.ndarray:
     """End-to-end batched sr25519 verify: host staging + device lanes.
     Returns a (n,) bool bitmap with per-sig exact semantics.  Malformed
     lengths are rejected host-side without poisoning the batch (same
-    guard as crypto/batch.verify_ed25519_batch)."""
-    from tendermint_tpu.libs import fail
+    guard as crypto/batch.verify_ed25519_batch).  One span and one
+    launch record (path LANE_PATH) a launch, the first launch of a
+    bucket compiled inside degrade.compiling(), as the ed25519 routes
+    do it (ops/ed25519.launch_lane)."""
+    from tendermint_tpu.libs import fail, trace
 
     # chaos seam: same role as ops/ed25519.verify_batch's — the degrade
     # runtime treats an injected fault here as a device-lane failure
@@ -110,23 +139,24 @@ def verify_batch_device(pubs, msgs, sigs) -> np.ndarray:
                                         [msgs[i] for i in good],
                                         [sigs[i] for i in good])
         return out
-    pub_m = ed._to_u8_matrix([bytes(p) for p in pubs], 32)
-    sig_m = ed._to_u8_matrix([bytes(s) for s in sigs], 64)
-    k, s, host_ok = _stage_host(pubs, msgs, sigs)
-    r_bytes = np.ascontiguousarray(sig_m[:, :32])
-    # ristretto byte screens (host-vectorized): encodings must be
-    # canonical (< p) and nonnegative (even)
-    host_ok = host_ok & ristretto.bytes_canonical_nonneg(pub_m) \
-        & ristretto.bytes_canonical_nonneg(r_bytes)
-    s_digits = ed.scalars_to_digits(s)
-    k_digits = ed.scalars_to_digits(k)
+    with trace.span("ops.sr25519.verify_batch", n=n):
+        bracket = ed.lane_bracket()
+        with trace.span("sr25519.stage", n=n):
+            operands, host_ok, nb = _stage(pubs, msgs, sigs)
+        out = ed.launch_lane(LANE_PATH, n, nb, bracket, operands,
+                             _verify_core)
+    return out[:n] & host_ok
+
+
+def warm_bucket(n: int) -> int:
+    """Launch the bucket an n-row batch pads to, on all-zero operands,
+    by a direct call: its one-time trace + compile is paid here, off
+    any request, and the (LANE_PATH, nb) bucket is marked seen
+    (ops/secp.warm_bucket is the secp256k1 lane's).  Returns nb."""
     nb = ed.bucket_size(n)
-    if nb != n:
-        pad = [(0, nb - n), (0, 0)]
-        pub_m = np.pad(pub_m, pad)
-        r_bytes = np.pad(r_bytes, pad)
-        s_digits = np.pad(s_digits, pad)
-        k_digits = np.pad(k_digits, pad)
-    out = _verify_core(jnp.asarray(pub_m), jnp.asarray(r_bytes),
-                       jnp.asarray(s_digits), jnp.asarray(k_digits))
-    return np.asarray(out)[:n] & host_ok
+    ed.launch_lane(LANE_PATH, 0, nb, ed.lane_bracket(),
+                   (np.zeros((nb, 32), np.uint8),
+                    np.zeros((nb, 32), np.uint8),
+                    np.zeros((nb, 64), np.int8),
+                    np.zeros((nb, 64), np.int8)), _verify_core)
+    return nb

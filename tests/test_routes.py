@@ -45,6 +45,12 @@ ROUTE_TABLE = [
     # val10k-skipping / val10k-client: the trusting prefix
     ("3,334 cached rows, TPU",
      (3334, True, True, False, False), [("pallas", 4096, 1)]),
+    # val10k-mixed-commit and chip_smoke's mixed phase: the ed25519 third
+    # of a commit in three key schemes, a list of keys from the
+    # BatchVerifier's lane (the other two schemes' lanes are their own
+    # modules': ops/secp, ops/sr25519, one bucket_size(n) launch each)
+    ("3,300 rows of a BatchVerifier lane, not cached, TPU",
+     (3300, False, True, False, False), [("pallas", 4096, 1)]),
     # the PUB_CACHE_MIN edge
     ("4,095 cached rows, TPU",
      (4095, True, True, False, False), [("pallas", 4096, 1)]),
