@@ -516,6 +516,8 @@ def verify_sigs_bulk(pubs: Sequence[PubKey], msgs, sigs: Sequence[bytes],
                 host_fn=partial(_host_bulk_ed25519, pubs, msgs, sigs),
                 spot_check=_spot_check_bulk(pubs, msgs, sigs))
         pubs = [ed.PubKey(bytes(p)) for p in pubs]
+        if isinstance(sigs, np.ndarray):
+            sigs = [bytes(s) for s in sigs]
     if (n >= tpu_threshold and _use_device()
             and all(p.type_name == ed.KEY_TYPE for p in pubs)):
         # cache_pubs: a validator set's keys recur every block, so the
@@ -565,12 +567,16 @@ def verify_ed25519_batch(pubkeys: Sequence[bytes], msgs: Sequence[bytes],
     """Raw-bytes ed25519 batch verify on the device (malformed lengths are
     rejected host-side without poisoning the batch)."""
     n = len(pubkeys)
-    if isinstance(pubkeys, np.ndarray):   # (n, 32): shape-guaranteed
-        ok_len = np.fromiter((len(sigs[i]) == 64 for i in range(n)),
-                             dtype=bool, count=n)
-    else:
-        ok_len = np.array([
-            len(pubkeys[i]) == 32 and len(sigs[i]) == 64 for i in range(n)])
+    # an (n, 32) key matrix and an (n, 64) signature matrix
+    # (types/validator_set._collect_batch hands both) are
+    # shape-guaranteed: nothing to screen a row at a time
+    ok_len = np.ones(n, dtype=bool)
+    if not isinstance(pubkeys, np.ndarray):
+        ok_len &= np.fromiter(map(len, pubkeys), dtype=np.int64,
+                              count=n) == 32
+    if not isinstance(sigs, np.ndarray):
+        ok_len &= np.fromiter(map(len, sigs), dtype=np.int64,
+                              count=n) == 64
     if not ok_len.all():
         good = np.flatnonzero(ok_len)
         if good.size == 0:

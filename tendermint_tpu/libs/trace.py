@@ -124,9 +124,13 @@ KNOWN_SPANS = frozenset({
     # merkle hash (attr `memo`: answered by the memo on the validators
     # list, nothing computed), the commit's structural checks
     # (Commit.validate_basic itself, whoever calls it), the
-    # trusting path's match by address, the >2/3 tally, and sign-bytes +
-    # pubkey rows up to the call of verify_sigs_bulk
-    "commit.collect", "commit.match", "commit.prefix",
+    # trusting path's match by address, the >2/3 tally, and the filter,
+    # sign-bytes + pubkey and signature rows up to the call of
+    # verify_sigs_bulk; commit.columns (types/commit._columns, attrs
+    # rows, fields) is one read of a commit's rows into numpy columns,
+    # inside whichever of the others asked for it: what is left of the
+    # per-row Python
+    "commit.collect", "commit.columns", "commit.match", "commit.prefix",
     "commit.validate_basic", "valset.hash",
     # networks/ — the in-process multi-node harness (ADR-019)
     "harness.scenario", "harness.step", "vnet.deliver",

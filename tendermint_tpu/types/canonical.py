@@ -30,7 +30,7 @@ def canonical_vote_bytes(chain_id: str, vtype: SignedMsgType, height: int,
     return pe.length_delimited(body)
 
 
-def commit_sign_bytes_batch(chain_id: str, commit, indices):
+def commit_sign_bytes_batch(chain_id: str, commit, indices, cols=None):
     """Sign bytes of the precommits at `indices` of one commit, assembled
     as a batch (RaggedBytes).
 
@@ -40,6 +40,12 @@ def commit_sign_bytes_batch(chain_id: str, commit, indices):
     and only the timestamp is encoded per entry (native/staging.c
     tm_vote_sign_bytes; numpy-free Python fallback below).  Byte-identical
     to canonical_vote_bytes per index (tests/test_types.py).
+
+    `cols` holds the rows' `seconds`, `nanos` and `flag` columns
+    (types/commit._columns) where the caller has read them, over the
+    commit's rows from the first up to the last index at least; without
+    them they are read here, once, and the per-entry inputs are numpy
+    takes at `indices` either way.
     """
     import numpy as np
 
@@ -55,15 +61,14 @@ def commit_sign_bytes_batch(chain_id: str, commit, indices):
     prefix1 = head  # nil vote: zero BlockID encodes to an absent field 4
     suffix = pe.string_field(6, chain_id)
 
-    sigs = commit.signatures
+    if cols is None:
+        from .commit import _columns
+        cols = _columns(commit.signatures, ("seconds", "nanos", "flag"))
+    indices = np.asarray(indices, dtype=np.int64)
     n = len(indices)
-    seconds = np.fromiter((sigs[i].timestamp.seconds for i in indices),
-                          dtype=np.int64, count=n)
-    nanos = np.fromiter((sigs[i].timestamp.nanos for i in indices),
-                        dtype=np.int64, count=n)
-    variant = np.fromiter(
-        (0 if sigs[i].block_id_flag == BlockIDFlag.COMMIT else 1
-         for i in indices), dtype=np.uint8, count=n)
+    seconds = cols["seconds"][indices]
+    nanos = cols["nanos"][indices]
+    variant = (cols["flag"][indices] != BlockIDFlag.COMMIT).astype(np.uint8)
     out = native.vote_sign_bytes(seconds, nanos, variant,
                                  prefix0, prefix1, suffix)
     if out is not None:

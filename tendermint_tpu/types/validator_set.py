@@ -25,6 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from fractions import Fraction
+from operator import attrgetter
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -35,8 +36,8 @@ from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.safemath import (
     INT64_MAX, INT64_MIN, safe_add_clip, safe_mul, safe_sub_clip, trunc_div)
 
-from .basic import BlockID
-from .commit import Commit
+from .basic import BlockID, BlockIDFlag
+from .commit import Commit, _columns
 from .validator import Validator
 
 MAX_TOTAL_VOTING_POWER = INT64_MAX // 8
@@ -66,6 +67,9 @@ class ValidatorSet:
     _addr_index = None
     # (the validators list it was computed from, its merkle root): see hash
     _hash_memo = None
+    # (the validators list it was read from, its voting powers as an
+    # int64 column): see _power_column
+    _power_memo = None
 
     def __init__(self, validators: Optional[List[Validator]] = None):
         """NewValidatorSet semantics (reference :71-86): copies, validates,
@@ -89,6 +93,7 @@ class ValidatorSet:
         d.pop("_pubmat_cache", None)
         d.pop("_addr_index", None)
         d.pop("_hash_memo", None)
+        d.pop("_power_memo", None)
         return d
 
     def size(self) -> int:
@@ -313,6 +318,7 @@ class ValidatorSet:
         # and a root computed on it another leaf order
         self._addr_index = None
         self._hash_memo = None
+        self._power_memo = None
 
     def _verify_removals(self, deletes: List[Validator]) -> int:
         removed = 0
@@ -405,23 +411,23 @@ class ValidatorSet:
         batch; tallies for-block power; raises on any bad signature or
         insufficient power."""
         self._check_commit_header(chain_id, block_id, height, commit)
-        batch_idx = [idx for idx, cs in enumerate(commit.signatures)
-                     if not cs.is_absent()]
-        self._verify_sigs_batch(chain_id, commit, batch_idx,
-                                [self.validators[i] for i in batch_idx])
-        tallied = sum(self.validators[i].voting_power
-                      for i in batch_idx if commit.signatures[i].for_block())
-        needed = self.total_voting_power() * 2 // 3
-        if tallied <= needed:
-            raise NotEnoughVotingPowerError(tallied, needed)
+        # the filter is part of what the call costs before its launch:
+        # it lies inside the span
+        with trace.span("commit.collect") as sp:
+            flag = _columns(commit.signatures, ("flag",))["flag"]
+            idxs = np.flatnonzero(flag != BlockIDFlag.ABSENT)
+            batch = self._collect_batch(chain_id, commit, idxs, None, flag,
+                                        sp)
+        self._verify_collected(commit, idxs, *batch)
+        self._check_for_block_power(flag)
 
     def verify_commit_light(self, chain_id: str, block_id: BlockID,
                             height: int, commit: Commit):
         """Reference :717-760 — verify only the minimal prefix of for-block
         signatures that crosses 2/3, in one batch."""
-        prefix = self.collect_commit_light(chain_id, block_id, height, commit)
-        self._verify_prefix_batch(chain_id, commit, prefix,
-                                  [self.validators[i] for i in prefix])
+        prefix, flag = self._light_prefix(chain_id, block_id, height,
+                                          commit)
+        self._verify_sigs_batch(chain_id, commit, prefix, flag=flag)
 
     def collect_commit_light(self, chain_id: str, block_id: BlockID,
                              height: int, commit: Commit) -> List[int]:
@@ -432,22 +438,27 @@ class ValidatorSet:
         consecutive blocks and verifies them in ONE batched kernel launch
         (vs the reference's per-block serial loop, blocksync/reactor.go:375).
         """
+        return self._light_prefix(chain_id, block_id, height,
+                                  commit)[0].tolist()
+
+    def _light_prefix(self, chain_id: str, block_id: BlockID, height: int,
+                      commit: Commit) -> Tuple[np.ndarray, np.ndarray]:
+        """(the indices of the minimal prefix of for-block rows whose power
+        crosses 2/3, the commit's flag column they were read from)."""
         self._check_commit_header(chain_id, block_id, height, commit)
         needed = self.total_voting_power() * 2 // 3
-        prefix = []
-        tallied = 0
         with trace.span("commit.prefix") as sp:
-            for idx, cs in enumerate(commit.signatures):
-                if not cs.for_block():
-                    continue
-                prefix.append(idx)
-                tallied += self.validators[idx].voting_power
-                if tallied > needed:
-                    break
-            else:
-                raise NotEnoughVotingPowerError(tallied, needed)
+            flag = _columns(commit.signatures, ("flag",))["flag"]
+            for_block = np.flatnonzero(flag == BlockIDFlag.COMMIT)
+            tallied = np.cumsum(self._power_column()[for_block])
+            over = tallied > needed
+            if not over.any():
+                raise NotEnoughVotingPowerError(
+                    int(tallied[-1]) if tallied.size else 0, needed)
+            # the serial loop's early stop: the first row that crosses
+            prefix = for_block[:int(np.argmax(over)) + 1]
             sp.add(prefix=len(prefix))
-        return prefix
+        return prefix, flag
 
     def verify_commit_light_trusting(self, chain_id: str, commit: Commit,
                                      trust_level: Fraction):
@@ -504,7 +515,8 @@ class ValidatorSet:
                 raise NotEnoughVotingPowerError(tallied, needed)
             sp.add(scanned=idx + 1, matched=len(prefix), lookups=lookups,
                    index_built=index_built)
-        self._verify_prefix_batch(chain_id, commit, prefix, vals)
+        self._verify_sigs_batch(chain_id, commit,
+                                np.asarray(prefix, dtype=np.int64), vals)
 
     def check_commit_no_sigs(self, chain_id: str, block_id: BlockID,
                              height: int, commit: Commit):
@@ -513,10 +525,14 @@ class ValidatorSet:
         `commit` was already verified in a coalesced batch (blocksync's
         pre-verified cache, state/execution.py)."""
         self._check_commit_header(chain_id, block_id, height, commit)
-        tallied = sum(self.validators[i].voting_power
-                      for i, cs in enumerate(commit.signatures)
-                      if cs.for_block())
+        self._check_for_block_power(
+            _columns(commit.signatures, ("flag",))["flag"])
+
+    def _check_for_block_power(self, flag: np.ndarray):
+        """The for-block rows' power against 2/3 of the set's, from the
+        commit's flag column (`needed` first: _power_column)."""
         needed = self.total_voting_power() * 2 // 3
+        tallied = int(self._power_column()[flag == BlockIDFlag.COMMIT].sum())
         if tallied <= needed:
             raise NotEnoughVotingPowerError(tallied, needed)
 
@@ -533,10 +549,6 @@ class ValidatorSet:
             raise CommitVerifyError(
                 f"invalid commit -- wrong block ID: want {block_id}, "
                 f"got {commit.block_id}")
-
-    def _verify_prefix_batch(self, chain_id: str, commit: Commit,
-                             prefix: List[int], vals: List[Validator]):
-        self._verify_sigs_batch(chain_id, commit, prefix, vals)
 
     def _pub_matrix(self):
         """Cached (n, 32) uint8 pubkey-byte matrix + all-ed25519 flag for
@@ -563,50 +575,124 @@ class ValidatorSet:
         self._pubmat_cache = (self.validators, mat, all_ed)
         return mat, all_ed
 
+    def _power_column(self) -> np.ndarray:
+        """The validators' voting powers as an int64 column, row-aligned
+        with self.validators; read once per validators list and memoised
+        on the list object by a retained reference, as _pub_matrix keys
+        its cache (state a node holds, unlike a commit's rows).  The
+        total is capped at MAX_TOTAL_VOTING_POWER, so int64 sums over it
+        are exact; callers compute `needed` first, which raises where a
+        decoded set exceeds the cap."""
+        memo = self._power_memo
+        if memo is not None and memo[0] is self.validators:
+            return memo[1]
+        powers = np.fromiter(map(attrgetter("voting_power"), self.validators),
+                             dtype=np.int64, count=len(self.validators))
+        self._power_memo = (self.validators, powers)
+        return powers
+
     def _verify_sigs_batch(self, chain_id: str, commit: Commit,
-                           idxs: List[int], vals: List[Validator]):
-        """Exact check-all verification of the signatures at `idxs`
-        (belonging to `vals`, same order) in one batch: sign bytes come
-        from the shared-prefix batch assembler (types/canonical.py
-        commit_sign_bytes_batch) and verification from the bulk routing
-        path (crypto/batch.verify_sigs_bulk) — no per-signature Python
-        objects on the 100k-validator path."""
+                           idxs: np.ndarray,
+                           vals: Optional[List[Validator]] = None,
+                           flag: Optional[np.ndarray] = None):
+        """Exact check-all verification of the signatures at the commit
+        rows `idxs` (an ascending int64 array) in one batch; the error
+        names the lowest failing row.
+
+        `vals` None SAYS that the rows are the set's own: row i was signed
+        by self.validators[i] (verify_commit, verify_commit_light, which
+        index the set themselves).  The trusting path matches BY ADDRESS,
+        possibly across sets, and hands the validators it matched, in the
+        order of `idxs`.  `flag` is the commit's flag column where the
+        caller has read it already."""
+        with trace.span("commit.collect") as sp:
+            batch = self._collect_batch(chain_id, commit, idxs, vals, flag,
+                                        sp)
+        self._verify_collected(commit, idxs, *batch)
+
+    def _collect_batch(self, chain_id: str, commit: Commit, idxs: np.ndarray,
+                       vals: Optional[List[Validator]],
+                       flag: Optional[np.ndarray], sp):
+        """What verify_sigs_bulk needs for the rows `idxs`, inside the
+        caller's commit.collect span: (pubs, msgs, sigs, launched).
+
+        The rows are read ONCE into columns (types/commit._columns) and
+        everything after is numpy: sign bytes from the shared-prefix
+        batch assembler over the timestamp and flag columns, pubkey rows
+        gathered from the set's cached matrix, signatures as rows of one
+        (n, 64) matrix: no per-signature Python object, loop step or
+        method call on the 100k-validator path.  A row whose signature is
+        not 64 bytes long is invalid, never an exception: it is left out
+        of the launch (`launched` is the positions in `idxs` that go
+        down, None for all) and its bit stays false.
+
+        Where there is no pubkey matrix (a mixed set, no device, under 32
+        rows) the batch is key objects and the rows' own bytes, and the
+        bulk path screens lengths itself."""
         from .canonical import commit_sign_bytes_batch
 
         from tendermint_tpu.crypto.batch import _use_device
 
-        with trace.span("commit.collect", n=len(idxs)) as sp:
-            msgs = commit_sign_bytes_batch(chain_id, commit, idxs)
-            # the raw-pubkey matrix only helps the device route; the host
-            # fallback verifies through the validators' existing PubKey
-            # objects (rebuilding 100k of them would regress that path)
-            cached = getattr(self, "_pubmat_cache", None)
-            mat, all_ed = (self._pub_matrix()
-                           if len(idxs) >= 32 and _use_device()
-                           else (None, False))
-            # the matrix rows are index-aligned with self.validators;
-            # that matches idxs always on the check-all/light paths.
-            # The trusting path matches validators BY ADDRESS, possibly
-            # across different sets: vals[j] is validators[idxs[j]]
-            # when the commit was signed by this set in this order, and
-            # need not be otherwise — verify alignment by identity
-            # (pointer compares, ~10 ms at 100k) before using rows
-            nvals = len(self.validators)
-            aligned = mat is not None and all(
-                idxs[j] < nvals and self.validators[idxs[j]] is vals[j]
-                for j in range(len(vals)))
-            if aligned:
-                pubs = mat if len(idxs) == mat.shape[0] else \
-                    mat[np.asarray(idxs, dtype=np.int64)]
-            else:
-                pubs = [v.pub_key for v in vals]
-            sigs = [commit.signatures[i].signature for i in idxs]
-            sp.add(aligned=aligned,
-                   pubmat_cached=cached is not None
-                   and cached[0] is self.validators)
+        n = len(idxs)
+        sp.add(n=n)
+        if n == 0:
+            return [], [], [], None
+        # the raw-pubkey matrix only helps the device route; the host
+        # fallback verifies through the validators' existing PubKey
+        # objects (rebuilding 100k of them would regress that path)
+        cached = getattr(self, "_pubmat_cache", None)
+        mat, _ = (self._pub_matrix() if n >= 32 and _use_device()
+                  else (None, False))
+        nvals = len(self.validators)
+        # the matrix rows are index-aligned with self.validators: the
+        # set's own rows need no check.  Matched validators are vals[j]
+        # is validators[idxs[j]] when the commit was signed by this set
+        # in this order, and need not be otherwise: verify alignment by
+        # identity (pointer compares) before using rows
+        aligned = mat is not None and (vals is None or all(
+            i < nvals and self.validators[i] is v
+            for i, v in zip(idxs.tolist(), vals)))
+        sp.add(aligned=aligned,
+               pubmat_cached=cached is not None
+               and cached[0] is self.validators)
+        # rows past the last one asked for are never read
+        rows = commit.signatures[:int(idxs[-1]) + 1]
+        fields = ("seconds", "nanos") + (("sig",) if aligned else ()) \
+            + (("flag",) if flag is None else ())
+        cols = _columns(rows, fields)
+        if flag is not None:
+            cols["flag"] = flag
+        launched = None
+        if aligned:
+            whole = cols["sig_len"] == 64
+            if not whole[idxs].all():
+                launched = np.flatnonzero(whole[idxs])
+                idxs = idxs[launched]
+            sigs = cols["sig"]
+            if len(idxs) != sigs.shape[0]:
+                # row i's signature is the matrix row that counts the
+                # whole rows up to it
+                sigs = sigs[(np.cumsum(whole) - 1)[idxs]]
+            pubs = mat if len(idxs) == nvals else mat[idxs]
+        else:
+            at = idxs.tolist()
+            sigs = list(map(attrgetter("signature"),
+                            map(rows.__getitem__, at)))
+            if vals is None:
+                vals = map(self.validators.__getitem__, at)
+            pubs = list(map(attrgetter("pub_key"), vals))
+        msgs = commit_sign_bytes_batch(chain_id, commit, idxs, cols)
+        return pubs, msgs, sigs, launched
+
+    def _verify_collected(self, commit: Commit, idxs: np.ndarray, pubs,
+                          msgs, sigs, launched: Optional[np.ndarray]):
         bits = verify_sigs_bulk(pubs, msgs, sigs)
+        if launched is not None:
+            every = np.zeros(len(idxs), dtype=bool)
+            every[launched] = bits
+            bits = every
         if not bits.all():
-            bad = idxs[int(np.argmin(bits))]
+            bad = int(idxs[int(np.argmin(bits))])
             raise CommitVerifyError(
                 f"wrong signature (#{bad}): "
                 f"{commit.signatures[bad].signature.hex()}")

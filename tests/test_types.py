@@ -446,8 +446,11 @@ def trusting(monkeypatch):
         rows = isinstance(pubs, np.ndarray)
         keys = [edkeys.PubKey(bytes(p)) for p in pubs] if rows \
             else list(pubs)
+        # beside matrix rows the signatures are an (n, 64) matrix too
+        assert isinstance(sigs, np.ndarray) is rows
+        sigs = [bytes(s) for s in sigs]
         calls.append({"rows": rows, "keys": [k.bytes() for k in keys],
-                      "sigs": list(sigs)})
+                      "sigs": sigs})
         return np.array([k.verify_signature(msgs[j], sigs[j])
                          for j, k in enumerate(keys)], dtype=bool)
 
@@ -583,3 +586,18 @@ def test_commit_sign_bytes_batch_byte_exact():
     # subsets and duplicates resolve by index
     sub = commit_sign_bytes_batch(CHAIN, commit, [7, 0, 7])
     assert [sub[0], sub[1], sub[2]] == [want[7], want[0], want[7]]
+
+    # fed from the rows' columns, as the entry points feed it: an index
+    # array over columns the caller has read, nil-vote rows among them,
+    # the columns no longer than the last row asked for
+    import numpy as np
+    from tendermint_tpu.types.commit import _columns
+    cols = _columns(commit.signatures, ("seconds", "nanos", "flag"))
+    for pick in ([1, 2, 5, 6], [5], [0, 1, 5, 8], idxs):
+        got = commit_sign_bytes_batch(
+            CHAIN, commit, np.asarray(pick, dtype=np.int64), cols)
+        assert [got[j] for j in range(len(pick))] == [want[i] for i in pick]
+    short = _columns(commit.signatures[:6], ("seconds", "nanos", "flag"))
+    got = commit_sign_bytes_batch(CHAIN, commit, np.array([1, 4, 5]), short)
+    assert [got[0], got[1], got[2]] == [want[1], want[4], want[5]]
+    assert len(want[5]) < len(want[4])      # a nil vote names no block
