@@ -369,20 +369,37 @@ def cmd_debug_trace(args):
     """Snapshot the running node's flight recorder (libs/trace.py) via
     its pprof listener's GET /debug/trace and print (or write) the
     Chrome-trace JSON — load the output into chrome://tracing or
-    ui.perfetto.dev to see the vote -> verify -> commit timeline."""
+    ui.perfetto.dev to see the vote -> verify -> commit timeline.
+    --rollup prints the same records reduced (per span name count,
+    total and self time, the unnamed remainder per thread), --incidents
+    the stalled requests the recorder kept."""
     import urllib.request
 
     addr = _pprof_addr(args, "the recorder is on unless the node runs "
                              "with TM_TPU_TRACE=0")
     url = f"http://{addr}/debug/trace?since={args.since}"
+    if args.rollup:
+        url += "&rollup=1"
+    elif args.incidents:
+        url += "&incidents=1"
     with urllib.request.urlopen(url, timeout=10) as r:
         body = r.read().decode()
+    if args.rollup or args.incidents:
+        # the two reductions are for reading, not for a trace viewer
+        body = json.dumps(json.loads(body), indent=1)
     if args.output_file:
         out = os.path.abspath(args.output_file)
         with open(out, "w") as f:
             f.write(body)
-        n = len(json.loads(body).get("traceEvents", []))
-        print(f"wrote {n} trace events to {out}")
+        doc = json.loads(body)
+        if args.rollup:
+            print(f"wrote the roll-up of {len(doc['spans'])} span names "
+                  f"to {out}")
+        elif args.incidents:
+            print(f"wrote {len(doc['incidents'])} incidents to {out}")
+        else:
+            print(f"wrote {len(doc.get('traceEvents', []))} trace events "
+                  f"to {out}")
     else:
         print(body)
 
@@ -841,11 +858,19 @@ def main(argv=None):
     sp.set_defaults(fn=cmd_debug_dump)
     sp = sub.add_parser("debug-trace",
                         help="snapshot the node's flight recorder as "
-                             "Chrome-trace JSON")
+                             "Chrome-trace JSON, or reduced (--rollup, "
+                             "--incidents)")
     sp.add_argument("--pprof-laddr", dest="pprof_laddr", default="",
                     help="pprof listener (default: [rpc] pprof_laddr)")
     sp.add_argument("--since", type=int, default=0,
                     help="fetch only events after this seq cursor")
+    sp.add_argument("--rollup", action="store_true",
+                    help="per span name count, total and self time, and "
+                         "the unnamed remainder per thread, instead of "
+                         "the events")
+    sp.add_argument("--incidents", action="store_true",
+                    help="the stalled requests the recorder kept (a span "
+                         "at 8x its usual), with every thread's records")
     sp.add_argument("--output-file", dest="output_file", default="")
     sp.set_defaults(fn=cmd_debug_trace)
     sp = sub.add_parser("debug-latency",

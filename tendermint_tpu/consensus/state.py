@@ -38,7 +38,7 @@ from tendermint_tpu.types.part_set import Part, PartSet, make_block_parts
 from tendermint_tpu.types.proposal import Proposal
 from tendermint_tpu.types.vote import Vote
 from tendermint_tpu.types.vote_set import (
-    ConflictingVoteError, VoteSet, VoteSetError)
+    TALLY, ConflictingVoteError, VoteSet, VoteSetError)
 
 from tendermint_tpu.p2p import netobs
 
@@ -279,6 +279,10 @@ class ConsensusState(BaseService):
         votes = [m.vote for m, _ in batch if isinstance(m, VoteMessage)]
         if len(votes) < self.BATCH_MIN_VOTES:
             return
+        # what the serial apply has done so far (types/vote_set.TALLY),
+        # once a drained batch: two samples differenced are the add_vote
+        # calls between them, which have no span of their own (ADR-011)
+        trace.counter("votes", **TALLY.sample())
         with trace.span("consensus.preverify", queued=len(batch),
                         votes=len(votes)):
             self._preverify_votes_locked(votes)
@@ -298,8 +302,31 @@ class ConsensusState(BaseService):
             vals_last = state.last_validators
             height = self.rs.height
             cur_votes = self.rs.votes
+        with trace.span("consensus.screen", votes=len(votes)) as sp:
+            items = self._screen_votes(votes, state.chain_id, height,
+                                       vals_now, vals_last, cur_votes)
+            sp.add(items=len(items))
+        if items:
+            try:
+                # highest-priority class on the shared verify scheduler
+                # (coalesces with concurrent light/blocksync batches in
+                # one device launch); identical direct BatchVerifier
+                # path when no scheduler is running.  Either way the
+                # valid triples land in crypto.batch.verified_sigs and
+                # the serial apply below hits the cache.
+                from tendermint_tpu.crypto import scheduler as vsched
+                vsched.verify_items(
+                    items, vsched.Priority.CONSENSUS,
+                    deadline=time.monotonic() + self.PREVERIFY_DEADLINE_S)
+            except Exception:
+                pass
+
+    @staticmethod
+    def _screen_votes(votes, chain_id, height, vals_now, vals_last,
+                      cur_votes):
+        """The (pub_key, sign bytes, signature) of every vote of one
+        drained batch that the serial apply will verify."""
         items = []
-        chain_id = state.chain_id
         seen = set()
         for v in votes:
             # every field here is peer-controlled and type-unchecked; a
@@ -339,20 +366,7 @@ class ConsensusState(BaseService):
                               v.signature))
             except Exception:
                 continue
-        if items:
-            try:
-                # highest-priority class on the shared verify scheduler
-                # (coalesces with concurrent light/blocksync batches in
-                # one device launch); identical direct BatchVerifier
-                # path when no scheduler is running.  Either way the
-                # valid triples land in crypto.batch.verified_sigs and
-                # the serial apply below hits the cache.
-                from tendermint_tpu.crypto import scheduler as vsched
-                vsched.verify_items(
-                    items, vsched.Priority.CONSENSUS,
-                    deadline=time.monotonic() + self.PREVERIFY_DEADLINE_S)
-            except Exception:
-                pass
+        return items
 
     def _handle_msg(self, msg, peer_id: str):
         if self.wal is not None:
@@ -992,13 +1006,9 @@ class ConsensusState(BaseService):
     # -- votes (reference :2003-2293) --------------------------------------
 
     def _try_add_vote(self, vote: Vote, peer_id: str):
-        # vote receipt: the causal start of the vote -> verify -> commit
-        # timeline (the serial apply after the coalesced preverify; a
-        # SigCache hit here means the batched launch already paid the
-        # signature check)
-        trace.instant("consensus.vote", height=vote.height,
-                      round=vote.round, index=vote.validator_index,
-                      peer=bool(peer_id))
+        # the serial apply after the coalesced preverify; a SigCache hit
+        # here means the batched launch already paid the signature check
+        # (counted, not recorded: types/vote_set.TALLY)
         try:
             self._add_vote(vote, peer_id)
         except ConflictingVoteError as e:

@@ -461,10 +461,16 @@ class DeviceLaneRuntime:
         # span id HERE so the worker's span links into the caller's tree
         # (the thread-local stack doesn't cross the pool boundary)
         parent = trace.current_id()
+        submitted = time.monotonic()
 
         def _launch():
             _lane_tls.clock = self._compile_clock
-            with trace.span("device.launch", parent=parent, site=site):
+            # queued_ns: submit to the lane worker taking the launch up
+            # (the started_at it stamps a moment before this runs): the
+            # wait behind another lane's launch, on no other record
+            with trace.span("device.launch", parent=parent, site=site,
+                            queued_ns=int(
+                                (time.monotonic() - submitted) * 1e9)):
                 fail.inject(site)
                 return fn(*args)
         try:

@@ -98,8 +98,9 @@ class VerifyFuture:
     never clobber the stop error the waiter already observed (or vice
     versa)."""
 
-    def __init__(self, n: int):
+    def __init__(self, n: int, priority: str = ""):
         self._n = n
+        self._priority = priority
         self._ev = threading.Event()
         self._bits: Optional[np.ndarray] = None
         self._exc: Optional[BaseException] = None
@@ -118,7 +119,15 @@ class VerifyFuture:
         return self._ev.is_set()
 
     def result(self, timeout: Optional[float] = None) -> np.ndarray:
-        if not self._ev.wait(timeout):
+        if not self._ev.is_set():
+            # a NAMED wait on the submitter's thread (as device.collect
+            # and pipeline.wait_staged are on theirs): what the window,
+            # the hand-offs and the launch cost it is on the scheduler's
+            # threads' spans, not in the self time of the span around it
+            with trace.span("sched.wait", n=self._n,
+                            priority=self._priority):
+                self._ev.wait(timeout)
+        if not self._ev.is_set():
             raise TimeoutError(
                 f"verify future ({self._n} items) not resolved "
                 f"within {timeout}s")
@@ -140,7 +149,7 @@ class _Submission:
         self.deadline = deadline    # monotonic or None
         self.populate_cache = populate_cache
         self.n = len(items)
-        self.future = VerifyFuture(self.n)
+        self.future = VerifyFuture(self.n, prio.name.lower())
         self.bits = np.zeros(self.n, dtype=bool)
         self.remaining = self.n
         self.enq_t = 0.0
@@ -683,11 +692,18 @@ class VerifyScheduler(BaseService):
         t_exec0 = time.monotonic()
         t_submit0 = min(s.submit_t for s in launch.subs)
         fell_back: List[str] = []  # schemes whose device lane degraded
+        # queue_wait_ns: the window's oldest submission, submit to window
+        # close; exec_wait_ns: staged to the executor taking it up.  On
+        # EVERY window's span (last_latency_report keeps the last only)
         with trace.span("sched.launch", parent=launch.parent_span, n=n,
                         schemes=",".join(f"{t}:{len(ix)}"
                                          for t, ix in by_scheme.items()),
                         dedup=launch.dedup,
-                        cache_hits=launch.cache_hits) as sp:
+                        cache_hits=launch.cache_hits,
+                        queue_wait_ns=int(max(
+                            launch.wclose_t - t_submit0, 0.0) * 1e9),
+                        exec_wait_ns=int(max(
+                            t_exec0 - launch.staged_t, 0.0) * 1e9)) as sp:
             rt = degrade.runtime() \
                 if n >= self.tpu_threshold else None
             # latch the flag once: trace.enable() mid-launch must not

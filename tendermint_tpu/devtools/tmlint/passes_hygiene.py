@@ -9,7 +9,7 @@ CHANGES.md / ADRs:
   TM303  no backslash inside an f-string replacement field (py3.10)
   TM304  no silent `except Exception: pass` in ops/ and crypto/
   TM305  fail.inject sites registered in libs/fail.REGISTERED_SITES
-  TM306  trace span/instant names registered in libs/trace.KNOWN_SPANS
+  TM306  trace span/instant/counter names registered in libs/trace.KNOWN_SPANS
   TM307  metrics-bundle attribute reads name registered metrics
   TM308  every KnobSpec declares a literal finite safe_range and a
          signal naming a registered metric (ADR-023 control plane)
@@ -375,7 +375,7 @@ def _check_trace_spans(f: SourceFile, known: Set[str],
         if not isinstance(node, ast.Call):
             continue
         name = _call_name(node.func)
-        if name not in ("span", "timed", "instant"):
+        if name not in ("span", "timed", "instant", "counter"):
             continue
         recv = getattr(node.func, "value", None)
         if not (isinstance(recv, ast.Name) and recv.id == "trace"):

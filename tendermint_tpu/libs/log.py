@@ -100,6 +100,9 @@ class Logger:
     def info(self, msg: str, **kv):
         self._emit(logging.INFO, msg, kv)
 
+    def warn(self, msg: str, **kv):
+        self._emit(logging.WARNING, msg, kv)
+
     def error(self, msg: str, **kv):
         self._emit(logging.ERROR, msg, kv)
 

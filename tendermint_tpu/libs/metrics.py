@@ -786,6 +786,11 @@ class TraceMetrics:
             "Finished spans overwritten by flight-recorder ring "
             "wraparound since process start (the ring keeps the newest "
             "window; this counts what it forgot).")
+        self.incidents = reg.counter(
+            "trace", "incidents_total",
+            "Request-level spans that took 8 times their usual or more "
+            "and were kept, with what every thread recorded meanwhile, "
+            "out of the ring's reach (GET /debug/trace?incidents=1).")
 
 
 class MempoolMetrics:

@@ -17,6 +17,13 @@ image) and with near-zero overhead when idle:
                                as Chrome-trace / Perfetto JSON; `since`
                                fetches incrementally from a previous
                                response's last_seq cursor
+  GET /debug/trace?rollup=1    the same records reduced: per span name
+                               count, total and self time, the unnamed
+                               remainder per thread, the newest sample
+                               of each counter
+  GET /debug/trace?incidents=1 the requests that stalled (a span at 8x
+                               its usual), each with what every thread
+                               recorded meanwhile: kept, not overwritten
   GET /debug/latency           latency observatory (libs/slo.py +
                                crypto/scheduler.last_latency_report):
                                windowed SLO quantiles/burn rates and
@@ -89,6 +96,12 @@ DEBUG_ENDPOINTS = (
     ("/debug/gc", "gc generation counts + uncollectable total"),
     ("/debug/trace?since=N",
      "flight recorder snapshot as Chrome-trace/Perfetto JSON (ADR-011)"),
+    ("/debug/trace?rollup=1",
+     "the recorder's spans by name: count, total, self time; the unnamed "
+     "remainder per thread; the newest counter samples"),
+    ("/debug/trace?incidents=1",
+     "the last 8 stalled requests (a span at 8x its usual) with every "
+     "thread's records while they ran: kept, not overwritten"),
     ("/debug/latency",
      "latency observatory: windowed SLO quantiles + verify lifecycle "
      "decomposition (ADR-016)"),
@@ -218,10 +231,15 @@ class _Handler(BaseHTTPRequestHandler):
                 from tendermint_tpu.libs import trace
                 q = parse_qs(url.query)
                 since = int(q.get("since", ["0"])[0])
+                if q.get("rollup", ["0"])[0] not in ("", "0"):
+                    body = trace.rollup_snapshot(since)
+                elif q.get("incidents", ["0"])[0] not in ("", "0"):
+                    body = {"incidents": trace.incidents()}
+                else:
+                    body = trace.chrome_trace(since)
                 # default=str: span attrs are arbitrary values; an odd
                 # one must never make the debug surface 500
-                self._send(200, json.dumps(trace.chrome_trace(since),
-                                           default=str),
+                self._send(200, json.dumps(body, default=str),
                            ctype="application/json")
             elif url.path == "/debug/consensus":
                 # the consensus observatory (ADR-020): the last N

@@ -53,16 +53,31 @@ session it lies in the profile, on the profile's clock, beside the
 device's operations.  This module never imports jax: it takes
 ``jax.profiler`` from ``sys.modules`` once something else has loaded it.
 
+Three things beside spans.  A loop that may not have a span a turn
+(``VoteSet.add_vote``) keeps a tally and a batch boundary samples it with
+``counter()`` (Chrome-trace ph "C"): two samples differenced read the loop
+from inside.  What no span covers is a number, not a guess:
+``self_times`` / ``unnamed_ns`` over a snapshot (the roll-up of
+``/debug/trace?rollup=1``), with ``ENVELOPES`` naming the spans whose
+self time counts as unnamed.  And the ring forgets, so a request-level
+span (``KEPT_SPANS``) that takes 8 times its usual is copied, with what
+every thread recorded while it ran, into a FIFO of the last 8 incidents
+that the wrap does not touch (``incidents()``); a collection of the
+cyclic collector of 1 ms or more is a ``gc.pause`` span, so that a pause
+has a name when it is one.
+
 Switches: ``TM_TPU_TRACE=0`` in the environment or ``trace.disable()``
 turn it off, ``trace.enable()`` on again (capacity override:
 ``TM_TPU_TRACE_CAPACITY``).  Read it back three ways:
-``GET /debug/trace?since=<seq>`` on the pprof listener (libs/pprof.py),
+``GET /debug/trace?since=<seq>`` on the pprof listener (libs/pprof.py;
+``?rollup=1`` and ``?incidents=1`` for the two reductions),
 the ``debug-trace`` CLI (cmd/__main__.py), or the per-config artifact
 bench.py writes next to its JSON line.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
@@ -76,17 +91,18 @@ _UNSET = object()  # sentinel: "inherit parent from the thread's stack"
 
 # ---------------------------------------------------------------------------
 # the span-name registry (tmlint TM306).  Every literal name passed to
-# trace.span()/trace.timed()/trace.instant() must appear here: trace
-# consumers (the benchmark's per-layer readers under perfbench/layers,
-# the debug-trace CLI, the acceptance tests walking span trees) key on
-# these strings, so an
-# unregistered name is either a typo or an undocumented contract.
+# trace.span()/trace.timed()/trace.instant()/trace.counter() must appear
+# here: trace consumers (the benchmark's per-layer readers under
+# perfbench/layers, the debug-trace CLI, the acceptance tests walking
+# span trees) key on these strings, so an unregistered name is either a
+# typo or an undocumented contract.
 # Grouped by subsystem; keep alphabetical within a group.
 # ---------------------------------------------------------------------------
 
 KNOWN_SPANS = frozenset({
-    # crypto/batch.py — the BatchVerifier coalesce window
-    "batch.host_lane", "batch.verdict", "batch.verify",
+    # crypto/batch.py — the BatchVerifier coalesce window; batch.items
+    # is verify_sigs_bulk's list-path loop of bv.add (attr n), ahead of it
+    "batch.host_lane", "batch.items", "batch.verdict", "batch.verify",
     # blocksync/replay.py — the root of one replayed window (path =
     # pipelined / coalesced / strict) and the block store's share of
     # each apply
@@ -139,9 +155,16 @@ KNOWN_SPANS = frozenset({
     # mempool/ingress.py — overload-safe admission (ADR-018)
     "ingress.admit", "ingress.batch", "ingress.checktx",
     "ingress.recheck",
-    # consensus/state.py
+    # consensus/state.py — consensus.screen is the screening loop of one
+    # drained batch inside consensus.preverify (attr items), and `votes`
+    # the counter record sampled at the same boundary: the process-wide
+    # tally types/vote_set.add_vote keeps (calls, wall_ns, cache_hits,
+    # host_verifies, refused), read by differencing two samples
     "consensus.finalize_commit", "consensus.preverify",
-    "consensus.quorum", "consensus.step", "consensus.vote",
+    "consensus.quorum", "consensus.screen", "consensus.step", "votes",
+    # libs/trace.py itself — one collection of the cyclic collector that
+    # took a millisecond or more (attrs generation, collected)
+    "gc.pause",
     # ops/ — kernel routing: comb.resolve is verify_batch looking the
     # batch's keys up in the comb's tables ahead of the launch bracket
     # (attrs n, outcome: resident / built / declined / unknown, and
@@ -158,9 +181,12 @@ KNOWN_SPANS = frozenset({
     # state/pipeline.py — the block application pipeline (ADR-017)
     "pipeline.apply", "pipeline.commit", "pipeline.drain",
     "pipeline.stage", "pipeline.wait_staged",
-    # crypto/scheduler.py — the VerifyScheduler pipeline
+    # crypto/scheduler.py — the VerifyScheduler pipeline; sched.wait is
+    # a submitter blocked in VerifyFuture.result (attrs n, priority): a
+    # named wait on the submitter's thread
     "sched.coalesce", "sched.deadline_miss", "sched.host_lane",
     "sched.launch", "sched.resolve", "sched.shed", "sched.submit",
+    "sched.wait",
     # state/execution.py — the budgeted propose decomposition
     # (ADR-024) plus block apply
     "propose.assemble", "propose.prepare", "propose.reap",
@@ -170,6 +196,165 @@ KNOWN_SPANS = frozenset({
     # bounded chunk server (ADR-022)
     "statesync.fetch", "statesync.apply", "statesync.serve",
 })
+
+
+# The registered spans that stand for a request or a hand-off and not for
+# a piece of work: a root of one request (light.verify,
+# light.client.verify, blocksync.replay_window, consensus.preverify), a
+# coalesce window's root (batch.verify), or the bracket one thread holds
+# open around another's work (sched.launch, device.launch, and the three
+# kernel dispatches, whose launch brackets are launch records, not
+# spans).  What such a span does OUTSIDE its children has no name yet,
+# so its self time is "unnamed" (unnamed_ns below).  A span that is a
+# named wait on its thread (device.collect, pipeline.wait_staged,
+# pipeline.drain, sched.wait) or a named piece of work that happens to
+# have children (commit.collect, state.apply_block, light.store.save)
+# is not one: its self time is what its name says.
+ENVELOPES = frozenset({
+    "batch.verify", "blocksync.replay_window", "consensus.preverify",
+    "device.launch", "light.client.verify", "light.verify",
+    "ops.ed25519.verify_batch", "ops.secp.verify_batch",
+    "ops.sr25519.verify_batch", "sched.launch",
+})
+
+# The request-level spans whose usual duration the tracer knows, so that
+# one that takes INCIDENT_FACTOR times it is kept with everything that
+# overlapped it (Tracer._keep_incident) instead of being overwritten by
+# the ring's wrap.
+KEPT_SPANS = frozenset({
+    "batch.verify", "blocksync.replay_window", "consensus.preverify",
+    "device.collect", "light.client.verify", "light.verify",
+})
+INCIDENT_FACTOR = 8    # times the usual: a stall, not a slow request
+INCIDENT_WARMUP = 16   # of a name seen before any is judged: a process's
+#                        first launches compile, and are not incidents
+INCIDENT_KEEP = 8      # incidents held, oldest out first
+GC_PAUSE_MIN_NS = 1_000_000
+
+
+# ---------------------------------------------------------------------------
+# reading a snapshot: self time and the unnamed remainder.  Pure functions
+# over snapshot() records; the roll-up of /debug/trace, the incidents and
+# the benchmark's entry.unspanned_ms all rest on these two.
+# ---------------------------------------------------------------------------
+
+def _end(r) -> int:
+    return r["ts_ns"] + r["dur_ns"]
+
+
+def _segments(records, tid, t0_ns: int, t1_ns: int):
+    """[(start, end, the innermost finished span open on thread `tid`
+    then, or None)] covering [t0_ns, t1_ns) without overlap.  Spans of one
+    thread nest in time (they are context managers), so one sweep with a
+    stack does it; a span that straddles an edge is clipped to it."""
+    spans = sorted((r for r in records
+                    if r["ph"] == "X" and r["tid"] == tid
+                    and r["ts_ns"] < t1_ns and _end(r) > t0_ns),
+                   key=lambda r: (r["ts_ns"], -r["dur_ns"], -r["seq"]))
+    out, stack, cur = [], [], t0_ns
+
+    def upto(t):
+        nonlocal cur
+        t = min(t, t1_ns)
+        if t > cur:
+            out.append((cur, t, stack[-1] if stack else None))
+            cur = t
+
+    for r in spans:
+        while stack and _end(stack[-1]) <= r["ts_ns"]:
+            upto(_end(stack[-1]))
+            stack.pop()
+        upto(r["ts_ns"])
+        stack.append(r)
+    while stack:
+        upto(_end(stack[-1]))
+        stack.pop()
+    upto(t1_ns)
+    return out
+
+
+def _by_thread(records) -> dict:
+    """{tid: its records}."""
+    out: Dict[Any, list] = {}
+    for r in records:
+        out.setdefault(r["tid"], []).append(r)
+    return out
+
+
+def self_times(records) -> Dict[int, int]:
+    """{span id: self ns}: a span's duration less the part that the spans
+    inside it ON ITS OWN THREAD cover (the choosing-metrics guide's self
+    time).  Work a span caused on another thread is that thread's."""
+    out = {r["id"]: 0 for r in records if r["ph"] == "X"}
+    for tid, recs in _by_thread(records).items():
+        spans = [r for r in recs if r["ph"] == "X"]
+        if not spans:
+            continue
+        lo = min(r["ts_ns"] for r in spans)
+        hi = max(_end(r) for r in spans)
+        for a, b, inner in _segments(spans, tid, lo, hi):
+            if inner is not None:
+                out[inner["id"]] += b - a
+    return out
+
+
+def unnamed_ns(records, tid, t0_ns: int, t1_ns: int) -> int:
+    """The part of [t0_ns, t1_ns) on thread `tid` whose innermost open
+    span is an envelope (ENVELOPES) or nothing at all: time no name
+    accounts for."""
+    return sum(b - a for a, b, inner in _segments(records, tid, t0_ns, t1_ns)
+               if inner is None or inner["name"] in ENVELOPES)
+
+
+def _largest_gap(records, t0_ns: int, t1_ns: int):
+    """The longest stretch of [t0_ns, t1_ns), on any thread, that an
+    envelope held open with none of its children running: where a stall
+    hides.  With the envelope's name and the spans that ended at the
+    gap's start and began at its end.  (A worker thread with NOTHING open
+    is idle between tasks, not stalled; its wait shows as queue_wait_ns /
+    queued_ns on the span that follows.)"""
+    best = None
+    for tid, recs in _by_thread(records).items():
+        for a, b, inner in _segments(recs, tid, t0_ns, t1_ns):
+            if inner is None or inner["name"] not in ENVELOPES:
+                continue
+            if best is None or b - a > best["dur_ns"]:
+                before = [r["name"] for r in recs if r["ph"] == "X"
+                          and _end(r) == a and r is not inner]
+                after = [r["name"] for r in recs if r["ph"] == "X"
+                         and r["ts_ns"] == b and r is not inner]
+                best = {"tid": tid, "tname": inner["tname"], "ts_ns": a,
+                        "dur_ns": b - a, "inside": inner["name"],
+                        "before": before[0] if before else None,
+                        "after": after[0] if after else None}
+    return best
+
+
+def rollup(records) -> Dict[str, Any]:
+    """What an operator asks a slow node: per span name its count, total
+    and self time; per thread the unnamed remainder between its first and
+    last record; the newest sample of each counter."""
+    selfs = self_times(records)
+    spans: Dict[str, Dict[str, int]] = {}
+    counters: Dict[str, Any] = {}
+    for r in records:
+        if r["ph"] == "C":
+            counters[r["name"]] = dict(r["attrs"])
+        if r["ph"] != "X":
+            continue
+        row = spans.setdefault(r["name"],
+                               {"count": 0, "total_ns": 0, "self_ns": 0})
+        row["count"] += 1
+        row["total_ns"] += r["dur_ns"]
+        row["self_ns"] += selfs[r["id"]]
+    threads = []
+    for tid, recs in _by_thread(records).items():
+        lo = min(r["ts_ns"] for r in recs)
+        hi = max(_end(r) for r in recs)
+        threads.append({"tid": tid, "tname": recs[-1]["tname"],
+                        "window_ns": hi - lo,
+                        "unnamed_ns": unnamed_ns(recs, tid, lo, hi)})
+    return {"spans": spans, "threads": threads, "counters": counters}
 
 
 class _NoopSpan:
@@ -322,6 +507,17 @@ class Tracer:
         self._drop_counter = None  # lazy TraceMetrics handle
         self._ids = itertools.count(1)
         self._tls = threading.local()
+        # kept, not overwritten: per kept name [seen, usual ns, the
+        # first INCIDENT_WARMUP durations until the usual is set]
+        self._usual = {name: [0, 0, []] for name in KEPT_SPANS}
+        self._incidents: "collections.deque" = collections.deque(
+            maxlen=INCIDENT_KEEP)
+        self._incident_seq = 0
+        self._incident_counter = None  # lazy TraceMetrics handle
+        # collections of 1 ms or more, stashed by the gc hook WITHOUT the
+        # lock (a collection can start inside _record, under it) and
+        # turned into records by whoever takes the lock next
+        self._gc_pending: "collections.deque" = collections.deque()
 
     # -- state -------------------------------------------------------------
 
@@ -342,10 +538,14 @@ class Tracer:
         self._enabled = False
 
     def reset(self):
-        """Drop buffered spans.  seq stays monotonic so `since` cursors
-        held by pollers remain valid across a reset."""
+        """Drop buffered spans, kept incidents and what the tracer took
+        for usual.  seq stays monotonic so `since` cursors held by
+        pollers remain valid across a reset."""
         with self._lock:
             self._buf.clear()
+            self._gc_pending.clear()
+            self._incidents.clear()
+            self._usual = {name: [0, 0, []] for name in KEPT_SPANS}
 
     # -- recording ---------------------------------------------------------
 
@@ -384,6 +584,17 @@ class Tracer:
         self._record(name, "i", time.perf_counter_ns(), 0, 0, t.ident,
                      t.name, next(self._ids), parent, attrs)
 
+    def counter(self, name: str, **values):
+        """A sample of cumulative values (Chrome-trace ph="C"): what a
+        loop that may not have a span a turn has added up so far, taken
+        at a batch boundary; two samples differenced read the loop from
+        inside."""
+        if not self._enabled:
+            return
+        t = threading.current_thread()
+        self._record(name, "C", time.perf_counter_ns(), 0, 0, t.ident,
+                     t.name, next(self._ids), None, values)
+
     def current(self):
         """The innermost live span on this thread (no-op span when
         tracing is disabled or no span is open) — call sites deeper in
@@ -403,37 +614,153 @@ class Tracer:
 
     def _record(self, name, ph, t0_ns, dur_ns, cpu_ns, tid, tname,
                 span_id, parent_id, attrs):
+        usual = None
         with self._lock:
-            self._seq += 1
-            wrapped = len(self._buf) == self._buf.maxlen
-            if wrapped:
-                self._dropped += 1
-            self._buf.append({
-                "seq": self._seq, "name": name, "ph": ph, "ts_ns": t0_ns,
+            wrapped = self._drain_gc_locked() if self._gc_pending else 0
+            rec = {
+                "seq": 0, "name": name, "ph": ph, "ts_ns": t0_ns,
                 "dur_ns": dur_ns, "cpu_ns": cpu_ns, "tid": tid,
                 "tname": tname,
                 "id": span_id, "parent": parent_id, "attrs": attrs,
-            })
-        if wrapped:
+            }
+            wrapped += self._append_locked(rec)
+            if ph == "X":
+                # one look-up for a name that is not kept, one compare
+                # more for one that is and took its usual
+                st = self._usual.get(name)
+                if st is not None:
+                    usual = self._observe_locked(st, dur_ns)
+        for _ in range(wrapped):
             # counter inc AFTER releasing: the metric locks rank BELOW
             # the tracer lock (lockorder 80/84 < 90), so publishing
             # under self._lock would be a real inversion
-            self._publish_drop()
+            self._publish("_drop_counter", "dropped_spans")
+        if usual is not None:
+            self._keep_incident(rec, usual)
 
-    def _publish_drop(self):
-        c = self._drop_counter
+    def _append_locked(self, rec) -> int:
+        """Append under the lock; 1 when the ring dropped its oldest."""
+        self._seq += 1
+        rec["seq"] = self._seq
+        wrapped = len(self._buf) == self._buf.maxlen
+        if wrapped:
+            self._dropped += 1
+        self._buf.append(rec)
+        return int(wrapped)
+
+    def _publish(self, slot: str, metric: str):
+        c = getattr(self, slot)
         if c is None:
             try:
                 from tendermint_tpu.libs.metrics import TraceMetrics
-                c = TraceMetrics().dropped_spans
+                c = getattr(TraceMetrics(), metric)
             except Exception:  # noqa: BLE001 - observability of the
                 c = False       # observer must never take down a span
-            self._drop_counter = c
+            setattr(self, slot, c)
         if c is not False:
             try:
                 c.inc()
             except Exception:  # noqa: BLE001
                 pass
+
+    # -- kept, not overwritten ---------------------------------------------
+
+    @staticmethod
+    def _observe_locked(st, dur_ns):
+        """One more duration of a kept name: the usual (a running median:
+        the true one of the first INCIDENT_WARMUP, then a step of a 16th
+        towards each newcomer) when this one took INCIDENT_FACTOR times
+        it, else None.  No allocation once the usual is set."""
+        st[0] += 1
+        if st[0] <= INCIDENT_WARMUP:
+            st[2].append(dur_ns)
+            if st[0] == INCIDENT_WARMUP:
+                st[1] = sorted(st[2])[INCIDENT_WARMUP // 2]
+                st[2] = None
+            return None
+        usual = st[1]
+        if dur_ns >= INCIDENT_FACTOR * usual:
+            return usual  # and the usual stays what it was
+        step = max(usual >> 4, 1)
+        st[1] = usual + step if dur_ns > usual else usual - step
+        return None
+
+    def _drain_gc_locked(self) -> int:
+        wrapped = 0
+        while self._gc_pending:
+            wrapped += self._append_locked(self._gc_pending.popleft())
+        return wrapped
+
+    def _gc_pause(self, t0_ns: int, dur_ns: int, info: dict):
+        t = threading.current_thread()
+        stack = self._stack()
+        self._gc_pending.append({
+            "seq": 0, "name": "gc.pause", "ph": "X", "ts_ns": t0_ns,
+            "dur_ns": dur_ns, "cpu_ns": None, "tid": t.ident,
+            "tname": t.name, "id": next(self._ids),
+            "parent": stack[-1].span_id if stack else None,
+            "attrs": {"generation": info.get("generation"),
+                      "collected": info.get("collected")}})
+
+    def _keep_incident(self, rec, usual_ns: int):
+        """`rec` took INCIDENT_FACTOR times its name's usual: copy what
+        every thread recorded while it ran out of the ring's reach.  Paid
+        only by a request that has already lost a hundred times this."""
+        t0, t1 = rec["ts_ns"], _end(rec)
+        with self._lock:
+            last = self._incidents[-1] if self._incidents else None
+            if last is not None and last["ts_ns"] >= t0 \
+                    and last["ts_ns"] + last["dur_ns"] <= t1:
+                # the span around one already kept: the same stall
+                last["within"].append(rec["name"])
+                return
+            tail = []
+            for r in reversed(self._buf):
+                # records are appended as they END, so the first one that
+                # ended before the span began closes the walk (2 ms of
+                # slack for threads that raced to the lock)
+                if _end(r) < t0 - 2_000_000:
+                    break
+                if r["ts_ns"] < t1 and _end(r) >= t0:
+                    tail.append(dict(r, attrs=dict(r["attrs"])))
+            tail.reverse()
+            self._incident_seq += 1
+            seq = self._incident_seq
+        gap = _largest_gap(tail, t0, t1)
+        incident = {
+            "incident": seq, "name": rec["name"], "ts_ns": t0,
+            "dur_ns": rec["dur_ns"], "usual_ns": usual_ns,
+            "tid": rec["tid"], "tname": rec["tname"], "within": [],
+            "unnamed_ns": {
+                str(recs[-1]["tname"] or tid): unnamed_ns(recs, tid, t0, t1)
+                for tid, recs in _by_thread(tail).items()},
+            "gap": gap, "records": tail}
+        with self._lock:
+            self._incidents.append(incident)
+        self._publish("_incident_counter", "incidents")
+        try:
+            from tendermint_tpu.libs import log as tmlog
+            g = gap or {}
+            tmlog.logger("trace").warn(
+                "a request stalled: kept by the flight recorder",
+                span=rec["name"], ms=rec["dur_ns"] / 1e6,
+                usual_ms=usual_ns / 1e6, records=len(tail),
+                gap_ms=g.get("dur_ns", 0) / 1e6, gap_in=g.get("inside"),
+                gap_thread=g.get("tname"), gap_after=g.get("before"),
+                gap_before=g.get("after"))
+        except Exception:  # noqa: BLE001 - a log line, nothing more
+            pass
+
+    def incidents(self) -> List[Dict[str, Any]]:
+        """The last INCIDENT_KEEP kept incidents, oldest first (copies)."""
+        with self._lock:
+            return [dict(i, records=[dict(r) for r in i["records"]],
+                         within=list(i["within"]))
+                    for i in self._incidents]
+
+    def incident_count(self) -> int:
+        with self._lock:
+            return self._incident_seq
 
     def dropped(self) -> int:
         """Spans lost to ring wraparound since construction (a wrapped
@@ -454,6 +781,8 @@ class Tracer:
         actually handed, or spans recorded between two separate lock
         acquisitions would be skipped forever."""
         with self._lock:
+            if self._gc_pending:
+                self._drain_gc_locked()
             return ([dict(r, attrs=dict(r["attrs"]))
                      for r in self._buf if r["seq"] > since], self._seq)
 
@@ -469,6 +798,12 @@ class Tracer:
         records, last = self._snapshot(since)
         events = []
         for r in records:
+            if r["ph"] == "C":
+                # a counter event's args are its series, nothing else
+                events.append({"name": r["name"], "ph": "C", "pid": pid,
+                               "tid": r["tid"], "ts": r["ts_ns"] / 1000.0,
+                               "args": dict(r["attrs"])})
+                continue
             args = dict(r["attrs"])
             args["id"] = r["id"]
             if r["parent"] is not None:
@@ -521,6 +856,11 @@ def instant(name: str, parent=_UNSET, **attrs):
         TRACER.instant(name, parent, **attrs)
 
 
+def counter(name: str, **values):
+    if TRACER._enabled:
+        TRACER.counter(name, **values)
+
+
 def is_enabled() -> bool:
     return TRACER._enabled
 
@@ -535,6 +875,11 @@ def disable():
 
 def reset():
     TRACER.reset()
+
+
+def rollup_snapshot(since: int = 0) -> Dict[str, Any]:
+    """rollup() of the process-wide recorder's records."""
+    return rollup(TRACER.snapshot(since))
 
 
 def current():
@@ -557,9 +902,38 @@ def dropped() -> int:
     return TRACER.dropped()
 
 
+def incidents():
+    return TRACER.incidents()
+
+
 def chrome_trace(since: int = 0):
     return TRACER.chrome_trace(since)
 
 
 def export_file(path: str, since: int = 0) -> str:
     return TRACER.export_file(path, since)
+
+
+# ---------------------------------------------------------------------------
+# so that a pause has a name when it is one: the cyclic collector's start
+# and stop (gc.callbacks), a `gc.pause` span for a collection of 1 ms or
+# more.  Two clock reads a collection; nothing is recorded from inside the
+# hook (a collection can begin under the tracer's lock).
+# ---------------------------------------------------------------------------
+
+_gc_t0 = 0
+
+
+def _gc_hook(phase: str, info: dict):
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter_ns() if TRACER._enabled else 0
+    elif _gc_t0:
+        t0, _gc_t0 = _gc_t0, 0
+        dur = time.perf_counter_ns() - t0
+        if dur >= GC_PAUSE_MIN_NS:
+            TRACER._gc_pause(t0, dur, info)
+
+
+if _gc_hook not in gc.callbacks:
+    gc.callbacks.append(_gc_hook)

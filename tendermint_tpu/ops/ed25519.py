@@ -733,7 +733,7 @@ def _overlap_phases(probe: dict) -> dict:
     crypto/devobs.py for why a tighter number would require serializing
     the pipeline being measured)."""
     out = {}
-    for key in ("stage_s", "stage_cpu_s"):
+    for key in ("stage_s", "stage_cpu_s", "pub_rows_s", "head_s"):
         if probe.get(key) is not None:
             out[key] = probe[key]
     dma = probe.get("dma_s")
@@ -1594,7 +1594,11 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     pub_rows = np.ascontiguousarray(pub_m.T)
     if nb != n:
         pub_rows = np.pad(pub_rows, [(0, 0), (0, nb - n)])
+    t_rows = time.perf_counter()
     pub_chunks = _pub_cache_get(pub_rows, nsub, probe)
+    if probe is not None:
+        # the rows' content key, the look-up and, on a miss, the uploads
+        probe["pub_rows_s"] = time.perf_counter() - t_rows
     host_ok = np.zeros(nb, dtype=bool)
 
     stage_walls = []
@@ -1635,6 +1639,10 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
             outs.append(launch_kernel(pe.verify_packed_split_pallas,
                                       pub_chunks[j], cur,
                                       tile=PALLAS_TILE))
+            if j == 0 and probe is not None:
+                # the head of the launch ends here: the first chunk's
+                # kernel is dispatched (a clock read, no synchronisation)
+                probe["head_t"] = time.perf_counter()
             if j + 1 < nsub:
                 # stage j+1 on the host while the kernel runs chunk j; its
                 # device_put is issued after the dispatch so the DMA also
@@ -1710,7 +1718,14 @@ def _run_split(pubkeys, msgs, sigs, route: Route, obs_on: bool):
     outs, host_ok, _ = split_chunked_launch(pubkeys, msgs, sigs,
                                             probe=probe)
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    # head_s: the bracket's start to the return of the first chunk's
+    # launch_kernel, i.e. everything the device waited for before its
+    # first kernel; pub_rows_s (_pub_cache_get's wall) is the part of it
+    # a hit on the rows would spare
+    probe["head_s"] = probe.pop("head_t") - t0
     phases = _overlap_phases(probe) if obs_on else {}
+    trace.current().add(pub_rows_s=probe["pub_rows_s"],
+                        head_s=probe["head_s"])
     # what _pub_cache_get found: facts, not timings, so on the launch
     # record and on verify_batch's span whether or not the observatory
     # brackets the launch
@@ -1855,7 +1870,10 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
                 # mislabeled collect
                 key = "collect_s" if "compute_s" in phases else "drain_s"
                 phases[key] = time.perf_counter() - t_col
-        _record_launch(path or route.path, n, nb,
-                       time.perf_counter() - t0, shards=shards,
+        wall_s = time.perf_counter() - t0
+        _record_launch(path or route.path, n, nb, wall_s, shards=shards,
                        extra=phases)
+        # the wall the launch record holds: the span less this less its
+        # comb.resolve child is what verify_batch does OUTSIDE its bracket
+        sp.add(bracket_ns=int(wall_s * 1e9))
         return res[:n] & host_ok

@@ -7,6 +7,7 @@ TPU batch verifier consumes (reference types/vote.go:93, SURVEY.md §3.6).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Tuple
 
 from tendermint_tpu.libs import protodec as pd
 from tendermint_tpu.libs import protoenc as pe
@@ -73,11 +74,15 @@ class Vote:
         the verified-signature cache first: when the consensus receive loop
         has already batch-verified this vote in a coalesced launch, this is
         a hash lookup, not a signature check."""
+        return self.verify_cached(chain_id, pub_key)[0]
+
+    def verify_cached(self, chain_id: str, pub_key) -> Tuple[bool, bool]:
+        """(verify()'s verdict, whether the cache gave it)."""
         from tendermint_tpu.crypto.batch import verified_sigs
         msg = self.sign_bytes(chain_id)
         if verified_sigs.hit(pub_key.bytes(), msg, self.signature):
-            return True
-        return pub_key.verify_signature(msg, self.signature)
+            return True, True
+        return pub_key.verify_signature(msg, self.signature), False
 
     def validate_basic(self):
         if self.type not in (SignedMsgType.PREVOTE, SignedMsgType.PRECOMMIT):

@@ -10,6 +10,7 @@ and replay verification (types/validator_set.py, SURVEY.md §3.6).
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -21,6 +22,29 @@ from .validator_set import ValidatorSet
 from .vote import Vote
 
 MAX_VOTES_COUNT = 10000  # DoS cap (reference types/vote_set.go:18)
+
+
+class _Tally:
+    """What add_vote has done in this process, all VoteSets together: a
+    loop that may not have a span a vote (ADR-011) is read from inside by
+    differencing two samples of this (`trace.counter("votes", ...)`, taken
+    by the consensus receive loop once a drained batch).  Plain adds under
+    the GIL: votes are applied by one thread at a time, and a tally a few
+    counts off under a race would still be a tally."""
+
+    __slots__ = ("calls", "wall_ns", "cache_hits", "host_verifies",
+                 "refused")
+
+    def __init__(self):
+        self.calls = self.wall_ns = 0
+        self.cache_hits = self.host_verifies = self.refused = 0
+
+    def sample(self) -> Dict[str, int]:
+        return {k: getattr(self, k) for k in self.__slots__}
+
+
+TALLY = _Tally()
+_now = time.perf_counter_ns
 
 
 class VoteSetError(Exception):
@@ -86,44 +110,61 @@ class VoteSet:
     def add_vote(self, vote: Optional[Vote]) -> bool:
         """Returns True if the vote was added; raises on invalid votes or
         equivocation (ConflictingVoteError carries both votes)."""
-        if vote is None:
-            raise VoteSetError("nil vote")
-        val_index = vote.validator_index
-        val_addr = vote.validator_address
-        block_key = vote.block_id.key()
+        t0 = _now()
+        TALLY.calls += 1
+        try:
+            if vote is None:
+                raise VoteSetError("nil vote")
+            val_index = vote.validator_index
+            val_addr = vote.validator_address
+            block_key = vote.block_id.key()
 
-        if val_index < 0:
-            raise VoteSetError("vote has negative validator index")
-        if not val_addr:
-            raise VoteSetError("vote has empty validator address")
-        if (vote.height != self.height or vote.round != self.round
-                or vote.type != self.signed_msg_type):
-            raise VoteSetError(
-                f"expected {self.height}/{self.round}/{self.signed_msg_type}, "
-                f"got {vote.height}/{vote.round}/{vote.type}")
+            if val_index < 0:
+                raise VoteSetError("vote has negative validator index")
+            if not val_addr:
+                raise VoteSetError("vote has empty validator address")
+            if (vote.height != self.height or vote.round != self.round
+                    or vote.type != self.signed_msg_type):
+                raise VoteSetError(
+                    f"expected {self.height}/{self.round}/"
+                    f"{self.signed_msg_type}, "
+                    f"got {vote.height}/{vote.round}/{vote.type}")
 
-        # ensure the validator index matches the address
-        lookup_addr, val = self.val_set.get_by_index(val_index)
-        if val is None:
-            raise VoteSetError(
-                f"validator index {val_index} out of range")
-        if lookup_addr != val_addr:
-            raise VoteSetError(
-                "validator address does not match index")
+            # ensure the validator index matches the address
+            lookup_addr, val = self.val_set.get_by_index(val_index)
+            if val is None:
+                raise VoteSetError(
+                    f"validator index {val_index} out of range")
+            if lookup_addr != val_addr:
+                raise VoteSetError(
+                    "validator address does not match index")
 
-        # dedup: exact same vote already present?
-        existing = self._get_vote(val_index, block_key)
-        if existing is not None:
-            if existing.signature == vote.signature:
-                return False  # duplicate
-            raise VoteSetError("duplicate vote with different signature")
+            # dedup: exact same vote already present?
+            existing = self._get_vote(val_index, block_key)
+            if existing is not None:
+                if existing.signature == vote.signature:
+                    return False  # duplicate
+                raise VoteSetError(
+                    "duplicate vote with different signature")
 
-        # verify signature (single-item host path)
-        if not vote.verify(self.chain_id, val.pub_key):
-            raise VoteSetError(
-                f"invalid signature from {val_addr.hex()}")
+            # verify signature (single-item host path; a pre-verified
+            # vote is a SigCache hit)
+            ok, cached = vote.verify_cached(self.chain_id, val.pub_key)
+            if cached:
+                TALLY.cache_hits += 1
+            else:
+                TALLY.host_verifies += 1
+            if not ok:
+                raise VoteSetError(
+                    f"invalid signature from {val_addr.hex()}")
 
-        return self._add_verified_vote(vote, block_key, val.voting_power)
+            return self._add_verified_vote(vote, block_key,
+                                           val.voting_power)
+        except VoteSetError:
+            TALLY.refused += 1
+            raise
+        finally:
+            TALLY.wall_ns += _now() - t0
 
     def _get_vote(self, val_index: int, block_key: bytes) -> Optional[Vote]:
         v = self.votes[val_index]
