@@ -89,7 +89,7 @@ def expected_route(n: int, cache_pubs: bool, resident: bool, nshard: int,
     if not pallas:
         return "xla", bucket, 1
     if cache_pubs and n >= edops.PUB_CACHE_MIN:
-        chunk = min(edops.SPLIT_CHUNK, max(tile, bucket))
+        chunk = edops._split_chunk(n)
         return "pallas-split", -(-n // chunk) * chunk, 1
     return "pallas", max(tile, bucket), 1
 
@@ -670,12 +670,12 @@ def phase_commit_100k(ph: Phase, world):
         CHAIN_ID, commit.block_id, commit.height, commit))
     ph.expect(recs, [(n, True, False)], "verify_commit")
     if ph.pallas and ph.nshard == 1 and recs:
-        chunks = -(-n // edops.SPLIT_CHUNK)
+        chunks = -(-n // edops._split_chunk(n))
         ph.check(recs[0].get("chunks") == chunks,
                  f"{recs[0].get('chunks')} chunks, expected {chunks}")
     ph.facts["first_call_s"] = round(wall, 3)
     # five lanes: both ends, both sides of a chunk seam, one mid-batch
-    c = edops.SPLIT_CHUNK
+    c = edops._split_chunk(n)
     bad_idx = [0, c - 1, c, 4 * c, n - 1] if n > 4 * c + 1 \
         else [0, n // 4, n // 2, n - 2, n - 1]
     bad = tampered_commit(commit, bad_idx)

@@ -44,8 +44,11 @@ Three persistent side tables, all under the one leaf lock:
 
   * compile-cache inventory: (path, nb, shards) -> first-launch compile
     wall, first-seen monotonic time + observatory seq, and steady-state
-    hit count.  The keys are exactly ops/ed25519._seen_buckets' (the
-    CompileSentinel feed), so the two can be cross-checked.
+    hit count.  `nb` is the lanes of the shape that compiled: the
+    record's `bucket` where it carries one (a pallas-split launch is
+    chunks of one shape, whatever its length), its `nb` otherwise.  The
+    keys are exactly ops/ed25519._seen_buckets' (the CompileSentinel
+    feed), so the two can be cross-checked.
   * HBM residency ledger: per-pool resident bytes + high-water mark for
     the comb table cache, the pubkey-row cache, the static basepoint
     comb, and in-flight staging buffers (ledger_set for caches that
@@ -249,7 +252,10 @@ class DevObs:
                 r = dict(rec)
                 r["obs_seq"] = self._seq
                 r["t_mono"] = t
-                key = (r.get("path"), r.get("nb"), r.get("shards", 1))
+                # the shape that compiled: the record's `bucket` where
+                # it names one (pallas-split: the chunk), else its nb
+                key = (r.get("path"), r.get("bucket") or r.get("nb"),
+                       r.get("shards", 1))
                 inv = self._inventory.get(key)
                 if inv is None:
                     self._inventory[key] = {

@@ -95,10 +95,10 @@ class CompileSentinel:
     @staticmethod
     def bucket_allowed(nb: int, shards: int = 1) -> bool:
         """Is `nb` a known padded-lane shape?  Power-of-two lane
-        buckets (ops/ed25519.bucket_size) up to MAX_CHUNK, SPLIT_CHUNK
-        multiples (the split path), MAX_CHUNK multiples (pipelined
-        sub-batching), and on the mesh the per-shard rounding of any of
-        those."""
+        buckets (ops/ed25519.bucket_size) up to MAX_CHUNK, multiples
+        of SPLIT_CHUNK or SPLIT_CHUNK_SMALL (the split path's two
+        chunks), MAX_CHUNK multiples (pipelined sub-batching), and on
+        the mesh the per-shard rounding of any of those."""
         ed = CompileSentinel._edops()
         if ed is None:  # no kernel module imported -> nothing launched
             return True
@@ -114,9 +114,8 @@ class CompileSentinel:
                 CompileSentinel.bucket_allowed(nb)
         if nb == ed.bucket_size(nb) and nb <= ed.MAX_CHUNK:
             return True
-        if nb % ed.SPLIT_CHUNK == 0 or nb % ed.MAX_CHUNK == 0:
-            return True
-        return False
+        return any(nb % chunk == 0 for chunk in (
+            ed.SPLIT_CHUNK_SMALL, ed.SPLIT_CHUNK, ed.MAX_CHUNK))
 
     # -- lifecycle -----------------------------------------------------
 

@@ -693,15 +693,22 @@ def _set_last_launch(rec: dict):
 
 
 def _record_launch(path: str, n: int, nb: int, wall_s: float,
-                   shards: int = 1, extra: dict = None):
+                   shards: int = 1, extra: dict = None,
+                   bucket: int = None):
+    """`bucket`: the lanes of the shape the launch compiled, where that
+    is not `nb` — pallas-split launches chunks of one (96, chunk) shape,
+    so a batch of another length is another `nb` and no first launch.
+    The record says so (`bucket`), and devobs' inventory keys on it."""
     occupancy = n / nb if nb else 1.0
-    key = (path, nb, shards)
+    key = (path, bucket or nb, shards)
     with _launch_lock:
         first = key not in _seen_buckets
         _seen_buckets.add(key)
     rec = {
         "path": path, "n": n, "nb": nb, "occupancy": occupancy,
         "shards": shards, "first_launch": first, "wall_s": wall_s}
+    if bucket:
+        rec["bucket"] = bucket
     # the part of wall_s that was not the launch
     compile_s = _take_compile_s()
     if compile_s:
@@ -949,15 +956,21 @@ _pub_cache = DeviceLRU(max_entries=_PUB_CACHE_MAX)
 
 def _pub_cache_get(pub_rows: np.ndarray, nsub: int, probe: dict = None):
     """pub_rows: (32, NB) uint8, already padded; nsub: pipeline chunk
-    count.  Returns a list of nsub (32, NB/nsub) device arrays (the
-    pipelined launch shape), uploading on first sight.  Thread-safe:
-    multiple verifier threads (consensus, light client) route through
-    verify_sigs_bulk concurrently; a racing double upload resolves to
-    one resident copy (DeviceLRU.put is first-wins).
+    count.  Returns rows_of(j) -> the (32, NB/nsub) device array of
+    chunk j (the pipelined launch shape), to be asked for in launch
+    order.  Rows the cache holds are handed out; rows it does not are
+    uploaded chunk by chunk AS THEY ARE ASKED FOR, so a miss puts one
+    chunk's rows ahead of the first kernel and every later chunk's
+    behind a kernel (split_chunked_launch asks beside chunk j's
+    staging), and enter the cache with the last chunk.  One content key
+    over all the rows.  Thread-safe: multiple verifier threads
+    (consensus, light client) route through verify_sigs_bulk
+    concurrently; a racing double upload resolves to one resident copy
+    (DeviceLRU.put is first-wins).
 
     `probe` (optional dict) is told whether the rows were found on the
     device (pub_rows_cached) and, when they were not, the bytes this
-    call uploaded (pub_rows_bytes): a commit whose signers differ from
+    launch uploads (pub_rows_bytes): a commit whose signers differ from
     the last one's is new content, and pays the upload again."""
     key = (hashlib.sha256(pub_rows.tobytes()).digest(), nsub)
     chunks = _pub_cache.get(key)
@@ -966,15 +979,23 @@ def _pub_cache_get(pub_rows: np.ndarray, nsub: int, probe: dict = None):
         if chunks is None:
             probe["pub_rows_bytes"] = int(pub_rows.nbytes)
     if chunks is not None:
-        return chunks
-    # upload outside the cache lock (device_put blocks on the copy)
+        return chunks.__getitem__
     sub = pub_rows.shape[1] // nsub
-    chunks = [jax.device_put(jnp.asarray(np.ascontiguousarray(
-        pub_rows[:, j * sub:(j + 1) * sub]).view(np.int8)))
-        for j in range(nsub)]
-    chunks = _pub_cache.put(key, chunks, nbytes=int(pub_rows.nbytes))
-    devobs.ledger_set("pub_cache", _pub_cache.total_bytes)
-    return chunks
+    dev = jax.devices()[0]
+    mine = []
+
+    def upload(j):
+        # outside the cache lock (device_put blocks on the copy)
+        while len(mine) <= j:
+            a = len(mine) * sub
+            mine.append(jax.device_put(np.ascontiguousarray(
+                pub_rows[:, a:a + sub]).view(np.int8), dev))
+            if len(mine) == nsub:
+                _pub_cache.put(key, mine, nbytes=int(pub_rows.nbytes))
+                devobs.ledger_set("pub_cache", _pub_cache.total_bytes)
+        return mine[j]
+
+    return upload
 
 
 # -- comb table cache (ADR-013): per-validator fixed-base window tables,
@@ -1309,14 +1330,17 @@ class _CombSet(NamedTuple):
 
 class _Launched(NamedTuple):
     """What a route hands verify_batch to read back and record."""
-    out: object          # device array still in flight, or the host
-    #                      bitmap of a route that blocked inside
+    out: object          # device array still in flight, a list of them
+    #                      in lane order (one a chunk, joined on the
+    #                      host), or the host bitmap of a route that
+    #                      blocked inside
     host_ok: np.ndarray  # (n,) the host-side screens (lengths, s < L)
     nb: int              # padded lanes launched
     phases: dict         # devobs phase walls + the route's own fields
     t0: float            # perf_counter at the start of the route's bracket
     path: str = None     # set when the route ran as a variant of itself
     shards: int = 1
+    bucket: int = None   # lanes of the shape that compiled, where not nb
 
 
 def _comb_resolve(pubkeys, cache_pubs: bool):
@@ -1545,16 +1569,27 @@ def _run_comb(comb: _CombSet, msgs, sigs, plane, obs_on: bool):
     return _Launched(res, host_ok, nb, phases, t0, path, shards)
 
 
-SPLIT_CHUNK = 16384  # chunk size of the staged split-path pipeline
+SPLIT_CHUNK = 16384  # chunk of a split launch of more rows than that
+# chunk of every split launch up to SPLIT_CHUNK rows, a multiple of
+# PALLAS_TILE: of 1,024 / 2,048 / 4,096 the one the chip's sweep kept
+# (PERF.md section 6, PR 36)
+SPLIT_CHUNK_SMALL = 1024
 
 
 def _split_chunk(n: int) -> int:
-    """Lanes per launch of the split path.  A batch pads to a multiple
-    of the chunk, NOT to a power-of-two bucket: every launch has the
-    same (96, chunk) shape (one compile), and a 100k batch pads to
-    7x16384 = 114,688 lanes instead of 131,072 — the power-of-two
-    rounding wasted 31% of the kernel floor."""
-    return min(SPLIT_CHUNK, max(PALLAS_TILE, bucket_size(n)))
+    """Lanes per launch of the split path, from the row count alone:
+    SPLIT_CHUNK_SMALL for a batch of up to SPLIT_CHUNK rows, SPLIT_CHUNK
+    above.  A batch pads to a multiple of the chunk, NOT to a
+    power-of-two bucket: every launch has the same (96, chunk) shape
+    (one compile for every length of its range), 6,667 rows pad to
+    7 x 1,024 = 7,168 lanes instead of 8,192 and a 100k batch to
+    7 x 16,384 = 114,688 instead of 131,072.  The small chunk is what
+    lets a batch of one old chunk run as a pipeline at all: its kernel
+    starts after a chunk's staging, not the batch's.  (That the chunk
+    jumps back up past SPLIT_CHUNK rows is held by the benchmark's pin
+    of val100k-commit's launch shape, not by a measurement: PERF.md
+    section 7 row 36.)"""
+    return SPLIT_CHUNK_SMALL if n <= SPLIT_CHUNK else SPLIT_CHUNK
 
 
 def _msgs_slice(msgs, a: int, b: int):
@@ -1566,17 +1601,21 @@ def _msgs_slice(msgs, a: int, b: int):
 
 
 def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
-    """Cache-path launcher with a three-stage pipeline: while the kernel
-    runs chunk j, the host stages chunk j+1 (C challenge hashing +
-    packing) and its DMA proceeds — so for big batches (100k-validator
-    VerifyCommit) staging AND transfer hide behind compute and the wall
-    clock approaches the kernel floor.  Pubkey rows come from the
-    device-resident cache (96 B/sig on the wire).
+    """Cache-path launcher with a three-stage pipeline over chunks of
+    _split_chunk(n) lanes: while the kernel runs chunk j, the host
+    stages chunk j+1 (C challenge hashing + packing) and its DMA
+    proceeds — so staging AND transfer hide behind compute and the wall
+    clock approaches the kernel floor plus ONE chunk's staging, for a
+    light client's 6,667 rows (7 chunks of 1,024) as for a
+    100k-validator VerifyCommit (7 of 16,384).  Pubkey rows come from
+    the device-resident cache (96 B/sig on the wire); rows it does not
+    hold go up chunk by chunk with the staged rows, never all ahead of
+    the first kernel.
 
     NON-BLOCKING: returns (outs, host_ok, n) where outs is the list of
     per-chunk device result arrays still in flight — callers that
     pipeline multiple batches (bench.py) block once at the end; the
-    verify_batch wrapper below blocks immediately.
+    verify_batch wrapper below reads them back chunk by chunk.
 
     `probe` (optional dict, ADR-021): filled with the summed per-chunk
     staging walls (stage_s) and DMA walls (dma_s / dma_first_s /
@@ -1595,9 +1634,10 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
     if nb != n:
         pub_rows = np.pad(pub_rows, [(0, 0), (0, nb - n)])
     t_rows = time.perf_counter()
-    pub_chunks = _pub_cache_get(pub_rows, nsub, probe)
+    rows_of = _pub_cache_get(pub_rows, nsub, probe)
     if probe is not None:
-        # the rows' content key, the look-up and, on a miss, the uploads
+        # the rows' content key and the look-up (a miss's uploads ride
+        # with the chunks' puts below)
         probe["pub_rows_s"] = time.perf_counter() - t_rows
     host_ok = np.zeros(nb, dtype=bool)
 
@@ -1623,22 +1663,28 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
         return rsk.view(np.int8)
 
     dev = jax.devices()[0]
-    outs = []
     put_walls = []
+
+    def put(j):
+        # chunk j's pubkey rows (an upload where the cache has none, and
+        # then ahead of the staging, so that it flies meanwhile), then
+        # its staged rows
+        t_put = time.perf_counter()
+        ops = rows_of(j), jax.device_put(stage(j), dev)
+        put_walls.append(time.perf_counter() - t_put)
+        return ops
+
+    outs = []
     # two rsk chunks in flight at the peak (cur being consumed + nxt
     # staged-and-transferring) — the double-buffered window, same
     # accounting as verify_packed_pipelined
     inflight = (2 if nsub > 1 else 1) * 96 * chunk
     devobs.ledger_add("staging", inflight)
     try:
-        t_put = time.perf_counter()
-        nxt = jax.device_put(stage(0), dev)
-        put_walls.append(time.perf_counter() - t_put)
+        nxt = put(0)
         for j in range(nsub):
-            cur = nxt
             outs.append(launch_kernel(pe.verify_packed_split_pallas,
-                                      pub_chunks[j], cur,
-                                      tile=PALLAS_TILE))
+                                      *nxt, tile=PALLAS_TILE))
             if j == 0 and probe is not None:
                 # the head of the launch ends here: the first chunk's
                 # kernel is dispatched (a clock read, no synchronisation)
@@ -1647,9 +1693,7 @@ def split_chunked_launch(pubkeys, msgs, sigs, probe: dict = None):
                 # stage j+1 on the host while the kernel runs chunk j; its
                 # device_put is issued after the dispatch so the DMA also
                 # overlaps (same scheme as verify_packed_pipelined)
-                t_put = time.perf_counter()
-                nxt = jax.device_put(stage(j + 1), dev)
-                put_walls.append(time.perf_counter() - t_put)
+                nxt = put(j + 1)
     finally:
         devobs.ledger_add("staging", -inflight)
     if probe is not None:
@@ -1687,7 +1731,9 @@ def select_routes(n: int, cache_pubs: bool, *, pallas: bool,
                     sharding.MESH_ON_TPU is)
       pallas-split  on TPU, a cache_pubs batch of >= PUB_CACHE_MIN rows:
                     pubkey rows device-resident, 96 B/sig on the wire,
-                    launches of _split_chunk(n) lanes
+                    ceil(n / chunk) pipelined launches of chunk =
+                    _split_chunk(n) lanes: SPLIT_CHUNK_SMALL up to
+                    SPLIT_CHUNK rows, SPLIT_CHUNK above
       pallas        on TPU otherwise: one packed launch of the
                     power-of-two bucket, MAX_CHUNK sub-launches past it
       xla           every other backend: the XLA-composed kernel
@@ -1717,15 +1763,19 @@ def _run_split(pubkeys, msgs, sigs, route: Route, obs_on: bool):
     probe = {}
     outs, host_ok, _ = split_chunked_launch(pubkeys, msgs, sigs,
                                             probe=probe)
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     # head_s: the bracket's start to the return of the first chunk's
     # launch_kernel, i.e. everything the device waited for before its
-    # first kernel; pub_rows_s (_pub_cache_get's wall) is the part of it
-    # a hit on the rows would spare
+    # first kernel (one chunk's staging and puts, whatever the number of
+    # chunks); pub_rows_s (_pub_cache_get's wall) is the rows' content
+    # key and look-up inside it
     probe["head_s"] = probe.pop("head_t") - t0
-    phases = _overlap_phases(probe) if obs_on else {}
+    overlap = _overlap_phases(probe)
+    # how many chunks the launch had, and what share of its puts had a
+    # kernel to hide behind
     trace.current().add(pub_rows_s=probe["pub_rows_s"],
-                        head_s=probe["head_s"])
+                        head_s=probe["head_s"], chunks=overlap["chunks"],
+                        chunk_overlap=round(overlap["chunk_overlap"], 4))
+    phases = overlap if obs_on else {}
     # what _pub_cache_get found: facts, not timings, so on the launch
     # record and on verify_batch's span whether or not the observatory
     # brackets the launch
@@ -1733,7 +1783,11 @@ def _run_split(pubkeys, msgs, sigs, route: Route, obs_on: bool):
             if k in probe}
     phases.update(rows)
     trace.current().add(**rows)
-    return _Launched(out, host_ok, route.nb, phases, t0)
+    # the chunks' results are joined on the host (no program of `chunks`
+    # operands to compile for a prefix of another length), and the
+    # chunk's shape is all that compiles
+    return _Launched(outs, host_ok, route.nb, phases, t0,
+                     bucket=route.nb // route.launches)
 
 
 def _run_pallas(pubkeys, msgs, sigs, route: Route, obs_on: bool):
@@ -1856,12 +1910,14 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
                     launched = None
             if launched is not None:
                 break
-        out, host_ok, nb, phases, t0, path, shards = launched
+        out, host_ok, nb, phases, t0, path, shards, bucket = launched
         if isinstance(out, np.ndarray):
             res = out  # the route blocked inside and read back itself
         else:
             t_col = time.perf_counter()
-            res = np.asarray(out)  # blocks: wall below includes execution
+            # blocks: wall below includes execution
+            res = np.concatenate([np.asarray(o) for o in out]) \
+                if isinstance(out, list) else np.asarray(out)
             if obs_on:
                 # routes that bracketed compute have only the readback
                 # left here (collect_s); the double-buffered ones block
@@ -1872,7 +1928,7 @@ def verify_batch(pubkeys, msgs, sigs, cache_pubs: bool = False) -> np.ndarray:
                 phases[key] = time.perf_counter() - t_col
         wall_s = time.perf_counter() - t0
         _record_launch(path or route.path, n, nb, wall_s, shards=shards,
-                       extra=phases)
+                       extra=phases, bucket=bucket)
         # the wall the launch record holds: the span less this less its
         # comb.resolve child is what verify_batch does OUTSIDE its bracket
         sp.add(bracket_ns=int(wall_s * 1e9))

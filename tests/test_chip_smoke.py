@@ -108,8 +108,8 @@ def test_expected_route_matches_the_issue_table():
     assert route(150, True, False, 1) == ("pallas", 256, 1)
     assert route(150, False, True, 1) == ("comb", 256, 1)
     assert route(4, False, True, 1) == ("comb", 64, 1)
-    assert route(10_000, True, False, 1) == ("pallas-split", 16_384, 1)
-    assert route(6_667, True, False, 1) == ("pallas-split", 8_192, 1)
+    assert route(10_000, True, False, 1) == ("pallas-split", 10_240, 1)
+    assert route(6_667, True, False, 1) == ("pallas-split", 7_168, 1)
     assert route(3_334, True, False, 1) == ("pallas", 4_096, 1)
     assert route(100_000, True, False, 1) == ("pallas-split", 114_688, 1)
     # four chips with the mesh on (sharding.MESH_ON_TPU; off by default,

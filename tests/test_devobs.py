@@ -481,7 +481,7 @@ def test_hbm_ledger_real_pools():
     # before ADR-021, leaving the byte ledger blind to the pool)
     pub_rows = np.zeros((32, 64), dtype=np.uint8)
     pub_rows[0] = np.arange(64, dtype=np.uint8)
-    edops._pub_cache_get(pub_rows, 1)
+    edops._pub_cache_get(pub_rows, 1)(0)
     rep = devobs.ledger_report()
     assert rep["pub_cache"]["bytes"] >= pub_rows.nbytes
     assert edops._pub_cache.total_bytes >= pub_rows.nbytes

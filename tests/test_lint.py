@@ -503,6 +503,10 @@ def test_compile_sentinel_flags_foreign_bucket():
     # nb=64 (the shared lane bucket) and chunk multiples pass
     assert CompileSentinel.bucket_allowed(64)
     assert CompileSentinel.bucket_allowed(edops.SPLIT_CHUNK * 7)
+    # 6,667 rows of the split path: 7 small chunks, no power of two
+    assert CompileSentinel.bucket_allowed(edops.SPLIT_CHUNK_SMALL * 7)
+    assert not CompileSentinel.bucket_allowed(
+        edops.SPLIT_CHUNK_SMALL * 7 + edops.PALLAS_TILE)
     assert CompileSentinel.bucket_allowed(edops.MAX_CHUNK * 2)
     assert not CompileSentinel.bucket_allowed(100)
     assert not CompileSentinel.bucket_allowed(0)

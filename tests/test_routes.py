@@ -54,16 +54,25 @@ ROUTE_TABLE = [
     # the PUB_CACHE_MIN edge
     ("4,095 cached rows, TPU",
      (4095, True, True, False, False), [("pallas", 4096, 1)]),
-    ("4,096 cached rows, TPU",
-     (4096, True, True, False, False), [("pallas-split", 4096, 1)]),
+    ("4,096 cached rows, TPU: 4 chunks of SPLIT_CHUNK_SMALL",
+     (4096, True, True, False, False), [("pallas-split", 4096, 4)]),
     # val10k-adjacent, and the 2/3 certificate of the other two
-    ("6,667 cached rows, TPU",
-     (6667, True, True, False, False), [("pallas-split", 8192, 1)]),
+    ("6,667 cached rows, TPU: 7 chunks of SPLIT_CHUNK_SMALL",
+     (6667, True, True, False, False), [("pallas-split", 7168, 7)]),
     ("6,667 rows, not cached, TPU",
      (6667, False, True, False, False), [("pallas", 8192, 1)]),
     # chip_smoke at 10,000 and 100,000 validators
-    ("10,000 cached rows, TPU",
-     (10000, True, True, False, False), [("pallas-split", 16384, 1)]),
+    ("10,000 cached rows, TPU: a multiple of the chunk, no power of two",
+     (10000, True, True, False, False), [("pallas-split", 10240, 10)]),
+    # the SPLIT_CHUNK edge: the last batch of small chunks, the first
+    # of large ones
+    ("16,384 cached rows, TPU",
+     (16384, True, True, False, False), [("pallas-split", 16384, 16)]),
+    ("16,385 cached rows, TPU: 2 chunks of SPLIT_CHUNK",
+     (16385, True, True, False, False), [("pallas-split", 32768, 2)]),
+    # val100k-commit (its workload pins this shape), chip_smoke at 100,000
+    ("99,000 cached rows, TPU: 7 chunks of SPLIT_CHUNK",
+     (99000, True, True, False, False), [("pallas-split", 114688, 7)]),
     ("100,000 cached rows, TPU: 7 chunks of SPLIT_CHUNK",
      (100000, True, True, False, False), [("pallas-split", 114688, 7)]),
     ("70,000 rows, not cached, TPU: 2 sub-launches of MAX_CHUNK",
@@ -79,7 +88,7 @@ ROUTE_TABLE = [
                                          ("xla", 1024, 1)]),
     ("plane worth sharding, no tables, TPU",
      (10000, True, True, False, True), [("mesh-pallas", None, None),
-                                        ("pallas-split", 16384, 1)]),
+                                        ("pallas-split", 10240, 10)]),
     # a plane is present but the batch is under its floor
     ("plane present, batch under its floor",
      (5, False, False, False, False), [("xla", 64, 1)]),
@@ -105,9 +114,28 @@ def test_route_table(observed, want):
                               plane_worth=plane_worth)
     assert [tuple(r) for r in got] == want
     # the thresholds the table is written against
-    assert (edops.PUB_CACHE_MIN, edops.PALLAS_TILE, edops.SPLIT_CHUNK,
-            edops.MAX_CHUNK, edops.MIN_BUCKET) == (4096, 256, 16384,
-                                                   65536, 64)
+    assert (edops.PUB_CACHE_MIN, edops.PALLAS_TILE,
+            edops.SPLIT_CHUNK_SMALL, edops.SPLIT_CHUNK, edops.MAX_CHUNK,
+            edops.MIN_BUCKET) == (4096, 256, 1024, 16384, 65536, 64)
+
+
+# n -> lanes of one launch of the split route: the row count and nothing
+# else decides, one small chunk up to SPLIT_CHUNK rows, SPLIT_CHUNK above
+SPLIT_CHUNKS = {4096: 1024, 6667: 1024, 10000: 1024, 16384: 1024,
+                16385: 16384, 99000: 16384, 100000: 16384}
+
+
+@pytest.mark.parametrize("n", sorted(SPLIT_CHUNKS))
+def test_split_chunk_reads_the_row_count_alone(n):
+    chunk = edops._split_chunk(n)
+    assert chunk == SPLIT_CHUNKS[n]
+    assert chunk % edops.PALLAS_TILE == 0
+    (route,) = edops.select_routes(n, True, pallas=True,
+                                   comb_resident=False, plane_worth=False)
+    assert route.path == "pallas-split"
+    assert route.launches == -(-n // chunk)
+    assert route.nb == route.launches * chunk
+    assert 0 <= route.nb - n < chunk
 
 
 def test_the_plane_answers_the_selectors_question():

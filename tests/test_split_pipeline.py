@@ -5,7 +5,11 @@ the launch record and on the `ops.ed25519.verify_batch` span follows the
 CONTENT of the batch's pubkey rows and the cache's four entries; and
 `comb.resolve` names each way the comb's look-up can end, among them the
 early exit of a batch no table set can hold (ISSUE 32), which is held to
-the full look-up's answer and shown not to sort.
+the full look-up's answer and shown not to sort.  Since ISSUE 36 a batch
+of up to SPLIT_CHUNK rows is a pipeline too, of SPLIT_CHUNK_SMALL-lane
+chunks: the seams of a 6,667-row batch at the module's own constants,
+what goes to the device ahead of each kernel on a miss and on a hit, and
+that a prefix of another length launches, and records, nothing new.
 
 The fused kernel compiles for a TPU only, so the route is taken here with
 the chunk patched small (as tests/test_ed25519.py::test_pub_cache_routing
@@ -14,6 +18,8 @@ the very rows the route staged for it: what is tested is the route, its
 seams and its records, not the kernel (tests/test_pallas_ed25519.py,
 tests/test_tpu_lowering.py)."""
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 import pytest
@@ -86,8 +92,7 @@ def test_three_chunks_carry_real_verdicts_across_their_seams(
     pubs, msgs, sigs = batch(n)
     # both ends, both sides of the first seam, one in the third chunk
     bad = [0, CHUNK - 1, CHUNK, 2 * CHUNK + 7, n - 1]
-    sigs = [bytes([s[0] ^ 1]) + s[1:] if i in bad else s
-            for i, s in enumerate(sigs)]
+    sigs = tamper(sigs, bad)
     out = edops.verify_batch(pubs, msgs, sigs, cache_pubs=True)
     want = np.array([_edref.verify(p, m, s)
                      for p, m, s in zip(pubs, msgs, sigs)])
@@ -149,6 +154,198 @@ def test_a_route_that_keeps_no_rows_says_nothing_of_them(split_route,
     rec, attrs = launch_facts()
     assert rec["path"] == "xla"
     assert "pub_rows_cached" not in rec and "pub_rows_cached" not in attrs
+
+
+# ---------------------------------------------------------------------------
+# the small chunk (ISSUE 36): a batch of up to SPLIT_CHUNK rows is a
+# pipeline of SPLIT_CHUNK_SMALL-lane launches
+# ---------------------------------------------------------------------------
+
+def signed(n, first=0, tag=b"small"):
+    """batch() through OpenSSL where the image has it: 6,667 rows in under
+    a second (the pure-Python signer takes 5 ms a row)."""
+    from tendermint_tpu.crypto import ed25519 as edkeys
+
+    privs = [edkeys.PrivKey((0x5100 + first + i).to_bytes(32, "little"))
+             for i in range(n)]
+    msgs = [b"%s %d" % (tag, first + i) for i in range(n)]
+    return ([k.pub_key().bytes() for k in privs], msgs,
+            [k.sign(m) for k, m in zip(privs, msgs)])
+
+
+def oracle(pubs, msgs, sigs):
+    from tendermint_tpu.crypto import ed25519 as edkeys
+    return np.array([edkeys.PubKey(p).verify_signature(m, s)
+                     for p, m, s in zip(pubs, msgs, sigs)])
+
+
+def tamper(sigs, bad):
+    return [bytes([s[0] ^ 1]) + s[1:] if i in bad else s
+            for i, s in enumerate(sigs)]
+
+
+def seam_rows(n, chunk):
+    """Both ends, a row on each side of every chunk seam, and the padded
+    tail's last real row (n - 1, twice named)."""
+    seams = range(chunk, n, chunk)
+    return sorted({0, n - 1} | {c - 1 for c in seams} | set(seams))
+
+
+def test_small_chunks_carry_real_verdicts_across_every_seam(
+        split_route, monkeypatch):
+    """The rule at 1/16 of its size with the XLA kernel's own verdicts: a
+    batch that the old rule launched as ONE 512-lane chunk runs as four of
+    128, the tail chunk a quarter full."""
+    monkeypatch.setattr(edops, "SPLIT_CHUNK", 8 * CHUNK)
+    monkeypatch.setattr(edops, "SPLIT_CHUNK_SMALL", CHUNK)
+    monkeypatch.setattr(pe, "verify_packed_split_pallas", xla_on_split_rows)
+    n = 3 * CHUNK + 33
+    pubs, msgs, sigs = signed(n)
+    bad = seam_rows(n, CHUNK)
+    assert bad == [0, 127, 128, 255, 256, 383, 384, n - 1]
+    sigs = tamper(sigs, bad)
+    out = edops.verify_batch(pubs, msgs, sigs, cache_pubs=True)
+    want = oracle(pubs, msgs, sigs)
+    assert [int(i) for i in np.flatnonzero(~want)] == bad
+    assert np.array_equal(out, want)
+    rec, attrs = launch_facts()
+    assert (rec["path"], rec["n"], rec["nb"], rec["bucket"]) == \
+        ("pallas-split", n, 4 * CHUNK, CHUNK)
+    assert rec["chunks"] == attrs["chunks"] == 4
+    assert 0.0 <= attrs["chunk_overlap"] <= 1.0
+    assert attrs["chunk_overlap"] == round(rec["chunk_overlap"], 4)
+
+
+@functools.lru_cache(maxsize=None)
+def light_rows():
+    pubs, msgs, sigs = signed(10000, tag=b"light")
+    _, rsk, ok = edops.prepare_batch_split(pubs, sigs, msgs)
+    assert ok.all()
+    return pubs, msgs, sigs, frozenset(
+        p + rsk[:, i].tobytes() for i, p in enumerate(pubs))
+
+
+@pytest.fixture
+def light_set(monkeypatch):
+    """A light client's prefix at the size the cells send it, the module's
+    own constants, and a stand-in for the kernel that knows the honest
+    rows: a lane is valid when its (A, R, s, k) rows are one honest
+    signature's, so a tampered row, a zeroed padding lane or a row that
+    landed in another lane reads false.  Yields (pubs, msgs, sigs, the
+    stand-in's log of (pub rows shape, rsk shape) a call)."""
+    import jax.numpy as jnp
+
+    assert (edops.PUB_CACHE_MIN, edops.SPLIT_CHUNK) == (4096, 16384)
+    monkeypatch.setattr(edops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(edops, "_pub_cache",
+                        edops.DeviceLRU(max_entries=edops._PUB_CACHE_MAX))
+    monkeypatch.setattr(edops, "_comb_enabled_override", False)
+    from tendermint_tpu.parallel import sharding
+    monkeypatch.setattr(sharding, "_PLANE", False)
+    pubs, msgs, sigs, honest = light_rows()
+    calls = []
+
+    def knows_the_honest_rows(pub_t, rsk, tile=None):
+        calls.append((pub_t.shape, rsk.shape))
+        a, r = np.asarray(pub_t), np.asarray(rsk)
+        return jnp.asarray([a[:, i].tobytes() + r[:, i].tobytes() in honest
+                            for i in range(r.shape[1])])
+
+    monkeypatch.setattr(pe, "verify_packed_split_pallas",
+                        knows_the_honest_rows)
+    with edops._launch_lock:
+        seen = set(edops._seen_buckets)
+        edops._seen_buckets.difference_update(
+            {k for k in seen if k[0] == "pallas-split"})
+    trace.enable()
+    trace.reset()
+    yield pubs, msgs, sigs, calls
+    trace.disable()
+    trace.reset()
+    with edops._launch_lock:
+        edops._seen_buckets.clear()
+        edops._seen_buckets.update(seen)
+
+
+def test_6667_rows_are_seven_small_chunks_and_the_oracles_bitmap(
+        light_set):
+    pubs, msgs, sigs = (x[:6667] for x in light_set[:3])
+    calls = light_set[3]
+    c = edops.SPLIT_CHUNK_SMALL
+    chunks = -(-6667 // c)
+    bad = seam_rows(6667, c)
+    assert len(bad) == 2 * chunks and 6666 in bad and 6666 % c < c - 1
+    sigs = tamper(sigs, bad)
+    out = edops.verify_batch(pubs, msgs, sigs, cache_pubs=True)
+    want = oracle(pubs, msgs, sigs)
+    assert [int(i) for i in np.flatnonzero(~want)] == bad
+    assert np.array_equal(out, want)
+    assert calls == [((32, c), (96, c))] * chunks
+    rec, attrs = launch_facts()
+    assert (rec["path"], rec["n"], rec["chunks"], rec["nb"]) == \
+        ("pallas-split", 6667, chunks, chunks * c)
+    assert rec["bucket"] == c and rec["first_launch"] is True
+    assert (attrs["chunks"], attrs["nb"]) == (chunks, chunks * c)
+    assert rec["head_s"] <= rec["wall_s"]
+
+
+def test_the_same_rows_again_are_a_hit_and_upload_nothing(light_set,
+                                                          monkeypatch):
+    """What goes to the device and when: on a miss one chunk's pubkey rows
+    and staged rows ahead of each kernel (the head holds ONE chunk's,
+    whatever the number of chunks), on a hit the staged rows alone."""
+    import jax
+
+    pubs, msgs, sigs = (x[:6667] for x in light_set[:3])
+    real_put, stand_in = jax.device_put, pe.verify_packed_split_pallas
+    events = []
+
+    def put(x, *a, **k):
+        events.append("put%d" % x.shape[0])
+        return real_put(x, *a, **k)
+
+    def kernel(*a, **k):
+        events.append("kernel")
+        return stand_in(*a, **k)
+
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(pe, "verify_packed_split_pallas", kernel)
+    c = edops.SPLIT_CHUNK_SMALL
+    chunks = -(-6667 // c)
+    for cached, want in ((False, ["put32", "put96", "kernel"] * chunks),
+                         (True, ["put96", "kernel"] * chunks)):
+        del events[:]
+        assert edops.verify_batch(pubs, msgs, sigs, cache_pubs=True).all()
+        assert events == want
+        rec, attrs = launch_facts()
+        assert rec["pub_rows_cached"] is attrs["pub_rows_cached"] is cached
+        assert rec.get("pub_rows_bytes") == \
+            (None if cached else 32 * chunks * c)
+    assert len(edops._pub_cache) == 1 and edops._pub_cache.hits == 1
+
+
+@pytest.mark.parametrize("n", [4096, 5000, 6668, 10000])
+def test_another_length_launches_no_new_kernel_shape(light_set, n):
+    """A light client's prefix changes its length with the set: every
+    length of 4,096..16,384 rows launches the one (96, C) shape, is no
+    first launch, and adds no entry to the compile inventory."""
+    from tendermint_tpu.crypto import devobs
+
+    pubs, msgs, sigs, calls = light_set
+    c = edops.SPLIT_CHUNK_SMALL
+    devobs.reset()
+    assert edops.verify_batch(pubs[:6667], msgs[:6667], sigs[:6667],
+                              cache_pubs=True).all()
+    assert edops.last_launch()["first_launch"] is True
+    assert edops.verify_batch(pubs[:n], msgs[:n], sigs[:n],
+                              cache_pubs=True).all()
+    rec = edops.last_launch()
+    chunks = -(-n // c)
+    assert (rec["n"], rec["nb"], rec["chunks"]) == (n, chunks * c, chunks)
+    assert rec["first_launch"] is False and "compile_s" not in rec
+    assert set(calls) == {((32, c), (96, c))}
+    assert [(e["path"], e["nb"], e["hits"])
+            for e in devobs.compile_inventory()] == [("pallas-split", c, 1)]
 
 
 # ---------------------------------------------------------------------------

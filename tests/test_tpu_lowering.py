@@ -28,9 +28,12 @@ def test_packed_ladder_lowers_for_tpu():
                       tile=edops.PALLAS_TILE)
 
 
-def test_split_ladder_lowers_for_tpu():
-    _lowers_to_mosaic(pe.verify_packed_split_pallas, (32, 8192), (96, 8192),
-                      tile=edops.PALLAS_TILE)
+@pytest.mark.parametrize("chunk", [edops.SPLIT_CHUNK_SMALL,
+                                   edops.SPLIT_CHUNK])
+def test_split_ladder_lowers_for_tpu(chunk):
+    # the two shapes the split route launches (ops/ed25519._split_chunk)
+    _lowers_to_mosaic(pe.verify_packed_split_pallas, (32, chunk),
+                      (96, chunk), tile=edops.PALLAS_TILE)
 
 
 def test_mesh_pallas_step_lowers_for_tpu_on_four_devices():
