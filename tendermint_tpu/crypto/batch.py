@@ -408,7 +408,7 @@ def _device_verifier(tname: str):
     """The TPU lane for a key scheme, or None if that scheme stays on the
     host.  ed25519: the comb / fused ladder routes (ops/ed25519.py);
     sr25519: same curve, ristretto lane (ops/sr25519.py); secp256k1:
-    the Jacobian Straus lane (ops/secp.py), default-on since ADR-015 —
+    the Pallas Straus lane (ops/secp.py), default-on since ADR-015 —
     TM_TPU_SECP_LANE=0 / [batch_verifier] secp_lane=false is the
     rollback switch back to the host C lane."""
     if tname == ed.KEY_TYPE:

@@ -4,8 +4,10 @@ NLIMB = 22, limb axis 0, batch axes trailing; see that module's docstring
 for the layout rationale).
 
 The reference verifies secp256k1 serially via btcec on the host
-(reference crypto/secp256k1/secp256k1.go:197); this field layer exists so
-the Straus ladder in ops/secp.py can run one signature per vector lane.
+(reference crypto/secp256k1/secp256k1.go:197).  The lane's ladder runs
+in the Pallas kernel ops/pallas_secp.py, which takes its limb layout,
+reduction constants and conversions from here; the jnp ops below are
+the plain reference its field arithmetic is tested against.
 
 Reduction structure: 22 limbs * 12 bits = 264 bits and
     2^264 = 2^8 * 2^256 ≡ 2^8 * (2^32 + 977) = 2^40 + 250112 (mod p)
@@ -86,12 +88,6 @@ def carry(c):
     return _tail_pass(_carry_pass(_carry_pass(_carry_pass(c))))
 
 
-def carry_lazy(c):
-    """carry() for operands already bounded by a few lazy adds of loose
-    values: two passes + tail suffice."""
-    return _tail_pass(_carry_pass(_carry_pass(c)))
-
-
 def _tail_pass(v):
     c0 = v[0] >> RADIX
     v = v.at[0].set(v[0] & MASK)
@@ -101,14 +97,6 @@ def _tail_pass(v):
 # ---------------------------------------------------------------------------
 # ring ops
 # ---------------------------------------------------------------------------
-
-def zero(shape=()):
-    return jnp.zeros((NLIMB,) + shape, dtype=_i32)
-
-
-def one(shape=()):
-    return jnp.zeros((NLIMB,) + shape, dtype=_i32).at[0].set(1)
-
 
 def _bcast(x, batch):
     want = (NLIMB,) + batch
@@ -233,12 +221,6 @@ def is_zero(a):
 
 def is_odd(a):
     return (freeze(a)[0] & 1).astype(jnp.bool_)
-
-
-def select(cond, a, b):
-    B = jnp.broadcast_shapes(jnp.shape(cond), a.shape[1:], b.shape[1:])
-    return jnp.where(jnp.broadcast_to(cond, B)[None, ...],
-                     _bcast(a, B), _bcast(b, B))
 
 
 # ---------------------------------------------------------------------------

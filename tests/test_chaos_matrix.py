@@ -155,7 +155,7 @@ def test_secp_device_lane_chaos_raise_bitmap_exact():
     asserted exercised by tests/test_lint.py) degrades to the host C
     lane with the exact per-sig bitmap.  Like the sr25519 twin above,
     the injection fires at function entry BEFORE any staging or kernel
-    dispatch — no XLA compile budget spent on the secp ladder."""
+    dispatch — no compile budget spent on the secp kernel."""
     rt = _runtime()
     pubs, msgs, sigs = _secp_batch()
     fail.set_mode("ops.secp.verify_batch", "raise")

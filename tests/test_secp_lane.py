@@ -10,6 +10,7 @@ and seeded random field elements, both as single lanes and batched.
 """
 from __future__ import annotations
 
+import hashlib
 import random
 
 import numpy as np
@@ -202,17 +203,43 @@ def _secp_adversarial_vectors():
     ]
 
 
+def _secp_degenerate_key_vectors():
+    """Secret keys 1 and N-1 (P = G and P = -G: x-only, so both lift to
+    G, whose table of -P multiples meets the fixed-base G table's own
+    rows inside the ladder, as P = Q and P = -Q additions), each signed
+    honestly, with a flipped bit, and with s = e, for which
+    R' = [e]G - [e]G is the point at infinity."""
+    from tendermint_tpu.crypto import secp256k1 as secp
+
+    out = []
+    for d in (1, secp.N - 1):
+        k = secp.PrivKey(d.to_bytes(32, "big"))
+        pub = k.pub_key().bytes()
+        m = b"degenerate key %d" % (d & 0xFF)
+        good = k.sign(m)
+        bad = bytearray(good)
+        bad[40] ^= 4
+        r = good[:32]
+        e = int.from_bytes(secp._tagged_hash(
+            "BIP0340/challenge",
+            r + pub[1:] + hashlib.sha256(m).digest()), "big") % secp.N
+        out += [(pub, m, good), (pub, m, bytes(bad)),
+                (pub, m, r + e.to_bytes(32, "big"))]
+    return out
+
+
 @pytest.mark.slow
 def test_secp_device_lane_bitmap_vs_host_oracles():
     """Bitmap of the TPU lane pinned against the host oracles on the
-    adversarial vectors + corrupted-signature sweep.  Slow tier: the
-    64-step complete-add ladder costs a multi-minute XLA-on-CPU compile
-    (one per process)."""
+    adversarial vectors, the degenerate keys 1 and N-1 and a corrupted-
+    signature sweep.  Slow tier: the whole Pallas kernel through the
+    interpreter off a TPU costs minutes of XLA-on-CPU compile (one per
+    process)."""
     from tendermint_tpu.crypto import secp256k1 as secp
     from tendermint_tpu.libs import native
     from tendermint_tpu.ops import secp as secp_ops
 
-    cases = _secp_adversarial_vectors()
+    cases = _secp_adversarial_vectors() + _secp_degenerate_key_vectors()
     # plus a corrupted sweep over fresh keys
     for i in range(6):
         k = secp.PrivKey.gen_from_secret((0xE100 + i).to_bytes(32, "big"))
@@ -292,3 +319,70 @@ def test_secp_lane_routing_default_on_with_rollback(monkeypatch):
     monkeypatch.delenv("TM_TPU_SECP_LANE")
     want, bits = run_batch()
     assert bits == want and routed == [6, 6]
+
+
+@pytest.fixture
+def stubbed_kernel(monkeypatch):
+    """ops/pallas_secp.verify replaced by a stand-in that notes the tile
+    and interpret flag _verify_core hands it and calls every lane valid;
+    _verify_core's own jit traces the stand-in (its cache is cleared on
+    both sides, so no other test meets the stub), and the launch
+    bookkeeping starts empty and is put back afterwards."""
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import ed25519 as edops
+    from tendermint_tpu.ops import pallas_secp as PS
+    from tendermint_tpu.ops import secp as secp_ops
+
+    seen = []
+
+    def stub(px, rx, s_digits, e_digits, tile, interpret):
+        seen.append((px.shape[1], tile, interpret))
+        return jnp.ones(px.shape[1], dtype=bool)
+
+    monkeypatch.setattr(PS, "verify", stub)
+    monkeypatch.setattr(edops, "_seen_buckets", set())
+    monkeypatch.setattr(edops, "_compiled", set())
+    secp_ops._verify_core.clear_cache()
+    yield seen
+    secp_ops._verify_core.clear_cache()
+
+
+@pytest.mark.parametrize("n", [1, 40, 100, 300])
+def test_lane_record_contract_with_the_kernel_stubbed(stubbed_kernel, n):
+    """One launch record a verify_batch_device call, path LANE_PATH
+    ("secp-xla", which names the lane and outlives the XLA ladder) and
+    nb == bucket_size(n); the kernel gets the whole bucket, the tile
+    min(DEFAULT_TILE, nb) and the interpreter off a TPU; warm_bucket(n)
+    marks that bucket seen, so the first request's launch is no first
+    launch."""
+    from tendermint_tpu.crypto import devobs
+    from tendermint_tpu.crypto import secp256k1 as secp
+    from tendermint_tpu.ops import ed25519 as edops
+    from tendermint_tpu.ops import pallas_secp as PS
+    from tendermint_tpu.ops import secp as secp_ops
+
+    assert secp_ops.LANE_PATH == "secp-xla"
+    nb = edops.bucket_size(n)
+    assert devobs.is_enabled()
+    seq0 = devobs.last_seq()
+    assert secp_ops.warm_bucket(n) == nb
+    (warm,) = devobs.records(since_seq=seq0)
+    assert (warm["path"], warm["n"], warm["nb"]) == ("secp-xla", 0, nb)
+    assert warm["first_launch"] and warm.get("compile_s", 0) > 0
+    assert ("secp-xla", nb, 1) in edops._seen_buckets
+    assert stubbed_kernel == [(nb, min(PS.DEFAULT_TILE, nb), True)]
+
+    keys = [secp.PrivKey.gen_from_secret(b"contract %d" % (i % 4))
+            for i in range(n)]
+    msgs = [b"contract row %d" % i for i in range(n)]
+    sigs = [k.sign(m) for k, m in zip(keys, msgs)]
+    pubs = [k.pub_key().bytes() for k in keys]
+    seq0 = devobs.last_seq()
+    bits = secp_ops.verify_batch_device(pubs, msgs, sigs)
+    (rec,) = devobs.records(since_seq=seq0)
+    assert bits.tolist() == [True] * n
+    assert (rec["path"], rec["n"], rec["nb"]) == ("secp-xla", n, nb)
+    assert not rec["first_launch"] and "compile_s" not in rec
+    assert rec["wall_s"] >= rec["stage_s"] > 0
+    assert len(stubbed_kernel) == 1  # the warm trace served the request
