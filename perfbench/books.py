@@ -12,8 +12,8 @@ record, `votes`, at each `_preverify_votes`: the tally's wall between two
 samples is named time although no span covers it.
 
 Everything here returns None, and raises nothing, on a program without the
-function or the counter (the parent's), in an untraced run, and under
-progspans.MIN_REQUESTS.
+function or the counter (the parent's), in an untraced run, in an open
+window, and under progspans.MIN_REQUESTS.
 """
 from __future__ import annotations
 
@@ -26,7 +26,9 @@ def requests(run: dict):
     """[(start_ns, end_ns, the program's records that began inside)] for
     each usable request of `run` in time order (progspans.assign's rule:
     once the ring has wrapped, only requests it still holds whole), or
-    None."""
+    None; None in an open window, as progspans.by_request."""
+    if "arrivals" in run:
+        return None
     rows = sorted((t0 * 1e9, t1 * 1e9) for name, t0, t1
                   in run.get("spans", []) if name == progspans.REQUEST_ROW)
     records, wrapped = progspans.program_records()

@@ -1,5 +1,7 @@
 """Median wall of one request on the caller's side, closed by the call's
-return, in ms."""
+return, in ms.  In an open window the wall runs from the request's
+scheduled arrival (the wait behind busy clients counts), and a late
+request's is cut at the window's close + run.DRAIN_S."""
 from perfbench import stats
 
 

@@ -1,6 +1,6 @@
 """Device: 1 - (union of the intervals in which an operation ran on the
-device) / (first traced request's start to the last one's end), in %.
-Absent without a TPU plane."""
+device) / (first traced request's start to the last one's end; in an open
+window, the profiled span), in %.  Absent without a TPU plane."""
 
 
 def read(run):

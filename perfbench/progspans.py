@@ -26,7 +26,8 @@ through this module:
 - a reader gives a median over requests, and gives None, never raises,
   when fewer than MIN_REQUESTS usable requests carry the span it wants:
   the parent commit's recorder is off and holds nothing, a program
-  without the module has no spans, and `--trace 0` has no request rows.
+  without the module has no spans, `--trace 0` has no request rows, and
+  an open window's requests overlap.
   MIN_REQUESTS is 3, not more, because a traced window is short: the
   runner stops the profiler inside it, that takes 50-70 s on the comb
   cells, and a `--trace 1` run of `val150-catchup` holds 6 requests in
@@ -79,7 +80,11 @@ def assign(requests, records, wrapped: bool) -> list:
 
 def by_request(run: dict):
     """The program's records of each usable request of `run`, or None
-    where there are no request rows or no records."""
+    where there are no request rows or no records, and in an open window
+    (`run["arrivals"]`): its requests overlap, so the request whose
+    interval holds a span's start is no rule there."""
+    if "arrivals" in run:
+        return None
     requests = [(t0 * 1e9, t1 * 1e9) for name, t0, t1 in run.get("spans", [])
                 if name == REQUEST_ROW]
     records, wrapped = program_records()

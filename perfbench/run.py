@@ -11,6 +11,11 @@ lists for it; each is read by a file of its own, perfbench/end_to_end/
 <metric>.py or perfbench/layers/<metric>.py.  This file knows no cell,
 configuration, traffic mix or metric by name (perfbench/README.md).
 
+The window is a closed loop (one caller, the next request when the last has
+returned) unless the traffic file holds `arrivals`: then it is an open loop,
+a schedule of arrivals drawn from --seed and served by client threads, each
+request timed from its scheduled arrival (`open_window`).
+
 A run: data from --seed; the process brought up as the configuration says;
 every shape the cell can reach warmed; correctness checked against
 per-signature OpenSSL; the timed window; the gate.  Everything up to the
@@ -32,6 +37,7 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import faulthandler  # noqa: E402
 import gc  # noqa: E402
+import hashlib  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -39,6 +45,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -53,6 +60,11 @@ TRACE_LAUNCHES = 24       # this many launches are in it if that is sooner
 #                           launch is ~30,000 device events, and the first
 #                           traced run of val150-live wrote 300 MB in 3 s
 #                           and took 200 s to stop and read
+DRAIN_S = 5.0             # an open window: a request scheduled inside it
+#                           that has not returned this long after its
+#                           close is late, and failed
+PROFILED_SPAN = "pb.profiled"   # an open window's traced span, in the trace
+ARRIVAL_KEYS = {"rate_per_s", "gap_cv", "clients"}
 
 
 class Refused(Exception):
@@ -205,19 +217,24 @@ class Profiler:
         self.t_on = 0.0
         self.seq_on = 0
 
-    def tick(self, i: int, since_begin: float):
-        """Called before request i."""
+    def _start(self):
         import jax
         from tendermint_tpu.crypto import devobs
+        self.dir = tempfile.mkdtemp(prefix="perfbench-trace-")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0      # spans, not every call
+        opts.host_tracer_level = 1
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        self.state = "on"
+        self.t_on, self.seq_on = time.perf_counter(), devobs.last_seq()
+
+    def tick(self, i: int, since_begin: float):
+        """Called before request i."""
+        from tendermint_tpu.crypto import devobs
         if self.state == "idle" and since_begin >= TRACE_AFTER_S:
-            self.dir = tempfile.mkdtemp(prefix="perfbench-trace-")
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0      # spans, not every call
-            opts.host_tracer_level = 1
-            opts.enable_hlo_proto = False
-            jax.profiler.start_trace(self.dir, profiler_options=opts)
-            self.state, self.first = "on", i
-            self.t_on, self.seq_on = time.perf_counter(), devobs.last_seq()
+            self._start()
+            self.first = i
         elif self.state == "on" and i > self.first and (
                 devobs.last_seq() - self.seq_on >= TRACE_LAUNCHES
                 or time.perf_counter() - self.t_on >= TRACE_FOR_S):
@@ -229,17 +246,21 @@ class Profiler:
             jax.profiler.stop_trace()
             self.state, self.last = "done", i - 1
 
-    def reduce(self) -> dict:
+    def _planes(self):
         from perfbench import tracered
-        if self.state != "done":
-            return {"why": "the window was too short for the profiler "
-                           f"(it starts {TRACE_AFTER_S} s in)"}
         path = tracered.find_xplane(self.dir)
         size = os.path.getsize(path)
         planes = tracered.read_xplane(path)
         say(f"trace {size} bytes; planes/lines/events "
             + json.dumps(tracered.inventory(planes))[:1500])
-        red = tracered.reduce(planes)
+        return planes
+
+    def reduce(self) -> dict:
+        from perfbench import tracered
+        if self.state != "done":
+            return {"why": "the window was too short for the profiler "
+                           f"(it starts {TRACE_AFTER_S} s in)"}
+        red = tracered.reduce(self._planes())
         red["requests"] = [self.first, self.last]
         return red
 
@@ -324,8 +345,279 @@ def window(gen, world: dict, seconds: float, spans: Spans, trace: bool):
 
 
 # ---------------------------------------------------------------------------
+# the open loop: a traffic file with `arrivals`
+# ---------------------------------------------------------------------------
+
+def schedule(seed: int, rate: float, seconds: float, gap_cv: float) -> list:
+    """The arrival times of an open window, in seconds from its start: a
+    pure function of its arguments.  Exactly round(rate x seconds) of them
+    in (0, seconds), so that the offered rate does not spread from seed to
+    seed: N + 1 gaps drawn from a Gamma distribution whose coefficient of
+    variation is `gap_cv` (1 is a Poisson process, over 1 bursts; 0 is
+    evenly spaced), scaled so that they span the window, the N arrivals
+    between them."""
+    import numpy as np
+
+    n = round(rate * seconds)
+    if gap_cv == 0:
+        return [seconds * (k + 1) / (n + 1) for k in range(n)]
+    digest = hashlib.sha256(b"perfbench/arrivals/%d" % seed).digest()
+    rng = np.random.default_rng(int.from_bytes(digest[:16], "big"))
+    gaps = rng.gamma(1.0 / gap_cv ** 2, 1.0, n + 1)
+    return (np.cumsum(gaps)[:n] * (seconds / gaps.sum())).tolist()
+
+
+def arrivals_plan(params: dict, gen, seed: int, seconds: float):
+    """None for a traffic file without `arrivals` (the closed loop); else
+    the open window's plan, with its schedule.  Refuses a malformed key,
+    an empty schedule, and more than one client for a generator that does
+    not declare `CONCURRENT = True`."""
+    arr = params.get("arrivals")
+    if arr is None:
+        return None
+    if not isinstance(arr, dict) or set(arr) != ARRIVAL_KEYS:
+        raise Refused(f"`arrivals` must hold exactly {sorted(ARRIVAL_KEYS)}, "
+                      f"not {arr!r}")
+    rate, gap_cv, clients = arr["rate_per_s"], arr["gap_cv"], arr["clients"]
+    if not (isinstance(clients, int) and clients >= 1 and rate > 0
+            and gap_cv >= 0):
+        raise Refused(f"`arrivals` needs rate_per_s > 0, gap_cv >= 0 and a "
+                      f"whole number of clients >= 1: {arr!r}")
+    if clients > 1 and getattr(gen, "CONCURRENT", False) is not True:
+        raise Refused(f"{clients} clients, and the generator "
+                      f"{gen.__name__} does not declare CONCURRENT = True")
+    t_sched = schedule(seed, rate, seconds, gap_cv)
+    if not t_sched:
+        raise Refused(f"{rate}/s over {seconds} s schedules no request")
+    return {**arr, "t_sched": t_sched}
+
+
+def refuse_over_capacity(world: dict, plan: dict):
+    """An open window schedules all its requests before it opens: a
+    generator that made a finite number of them in set-up must hold them
+    all."""
+    capacity = world.get("capacity")
+    if capacity is not None and capacity < len(plan["t_sched"]):
+        raise Refused(f"the schedule holds {len(plan['t_sched'])} requests "
+                      f"and set-up made {capacity}")
+
+
+def settle(t_sched: list, served: list, seconds: float) -> list:
+    """The rows of an open window, a pure function: `served[i]` is
+    (start, return, verdict as expected?, client thread) of request i in
+    seconds from the window's start, or None where it never started.
+    Latency runs from the SCHEDULED arrival, so the wait behind busy
+    clients counts.  A request that has not returned by seconds + DRAIN_S
+    is late and failed, its wall cut there."""
+    deadline = seconds + DRAIN_S
+    rows = []
+    for i, (ts, s) in enumerate(zip(t_sched, served)):
+        if s is not None and s[1] <= deadline:
+            t0, t1, ok, tid = s
+            rows.append({"i": i, "t_sched": ts, "t0": t0, "wall_s": t1 - ts,
+                         "service_s": t1 - t0, "ok": bool(ok),
+                         "late": False, "client": tid})
+        else:
+            rows.append({"i": i, "t_sched": ts,
+                         "t0": None if s is None else s[0],
+                         "wall_s": deadline - ts, "service_s": None,
+                         "ok": False, "late": True,
+                         "client": None if s is None else s[3]})
+    return rows
+
+
+class OpenProfiler(Profiler):
+    """The profiler in an open window: started and stopped by the main
+    thread at set times, whatever requests are in flight then.  The span
+    it covers is marked in the trace by PROFILED_SPAN, which bounds the
+    reduction."""
+
+    def __init__(self):
+        super().__init__()
+        self.mark = None
+        self.t_off = 0.0
+        self.records = []       # the launches that ended in the span
+
+    def tick(self, since_begin: float, seconds: float):
+        from tendermint_tpu.crypto import devobs
+        if self.state == "idle" and since_begin >= seconds:
+            self.state = "missed"
+        elif self.state == "idle" and since_begin >= TRACE_AFTER_S:
+            import jax
+            self._start()
+            self.mark = jax.profiler.TraceAnnotation(PROFILED_SPAN)
+            self.mark.__enter__()
+        elif self.state == "on" and (
+                devobs.last_seq() - self.seq_on >= TRACE_LAUNCHES
+                or time.perf_counter() - self.t_on >= TRACE_FOR_S
+                or since_begin >= seconds):
+            self.stop()
+
+    def wake_in(self, since_begin: float):
+        """Seconds until the next tick has something to do; None if
+        nothing."""
+        if self.state == "idle":
+            return max(TRACE_AFTER_S - since_begin, 0.0)
+        return 0.002 if self.state == "on" else None
+
+    def stop(self, i=None):
+        import jax
+        from tendermint_tpu.crypto import devobs
+        if self.state == "on":
+            self.mark.__exit__(None, None, None)
+            self.t_off = time.perf_counter()
+            # read now: devobs' ring holds the newest 256 launches only
+            self.records = devobs.records(since_seq=self.seq_on)
+            jax.profiler.stop_trace()
+            self.state = "done"
+
+    def reduce(self) -> dict:
+        from perfbench import tracered
+        if self.state != "done":
+            return {"why": "the window was too short for the profiler "
+                           f"(it starts {TRACE_AFTER_S} s in)"}
+        return tracered.reduce(self._planes(), bounds=PROFILED_SPAN)
+
+    def close(self):
+        if self.state == "on":
+            self.mark.__exit__(None, None, None)
+        super().close()
+
+
+def open_window(gen, world: dict, seconds: float, spans: Spans, trace: bool,
+                plan: dict):
+    """The open loop: `plan["clients"]` client threads stand for a
+    server's handler pool and take the scheduled arrivals in order; a
+    request starts at its scheduled time or when a client is free,
+    whichever is later.  The main thread keeps the window's clock and the
+    profiler, so a slow start or stop of the profiler delays no arrival.
+    No arrival is scheduled at or after `seconds`; what has not returned
+    by seconds + DRAIN_S is late (`settle`).  Returns the run record the
+    metric readers take: its rows carry no launch records (a launch that
+    serves several requests belongs to none of them); a traced run has
+    `profiled`, the launch records and the requests of the profiled
+    span."""
+    from tendermint_tpu.crypto import devobs
+
+    t_sched = plan["t_sched"]
+    n = len(t_sched)
+    deadline = seconds + DRAIN_S
+    served = [None] * n
+    lags = []           # how late a client that waited for its arrival woke
+    errors = []
+    halt = threading.Event()    # the main thread gave up: take no more
+    cv = threading.Condition()
+    taken = [0, 0]      # [next arrival to take, requests returned]
+    now = time.perf_counter
+    t_begin = 0.0
+
+    def client():
+        tid = threading.get_ident()
+        while True:
+            with cv:
+                i = taken[0]
+                if i >= n or errors:
+                    return
+                taken[0] = i + 1
+            wait = t_begin + t_sched[i] - now()
+            if wait > 0:
+                time.sleep(wait)
+            if halt.is_set():
+                return
+            t0 = now() - t_begin
+            if t0 >= deadline:
+                continue
+            try:
+                with spans.span("request"):
+                    ok = gen.request(world, i)
+            except BaseException as e:  # noqa: BLE001 - raised on main
+                with cv:
+                    errors.append(e)
+                    cv.notify()
+                return
+            t1 = now() - t_begin
+            with cv:
+                served[i] = (t0, t1, ok, tid)
+                if wait > 0:
+                    lags.append(t0 - t_sched[i])
+                taken[1] += 1
+                cv.notify()
+
+    prof = OpenProfiler() if trace else None
+    threads = [threading.Thread(target=client, daemon=True,
+                                name=f"perfbench-client-{k}")
+               for k in range(plan["clients"])]
+    seq_begin = devobs.last_seq()
+    buckets_begin = _buckets(devobs)
+    try:
+        t_begin = now()
+        for t in threads:
+            t.start()
+        while True:
+            since = now() - t_begin
+            if prof is not None:
+                prof.tick(since, seconds)
+            wake = deadline - since
+            if prof is not None and prof.wake_in(since) is not None:
+                wake = min(wake, prof.wake_in(since))
+            with cv:
+                if taken[1] >= n or errors or since >= deadline:
+                    break
+                cv.wait(timeout=wake)
+        if prof is not None:
+            prof.stop()
+        for t in threads:
+            t.join()
+        if errors:
+            raise errors[0]
+        rows = settle(t_sched, served, seconds)
+        run = {"requests": rows, "window_s": seconds,
+               "launches_in_window": devobs.last_seq() - seq_begin,
+               "new_buckets": sorted(_buckets(devobs) - buckets_begin),
+               "window_records": devobs.records(since_seq=seq_begin),
+               "spans": spans.rows,
+               "arrivals": {k: plan[k] for k in sorted(ARRIVAL_KEYS)},
+               "client_lag_s": sorted(lags),
+               "trace": prof.reduce() if prof is not None else None}
+        if prof is not None and prof.state == "done":
+            t_on, t_off = prof.t_on - t_begin, prof.t_off - t_begin
+            run["profiled"] = {
+                "t_on": t_on, "t_off": t_off, "records": prof.records,
+                "requests": [r["i"] for r in rows if not r["late"]
+                             and r["t0"] >= t_on
+                             and r["t_sched"] + r["wall_s"] <= t_off]}
+    finally:
+        halt.set()
+        if prof is not None:
+            prof.close()
+    return run
+
+
+# ---------------------------------------------------------------------------
 # the run
 # ---------------------------------------------------------------------------
+
+def say_open(run: dict):
+    """What an open window did: the offered load, the latencies from the
+    scheduled arrival, the service times, how late the clients woke for an
+    arrival they were free for, and the backlog."""
+    from perfbench import stats
+
+    rows, arr = run["requests"], run["arrivals"]
+    walls = [r["wall_s"] for r in rows]
+    service = [r["service_s"] for r in rows if not r["late"]]
+    lags = run["client_lag_s"]
+
+    def qs(v):
+        return "/".join(f"{stats.percentile(v, q):.5f}"
+                        for q in (0, 50, 95, 100)) if v else "-"
+    say(f"open loop: {len(rows)} arrivals at {arr['rate_per_s']}/s, gap cv "
+        f"{arr['gap_cv']}, {arr['clients']} client(s); late "
+        f"{sum(r['late'] for r in rows)}; backlog ratio "
+        f"{stats.backlog_ratio(rows, run['window_s'])}")
+    say(f"wall from the scheduled arrival s: min/median/p95/max {qs(walls)}; "
+        f"service s {qs(service)}; client wake-up lag s {qs(lags)}")
+
 
 def device_dict(devices, chips: int, run: dict) -> dict:
     peaks = []
@@ -353,11 +645,14 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     say(f"device {devices[0].device_kind} x{len(devices)}; compile cache "
         f"{jax.config.jax_compilation_cache_dir}")
     spans = Spans(trace)
+    plan = arrivals_plan(params, gen, seed, seconds)
     world = gen.setup(config, params, seed, seconds)
     world["span"] = spans.span
     say(f"data built: {world.get('made')}")
     stop = bringup.PROCESSES[config["process"]](world)
     try:
+        if plan is not None:
+            refuse_over_capacity(world, plan)
         say("process up as " + config["process"])
         gen.warm(world)
         say("warm")
@@ -371,7 +666,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if hasattr(gen, "window_begin"):
             gen.window_begin(world)
         setup_s = time.perf_counter() - T_START
-        run = window(gen, world, seconds, spans, trace)
+        if plan is None:
+            run = window(gen, world, seconds, spans, trace)
+        else:
+            run = open_window(gen, world, seconds, spans, trace, plan)
         if hasattr(gen, "window_end"):
             check_failures += gen.window_end(world, run)
     finally:
@@ -386,7 +684,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         f"{run['launches_in_window']} launches, routes {routes}, "
         f"compiles in window "
         f"{sum(1 for r in run['window_records'] if r.get('compile_s'))}")
-    if walls:
+    if plan is not None:
+        say_open(run)
+    elif walls:
         say("request wall s: min/q1/median/q3/p95/max " + "/".join(
             f"{stats.percentile(walls, q):.5f}"
             for q in (0, 25, 50, 75, 95, 100)) + "; slowest (i, at s, wall s) "
@@ -433,13 +733,15 @@ def main(argv=None) -> int:
         cell = load_cell(MANIFEST, args.workload)
         devices = startup(cell["chips"])
         program_checks()
+        faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
+        try:
+            result = run_cell(cell, args.seed, args.seconds,
+                              bool(args.trace), devices)
+        finally:
+            faulthandler.cancel_dump_traceback_later()
     except Refused as e:
         print(f"perfbench: refusing to start: {e}", file=sys.stderr)
         return 2
-    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
-    result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
-                      devices)
-    faulthandler.cancel_dump_traceback_later()
     print(json.dumps(result), flush=True)
     return 0
 

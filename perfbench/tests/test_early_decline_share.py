@@ -101,4 +101,3 @@ def test_the_runner_finds_the_reader_and_the_manifest_lists_it(program):
         "moves": "request_ms.p50",
         "workloads": ["val100k-commit", "val10k-adjacent",
                       "val10k-skipping", "val10k-client"]}
-    assert manifest["per_layer"][-1] == entry

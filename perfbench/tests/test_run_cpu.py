@@ -27,13 +27,17 @@ def run_cell(runner, capfd, workload, trace, seconds="3"):
 run_cell.seed = 2**31 + 7      # more than 32 signed bits hold
 
 
-CELLS = [("tiny-live", True, True), ("tiny-adjacent", False, True),
-         ("tiny-skipping", False, False), ("tiny-catchup", True, False)]
+# (cell, scheduler samples, p95 listed, open loop)
+CELLS = [("tiny-live", True, True, False),
+         ("tiny-adjacent", False, True, False),
+         ("tiny-skipping", False, False, False),
+         ("tiny-catchup", True, False, False),
+         ("tiny-adjacent-open", False, True, True)]
 
 
-@pytest.mark.parametrize("workload,sched,tail", CELLS)
+@pytest.mark.parametrize("workload,sched,tail,open_loop", CELLS)
 def test_untraced_line_holds_exactly_the_contract_keys(
-        runner, capfd, workload, sched, tail):
+        runner, capfd, workload, sched, tail, open_loop):
     res, out = run_cell(runner, capfd, workload, 0)
     assert set(res) == RESULT_KEYS
     assert set(res["device"]) == DEVICE_KEYS
@@ -45,15 +49,22 @@ def test_untraced_line_holds_exactly_the_contract_keys(
         assert set(m) == {"value", "unit"} and m["value"] > 0
 
 
-@pytest.mark.parametrize("workload,sched,tail", CELLS)
+@pytest.mark.parametrize("workload,sched,tail,open_loop", CELLS)
 def test_traced_line_reports_layers_and_no_device_metric_on_a_cpu(
-        runner, capfd, workload, sched, tail):
+        runner, capfd, workload, sched, tail, open_loop):
     res, out = run_cell(runner, capfd, workload, 1)
     assert set(res) == RESULT_KEYS        # no TPU plane: no breakdown
     assert set(res["device"]) == DEVICE_KEYS   # and no busy_s / window_s
     assert res["correct"] is True, out.err
+    assert "holds no /device:TPU:" in out.out     # said, not made up
+    if open_loop:
+        # an open window's rows carry no launch records: no per-request
+        # layer metric, and no device metric without a TPU plane
+        assert res["metrics"] == {}
+        assert res["failed"] == 0 and res["attempted"] >= 2
+        assert "open loop: " in out.out
+        return
     want = LAYERS_CPU | ({"sched.queue_wait_ms", "sched.lanes_per_launch"}
                          if sched else set())
     assert set(res["metrics"]) == want
-    assert "holds no /device:TPU:" in out.out     # said, not made up
     assert res["metrics"]["launch.count"]["value"] >= 1

@@ -135,10 +135,14 @@ def innermost_segments(spans, lo: float, hi: float, outside: str) -> list:
     return out
 
 
-def reduce(planes: dict) -> dict:
-    """The device's side of a traced sub-window.  Returns {} with
-    "why" set when the trace has no TPU plane (a CPU rehearsal): device
-    metrics are then absent, never made up."""
+def reduce(planes: dict, bounds: str | None = None) -> dict:
+    """The device's side of a traced sub-window: from the first traced
+    request's start to the last one's end (the closed loop), or, with
+    `bounds`, over the host span of that name (an open loop, whose
+    requests overlap and whose profiler starts and stops between no two
+    of them; no `request_busy_s` then).  Returns {} with "why" set when
+    the trace has no TPU plane (a CPU rehearsal): device metrics are then
+    absent, never made up."""
     dev = {p: lines[OPS_LINE] for p, lines in sorted(planes.items())
            if p.startswith(DEVICE_PLANE_PREFIX) and lines.get(OPS_LINE)}
     spans = host_spans(planes)
@@ -147,10 +151,19 @@ def reduce(planes: dict) -> dict:
         return {"why": f"the trace holds no {DEVICE_PLANE_PREFIX}* plane "
                        f"with an '{OPS_LINE}' line",
                 "requests_traced": len(requests)}
-    if not requests:
+    if bounds is not None:
+        marks = [(s, e) for n, s, e in spans if n == bounds]
+        if not marks:
+            return {"why": f"the trace holds no {bounds} span",
+                    "requests_traced": len(requests)}
+        lo, hi = marks[0]
+        spans = [sp for sp in spans if sp[0] != bounds]
+        requests = [(s, e) for s, e in requests if lo <= s and e <= hi]
+    elif not requests:
         return {"why": f"the trace holds no {REQUEST_SPAN} span",
                 "requests_traced": 0}
-    lo, hi = requests[0][0], requests[-1][1]
+    else:
+        lo, hi = requests[0][0], requests[-1][1]
     busy_by_plane = {p: union(clip([(s, s + d) for _, s, d in ev], lo, hi))
                      for p, ev in dev.items()}
     used = {p: b for p, b in busy_by_plane.items() if b}
@@ -185,7 +198,6 @@ def reduce(planes: dict) -> dict:
             if idle > 0:
                 idle_by_span[name] = idle_by_span.get(name, 0.0) + idle
             k += 1
-    per_request = [total(clip(busy, s, e)) / 1e9 for s, e in requests]
 
     def top(d):
         return [[k, v / 1e9] for k, v in
@@ -198,14 +210,18 @@ def reduce(planes: dict) -> dict:
         short = name.split(" = ")[0].lstrip("%")[:80]
         by_op[short] = by_op.get(short, 0.0) + ns
 
-    return {
+    out = {
         "read": {"planes": sorted(used), "line": OPS_LINE,
                  "events": sum(len(dev[p]) for p in used)},
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy_ns / 1e9,
         "chips_busy": len(used),
         "requests_traced": len(requests),
-        "request_busy_s": per_request,
+        "request_busy_s": [total(clip(busy, s, e)) / 1e9
+                           for s, e in requests],
         "device_ops": top(by_op),
         "idle_gaps": top(idle_by_span),
     }
+    if bounds is not None:
+        del out["request_busy_s"]
+    return out
